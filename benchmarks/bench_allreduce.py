@@ -102,15 +102,15 @@ def measured_sweep(out_path: str = "BENCH_allreduce.json"):
     (2 pod x 4 model) host mesh, record into an AutoTuner, and emit the
     strategy grid + the dispatcher's chosen-vs-best regret per size bucket.
 
-    Requires >= 8 devices (the ``--sweep`` entry point forces them before
-    jax initializes).
+    Requires >= 8 devices.
     """
     import json
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.core.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.core.compat import make_mesh
     from repro.core import (tp_all_reduce, tp_reduce_scatter, tp_all_gather,
                             ParallelCtx, autotune)
     from repro.core import comm_model as cm
@@ -311,25 +311,18 @@ def run():
 
 def main(argv=None):
     import argparse
-    import os
-    import sys
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--sweep", action="store_true",
-                    help="measure the strategy x message-size grid on 8 "
-                         "host devices and write BENCH_allreduce.json")
+                    help="measure the strategy x message-size grid on the "
+                         "8 devices JAX finds (on the CPU, start with "
+                         "XLA_FLAGS=--xla_force_host_platform_device_count=8)"
+                         " and write BENCH_allreduce.json")
     ap.add_argument("--out", default="BENCH_allreduce.json")
     args = ap.parse_args(argv)
     if not args.sweep:
         run()
         return
-    if "jax" in sys.modules:
-        raise SystemExit("--sweep must configure devices before jax "
-                         "initializes; run as a fresh process")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = \
-            (flags + " --xla_force_host_platform_device_count=8").strip()
     measured_sweep(args.out)
 
 

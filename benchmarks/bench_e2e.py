@@ -48,15 +48,14 @@ def real_integration():
         return
     import numpy as np
     import jax.numpy as jnp
-    from repro.core.compat import AxisType, make_mesh
+    from repro.core.compat import make_mesh
     from repro.core.pcontext import ParallelCtx
     from repro.models import ModelConfig, make_plan, init_params
     from repro.parallel.steps import build_decode_step, build_prefill
     cfg = ModelConfig(name="bench-tiny", family="dense", n_layers=2,
                       d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
                       d_ff=128, vocab_size=96, dtype=jnp.float32)
-    mesh = make_mesh((2, 4), ("pod", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
+    mesh = make_mesh((2, 4), ("pod", "model"))
     toks = {}
     for strat in ("flat", "hier_rd"):
         ctx = ParallelCtx(tp_fast=("model",), tp_slow=("pod",),

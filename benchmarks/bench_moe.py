@@ -51,7 +51,7 @@ def real_moe_integration():
         emit("fig10/real_moe", 0.0, "skipped=needs_8_devices")
         return
     import jax.numpy as jnp
-    from repro.core.compat import AxisType, make_mesh
+    from repro.core.compat import make_mesh
     from repro.core.pcontext import ParallelCtx
     from repro.models import ModelConfig, make_plan, init_params
     from repro.parallel.steps import build_decode_step, build_prefill
@@ -59,8 +59,7 @@ def real_moe_integration():
                       n_heads=4, n_kv_heads=2, head_dim=16, d_ff=32,
                       vocab_size=96, n_experts=8, top_k=2, d_ff_expert=32,
                       capacity_factor=8.0, dtype=jnp.float32)
-    mesh = make_mesh((2, 4), ("pod", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
+    mesh = make_mesh((2, 4), ("pod", "model"))
     toks = {}
     for strat in ("flat", "hier_rd"):
         ctx = ParallelCtx(tp_fast=("model",), tp_slow=("pod",),

@@ -1,9 +1,11 @@
 """Benchmark harness — one module per paper table/figure.
 
 ``PYTHONPATH=src python -m benchmarks.run``  prints ``name,us_per_call,
-derived`` CSV rows.  Multi-device benchmark parts (HLO-structural collective
-measurements, real sharded-integration checks) run in a subprocess with
-simulated host devices so this process keeps the single-device view.
+derived`` CSV rows.  Everything runs in this one process, so on a TPU host
+it holds the chips once.  The multi-device parts (real sharded-integration
+checks, the RD kernel run) use the devices JAX finds and print a
+``skipped=`` row when there are too few; on the CPU, give them 8 host
+devices with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 Map to the paper:
     bench_scaling    -> Figs. 1, 2, 11 (strong scaling TP vs HP)
@@ -17,29 +19,6 @@ Map to the paper:
     roofline_table   -> EXPERIMENTS.md §Roofline summary
 """
 from __future__ import annotations
-
-import os
-import subprocess
-import sys
-
-
-def _run_subprocess_dist():
-    """Re-run the device-hungry benchmark parts with 8 simulated devices."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    code = ("from benchmarks.bench_e2e import real_integration; "
-            "from benchmarks.bench_moe import real_moe_integration; "
-            "from benchmarks.bench_chunks import kernel_structure; "
-            "real_integration(); real_moe_integration(); "
-            "kernel_structure()")
-    proc = subprocess.run([sys.executable, "-c", code], env=env,
-                          capture_output=True, text=True, timeout=1800)
-    sys.stdout.write(proc.stdout)
-    if proc.returncode != 0:
-        print(f"dist-bench subprocess failed:\n{proc.stderr[-2000:]}",
-              file=sys.stderr)
-        return False
-    return True
 
 
 def main() -> None:
@@ -57,10 +36,10 @@ def main() -> None:
     bench_trace.simulated()
     bench_trace.real_scheduler()
     bench_moe.simulated()
-    ok = _run_subprocess_dist()
+    bench_e2e.real_integration()
+    bench_moe.real_moe_integration()
+    bench_chunks.kernel_structure()
     roofline_table.run()
-    if not ok:
-        raise SystemExit(1)
 
 
 if __name__ == "__main__":

@@ -1,6 +1,5 @@
 """Core: the paper's contribution — hierarchical all-reduce for multi-node
 (multi-pod) LLM inference/training, plus its alpha-beta performance models."""
-from . import compat  # installs the lax.axis_size shim on older jax
 from .pcontext import ParallelCtx, LOCAL, single_pod_ctx, multi_pod_ctx
 from .hierarchical import (
     rd_all_reduce, rd_halving_all_reduce, compressed_rd_all_reduce,

@@ -1,143 +1,27 @@
-"""Version-compat shims for the JAX API surface this repo targets.
+"""Mesh construction for the installed JAX.
 
-The code is written against the current JAX API (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``, ``lax.axis_size``,
-``pltpu.CompilerParams`` / ``pltpu.InterpretParams``).  Older installs (e.g.
-jax 0.4.x) expose the same functionality under different names; everything
-routes through here so the rest of the tree stays on the modern spelling.
+``jax.make_mesh`` makes every axis ``Explicit`` unless told otherwise; the
+whole tree is written for ``Auto`` axes (sharding propagated by the
+compiler, collectives spelled out inside ``jax.shard_map``), so meshes are
+built through this one alias:
 
-Import this module before (or instead of) reaching for the raw JAX names:
-
-    from repro.core.compat import shard_map, make_mesh
-    from repro.core.compat import tpu_compiler_params, tpu_interpret_params
-
-``tpu_interpret_params()`` returns ``None`` when the installed Pallas has no
-TPU interpret mode capable of emulating remote DMA + semaphores on CPU; the
-callers (dist cases, benchmarks) skip those paths gracefully.
+    from repro.core.compat import make_mesh
+    mesh = make_mesh((2, 4), ("pod", "model"))
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Optional, Sequence
+from typing import Sequence
 
 import jax
-from jax import lax
-
-# ---------------------------------------------------------------------------
-# shard_map: jax.shard_map(check_vma=...) vs jax.experimental (check_rep=...)
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):                         # modern jax
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:                                                  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-
-
-# ---------------------------------------------------------------------------
-# make_mesh: axis_types only exists on newer jax; older meshes are all-Auto
-# ---------------------------------------------------------------------------
-
-try:
-    from jax.sharding import AxisType as _AxisType  # noqa: F401
-    _HAS_AXIS_TYPES = True
-except ImportError:
-    _AxisType = None
-    _HAS_AXIS_TYPES = False
-
-
-if _HAS_AXIS_TYPES:
-    AxisType = _AxisType
-else:
-    class AxisType:  # placeholder: every axis is Auto on older jax anyway
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-def auto_axis_types(n: int):
-    """(AxisType.Auto,) * n on modern jax, None on older jax."""
-    if _HAS_AXIS_TYPES:
-        return (_AxisType.Auto,) * n
-    return None
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
-              *, axis_types: Any = None, devices=None):
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if _HAS_AXIS_TYPES and axis_types is not None \
-            and not isinstance(axis_types[0] if axis_types else None, str):
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types,
-                             **kw)
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
+              *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
-# ---------------------------------------------------------------------------
-# lax.axis_size: added to lax recently; psum(1, axis) folds to a python int
-# under both shard_map and pmap tracing on every version we support.
-# ---------------------------------------------------------------------------
-
-if not hasattr(lax, "axis_size"):
-    def _axis_size(axis_name) -> int:
-        return lax.psum(1, axis_name)
-
-    lax.axis_size = _axis_size  # patched once, at first repro.core import
-
-
-# ---------------------------------------------------------------------------
-# Pallas TPU params
-# ---------------------------------------------------------------------------
-
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-except ImportError:  # pallas not available at all (pure-CPU minimal install)
-    _pltpu = None
-
-
-def tpu_compiler_params(**kw):
-    """pltpu.CompilerParams on modern jax, TPUCompilerParams on 0.4.x.
-
-    Silently drops kwargs the installed dataclass does not know (e.g.
-    ``collective_id`` predates some 0.4.x releases) — the params are
-    performance/bookkeeping hints, not semantics.
-    """
-    if _pltpu is None:
-        return None
-    cls = getattr(_pltpu, "CompilerParams", None) \
-        or getattr(_pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        return None
-    import dataclasses
-    if dataclasses.is_dataclass(cls):
-        fields = {f.name for f in dataclasses.fields(cls)}
-        kw = {k: v for k, v in kw.items() if k in fields}
-    return cls(**kw)
-
-
-def tpu_interpret_params() -> Optional[Any]:
-    """TPU interpret-mode params (emulates remote DMA + semaphores on CPU).
-
-    Returns None when unsupported; callers must skip the kernel path then
-    (plain ``interpret=True`` cannot emulate cross-device semaphores).
-    """
-    if _pltpu is None:
-        return None
-    cls = getattr(_pltpu, "InterpretParams", None) \
-        or getattr(_pltpu, "TPUInterpretParams", None)
-    return cls() if cls is not None else None
-
-
-HAS_TPU_INTERPRET = tpu_interpret_params() is not None
-
-
-__all__ = [
-    "shard_map", "make_mesh", "auto_axis_types", "AxisType",
-    "tpu_compiler_params", "tpu_interpret_params", "HAS_TPU_INTERPRET",
-]
+__all__ = ["make_mesh"]

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import compat  # noqa: F401  (installs lax.axis_size on older jax)
 from .pcontext import ParallelCtx
 from ..kernels.rd_allreduce import quant as _q
 

@@ -64,12 +64,12 @@ import numpy as np
 from ..core import autotune
 from ..core.pcontext import ParallelCtx, LOCAL
 from ..parallel.steps import (build_admit_chunk_step, build_cache_init,
-                              build_prefill_only_step)
+                              build_prefill_only_step, serve_param_shardings)
 from .faults import FaultInjector
 from .kv_cache import (BundleIntegrityError, KVBundle, export_slot,
                        slots_to_heads)
 from .scheduler import (ContinuousBatcher, Request, _percentile,
-                        request_sampling_key, run_chunked_prefill)
+                        local_out, request_sampling_key, run_chunked_prefill)
 
 
 def pool_tuner(ar_table) -> autotune.AutoTuner:
@@ -104,8 +104,13 @@ class PrefillPool:
                  mesh=None, ar_table=None, temperature: float = 0.0,
                  top_k: int = 0, seed: int = 0, scan_layers: bool = True,
                  fsdp_serve: bool = False, admit_mode: str = "full",
-                 admit_chunk: int = 32, block_size: int = 0):
-        self.ap, self.cfg, self.params = ap, ap.cfg, params
+                 admit_chunk: int = 32, block_size: int = 0,
+                 device=None):
+        self.ap, self.cfg = ap, ap.cfg
+        # weights (and the private cache) where the steps read them, as in
+        # ContinuousBatcher
+        self.params = jax.device_put(params, serve_param_shardings(
+            ap, ctx, mesh, fsdp_serve=fsdp_serve, device=device))
         if self.cfg.family != "dense":
             raise ValueError("disaggregated serving supports attention-"
                              f"only dense families, not "
@@ -138,7 +143,8 @@ class PrefillPool:
             # a time, overwritten in place)
             geo = dict(slots=1, s_max=s_max, block_size=block_size,
                        n_blocks=None, fsdp_serve=fsdp_serve)
-            self.cache = build_cache_init(ap, ctx, mesh, **geo).jit()()
+            self.cache = build_cache_init(ap, ctx, mesh, **geo).jit(
+                **local_out(mesh, device))()
             kw = dict(self._step_kw)
             kw.update(slots=1, s_max=s_max, block_size=block_size,
                       n_blocks=None)

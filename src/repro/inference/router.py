@@ -208,15 +208,18 @@ class Router:
         self._rr = 0
 
     @classmethod
-    def from_spec(cls, spec: ServeSpec, *, ap=None, params=None) -> "Router":
+    def from_spec(cls, spec: ServeSpec, *, ap=None, params=None,
+                  devices=None) -> "Router":
         """Build the fleet a ``ServeSpec`` describes: N replicas from one
-        template, each on its own disjoint contiguous device group, each
-        with an independently-seeded fault schedule (``replica_id`` folds
-        into the plan seed)."""
+        template, each on its own disjoint contiguous device group (carved
+        from ``devices``, default ``jax.devices()``) with its weights and
+        cache placed there, each with an independently-seeded fault
+        schedule (``replica_id`` folds into the plan seed)."""
         from ..parallel.topology import replica_device_groups
         spec.validate()
         rspec = spec.replica
-        groups = replica_device_groups(spec.replicas, rspec.device_need)
+        groups = replica_device_groups(spec.replicas, rspec.device_need,
+                                       devices)
         reps = [build_replica(rspec, ap=ap, params=params,
                               devices=groups[i], replica_id=i)
                 for i in range(spec.replicas)]

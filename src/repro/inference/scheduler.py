@@ -63,7 +63,8 @@ import numpy as np
 from ..core.pcontext import ParallelCtx, LOCAL
 from ..parallel.steps import (build_admit_chunk_step, build_admit_step,
                               build_cache_init, build_kv_splice_step,
-                              build_serve_step, build_spec_verify_step)
+                              build_serve_step, build_spec_verify_step,
+                              local_sharding, serve_param_shardings)
 from .faults import FaultInjector
 from .kv_cache import (BlockAllocator, KVBundle, heads_to_slots,
                        paged_geometry)
@@ -110,6 +111,13 @@ def request_sampling_key(seed: int, rid: int) -> jax.Array:
     resamples its original tokens.  temperature=0 paths never consult it.
     """
     return jax.random.fold_in(jax.random.PRNGKey(seed), rid)
+
+
+def local_out(mesh, device) -> Dict[str, Any]:
+    """``jax.jit`` keywords that put a mesh-less step's output (a fresh
+    cache) on ``device``; a mesh step places its outputs itself."""
+    home = local_sharding(mesh, device)
+    return {} if home is None else {"out_shardings": home}
 
 
 def run_chunked_prefill(params, cache, prompt: np.ndarray, slot, chunk: int,
@@ -342,8 +350,14 @@ class ContinuousBatcher:
                  deadline_s: Optional[float] = None,
                  spec_autodisable_after: int = 0,
                  prefix_cache: str = "off",
-                 prefix_capacity: Optional[int] = None):
-        """``spec_mode`` turns on speculative decoding: each engine step
+                 prefix_capacity: Optional[int] = None,
+                 device=None):
+        """``device`` is where a mesh-less (``mesh=None``) batcher keeps
+        its weights and cache (``jax.devices()[0]`` when None); with a mesh
+        both follow the mesh.  ``params`` are moved to where the serve
+        steps read them (``steps.serve_param_shardings``) once, here.
+
+        ``spec_mode`` turns on speculative decoding: each engine step
         drafts ``spec_k`` tokens per slot (``"ngram"`` prompt-lookup,
         ``"draft"`` small model from ``configs.registry`` via
         ``draft_arch``, or an injected ``drafter``) and verifies them in
@@ -362,11 +376,15 @@ class ContinuousBatcher:
         ``spec_autodisable_after`` > 0 degrades a slot to plain decode
         after that many consecutive zero-accept verify passes (0 = off;
         a verify-path fault always disables the slot's speculation)."""
-        self.ap, self.cfg, self.params = ap, ap.cfg, params
+        self.ap, self.cfg = ap, ap.cfg
+        self.params = jax.device_put(params, serve_param_shardings(
+            ap, ctx, mesh, fsdp_serve=fsdp_serve, device=device))
         self.slots = slots
         self.s_max = s_max
         self.ctx = ctx
         self.mesh = mesh
+        # host-built arrays (slot state, block table) are uploaded here
+        self._home = local_sharding(mesh, device)
         self.temperature = temperature
         self.top_k = top_k
         self.seed = seed
@@ -444,7 +462,8 @@ class ContinuousBatcher:
         sample_kw = dict(temperature=temperature, top_k=top_k)
         self.cache = build_cache_init(
             ap, ctx, mesh, **{k: v for k, v in kw.items()
-                              if k != "scan_layers"}).jit()()
+                              if k != "scan_layers"}).jit(
+            **local_out(mesh, device))()
         self._serve = build_serve_step(ap, ctx, mesh, ar_table=ar_table,
                                        **sample_kw, **kw).jit()
         self._admit_kw = dict(ar_table=ar_table, **sample_kw, **kw)
@@ -495,7 +514,7 @@ class ContinuousBatcher:
         self._prefix_hits = 0
         self._prefix_tokens_saved = 0
         if self.paged:
-            self.cache["block_tbl"] = jnp.asarray(self.alloc.table)
+            self._upload_table()
 
         # host mirrors of the device-side slot state
         self.positions = np.zeros((slots,), np.int32)
@@ -540,17 +559,23 @@ class ContinuousBatcher:
     # -- state/device sync ---------------------------------------------------
 
     def _push_state(self):
-        self._state = {"tokens": jnp.asarray(self.tokens),
-                       "positions": jnp.asarray(self.positions),
-                       "remaining": jnp.asarray(self.remaining),
-                       "active": jnp.asarray(self.active_mask),
-                       "rng": jnp.asarray(self.slot_key),
-                       "sample_idx": jnp.asarray(self.sample_idx)}
+        self._state = jax.device_put(
+            {"tokens": self.tokens, "positions": self.positions,
+             "remaining": self.remaining, "active": self.active_mask,
+             "rng": self.slot_key, "sample_idx": self.sample_idx},
+            self._home, may_alias=False)
         self._dirty = False
+
+    def _upload_table(self):
+        """The allocator's block table, put where the cache's table
+        lives (a copy: the allocator edits its table in place)."""
+        self.cache["block_tbl"] = jax.device_put(
+            self.alloc.table, self.cache["block_tbl"].sharding,
+            may_alias=False)
 
     def _sync_table(self):
         if self.alloc.version != self._table_version:
-            self.cache["block_tbl"] = jnp.asarray(self.alloc.table)
+            self._upload_table()
             self._table_version = self.alloc.version
 
     def _step_rng(self):

@@ -346,10 +346,22 @@ def _plan(spec: ReplicaSpec, tp: int):
     return make_plan(cfg, tp)
 
 
-def _init_params(spec: ReplicaSpec, ap):
+def _init_params(spec: ReplicaSpec, ap, ctx, mesh, device=None):
+    """Weights from ``PRNGKey(spec.seed)``, created by one jit straight
+    into the shardings the replica's steps read them in (no host copy, no
+    stacked second copy, no re-layout on the first call)."""
     import jax
     from ..models.transformer import init_params
-    return init_params(jax.random.PRNGKey(spec.seed), ap)
+    from ..parallel.steps import serve_param_shardings
+    out = serve_param_shardings(ap, ctx, mesh, fsdp_serve=spec.fsdp_serve,
+                                device=device)
+    return jax.jit(lambda k: init_params(k, ap), out_shardings=out)(
+        jax.random.PRNGKey(spec.seed))
+
+
+def _local_device(devices, tp: int):
+    """The one device of a mesh-less (``tp == 1``) replica's group."""
+    return devices[0] if devices is not None and tp == 1 else None
 
 
 def make_injector(spec: ReplicaSpec, replica_id: int = 0):
@@ -381,7 +393,7 @@ def build_engine(spec: ReplicaSpec, *, ap=None, params=None, drafter=None):
     if ap is None:
         ap = _plan(spec, tp)
     if params is None:
-        params = _init_params(spec, ap)
+        params = _init_params(spec, ap, ctx, mesh)
     return InferenceEngine(
         ap, params, ctx=ctx, mesh=mesh, s_max=spec.s_max,
         fsdp_serve=spec.fsdp_serve, scan_layers=spec.scan_layers,
@@ -405,14 +417,15 @@ def build_prefill_pool(spec: ReplicaSpec, *, ap=None, params=None,
         overlap=spec.overlap, seq_parallel=spec.seq_parallel,
         ar_quant=spec.ar_quant,
         devices=None if devices is None else devices[:spec.prefill_tp])
+    device = _local_device(devices, tp)
     if ap is None:
         ap = _plan(spec, tp)
     if params is None:
-        params = _init_params(spec, ap)
+        params = _init_params(spec, ap, ctx, mesh, device)
     if ar_table is None:
         ar_table = pool_tuner(spec.prefill_ar_table or spec.ar_table)
     return PrefillPool(
-        ap, params, s_max=spec.s_max, ctx=ctx, mesh=mesh,
+        ap, params, s_max=spec.s_max, ctx=ctx, mesh=mesh, device=device,
         ar_table=ar_table, temperature=spec.temperature, top_k=spec.top_k,
         seed=spec.seed, scan_layers=spec.scan_layers,
         fsdp_serve=spec.fsdp_serve, admit_mode=spec.admit_mode,
@@ -429,12 +442,14 @@ def _build_batcher(spec: ReplicaSpec, *, ap, params, drafter, injector,
         spec.tp, spec.pods, ar_strategy=spec.ar_strategy,
         overlap=spec.overlap, seq_parallel=seq_parallel,
         ar_quant=spec.ar_quant, devices=devices)
+    device = _local_device(devices, tp)
     if ap is None:
         ap = _plan(spec, tp)
     if params is None:
-        params = _init_params(spec, ap)
+        params = _init_params(spec, ap, ctx, mesh, device)
     return ContinuousBatcher(
         ap, params, slots=spec.slots, s_max=spec.s_max, ctx=ctx, mesh=mesh,
+        device=device,
         block_size=spec.block_size, n_blocks=spec.n_blocks,
         kv_quant=spec.kv_quant, ar_table=ar_table,
         temperature=spec.temperature, top_k=spec.top_k, seed=spec.seed,
@@ -461,7 +476,8 @@ def build_replica(spec: ReplicaSpec, *, ap=None, params=None, drafter=None,
     fleets share one weight init; params from ``PRNGKey(spec.seed)``
     otherwise, so sharing is the default behavior anyway).  ``devices``
     restricts the replica's mesh(es) to a disjoint device group (see
-    ``parallel.topology.replica_device_groups``).  ``injector`` overrides
+    ``parallel.topology.replica_device_groups``); a one-device group puts
+    a ``tp == 1`` replica's weights and cache on that device.  ``injector`` overrides
     the one :func:`make_injector` derives from ``spec.fault_plan`` +
     ``replica_id``.  ``prefill_ap``/``decode_ap`` (+ ``*_params``) give a
     disagg replica with *heterogeneous* pool TP degrees caller-built

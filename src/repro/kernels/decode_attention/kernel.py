@@ -19,7 +19,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import tpu_compiler_params
 
 NEG_INF = -1.0e30
 
@@ -77,6 +76,8 @@ def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     the index_map below resolves logical block j of sequence b to physical
     block tbl[b, j] via scalar prefetch, so the gather happens in the DMA
     schedule — the logical (B, S, U, hd) view is never materialized in HBM.
+    Every value is 2-D (a (1, hd) query row, (bs, hd) key/value tiles,
+    (1, 1) softmax state), the shapes the TPU's vector units tile.
     """
     b = pl.program_id(0)
     j = pl.program_id(2)
@@ -90,7 +91,7 @@ def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     pos = pos_ref[b]
     k_pos = j * block_size + lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)[0]
+        jnp.int32, (1, block_size), 1)
     mask = k_pos <= pos
     if window > 0:
         mask &= k_pos > pos - window
@@ -99,27 +100,27 @@ def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     # table entries may point at the trash block).
     @pl.when(j * block_size <= pos)
     def _compute():
-        q_vec = q_ref[0, 0].astype(jnp.float32)           # (hd,)
-        kb = k_ref[0, :, 0].astype(jnp.float32)           # (bs, hd)
-        vb = v_ref[0, :, 0].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)                   # (1, hd)
+        kb = k_ref[0].astype(jnp.float32)                  # (bs, hd)
+        vb = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
-            kb, q_vec, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (bs,)
+            q, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # (1, bs)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[0]
-        m_new = jnp.maximum(m_prev, jnp.max(s))
+        m_prev = m_ref[...]                                # (1, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        l_ref[0] = l_ref[0] * alpha + jnp.sum(p)
-        acc_ref[0] = acc_ref[0] * alpha + jax.lax.dot_general(
-            p, vb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[0] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p, vb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # (1, hd)
+        m_ref[...] = m_new
 
     @pl.when(j == nj - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[0]
-                       / jnp.maximum(l_ref[0], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -136,46 +137,53 @@ def paged_decode_attention_call(q, k_phys, v_phys, block_tbl, positions, *,
 
     The block table and positions ride in SMEM as scalar-prefetch operands
     (``PrefetchScalarGridSpec``): each grid step (b, h, j) DMAs physical
-    block ``tbl[b, j]`` HBM->VMEM directly, so non-resident blocks cost
-    nothing and the KV working set per step is one (block_size, hd) tile.
-    Batch is *not* sublane-blocked (unlike the dense kernel): each
-    sequence's block list is independent, which trades sublane utilization
-    for zero logical-view materialization — the PagedAttention layout.
+    block ``tbl[b, j]``'s (block_size, hd) tile of kv head ``h //
+    q_per_kv`` HBM->VMEM directly, so non-resident blocks cost nothing.
+    Heads are folded into the lane dim (``(..., Hkv * hd)``) so that every
+    block's last two dims are (8k, 128m)-tileable; on a TPU that view of
+    the (..., Hkv, hd) pool is a relayout copy, which a head-major pool
+    would avoid.  Batch is *not* sublane-blocked (unlike the dense
+    kernel): each sequence's block list is independent, which trades
+    sublane utilization for zero logical-view materialization — the
+    PagedAttention layout.
     """
     B, Hq, hd = q.shape
+    n_blocks, block_size, Hkv, _ = k_phys.shape
     max_blocks = block_tbl.shape[1]
-    block_size = k_phys.shape[1]
     grid = (B, Hq, max_blocks)
     kern = functools.partial(_paged_decode_kernel, scale=hd ** -0.5,
                              window=window, block_size=block_size)
+    q_rows = q.reshape(B, 1, Hq * hd)
+    k_rows = k_phys.reshape(n_blocks, block_size, Hkv * hd)
+    v_rows = v_phys.reshape(n_blocks, block_size, Hkv * hd)
+    kv_spec = pl.BlockSpec((1, block_size, hd),
+                           lambda b, h, j, tbl, pos, qpk=q_per_kv:
+                           (tbl[b, j], 0, h // qpk))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, h, j, tbl, pos: (b, h, 0)),
-            pl.BlockSpec((1, block_size, 1, hd),
-                         lambda b, h, j, tbl, pos, qpk=q_per_kv:
-                         (tbl[b, j], 0, h // qpk, 0)),
-            pl.BlockSpec((1, block_size, 1, hd),
-                         lambda b, h, j, tbl, pos, qpk=q_per_kv:
-                         (tbl[b, j], 0, h // qpk, 0)),
+            pl.BlockSpec((1, 1, hd), lambda b, h, j, tbl, pos: (b, 0, h)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec((1, 1, hd),
-                               lambda b, h, j, tbl, pos: (b, h, 0)),
+                               lambda b, h, j, tbl, pos: (b, 0, h)),
         scratch_shapes=[
             pltpu.VMEM((1, hd), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct(q_rows.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tbl, positions, q, k_phys, v_phys)
+    )(block_tbl, positions, q_rows, k_rows, v_rows)
+    return out.reshape(B, Hq, hd)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -212,7 +220,7 @@ def decode_attention_call(q, k, v, positions, *, window: int,
             pltpu.VMEM((block_b,), jnp.float32),
             pltpu.VMEM((block_b,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(positions, q, k, v)

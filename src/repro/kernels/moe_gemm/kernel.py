@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import tpu_compiler_params
-
 
 def _moe_ffn_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref):
     i_f = pl.program_id(2)
@@ -66,7 +64,7 @@ def moe_expert_ffn_call(x, wg, wu, wd, *, block_c: int = 128,
                                lambda e, ic, i_f: (e, ic, 0)),
         out_shape=jax.ShapeDtypeStruct((E, C, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, D), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, wg, wu, wd)

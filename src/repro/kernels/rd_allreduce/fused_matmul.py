@@ -37,7 +37,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import tpu_compiler_params
+from .kernel import enter_barrier
 
 
 def _fused_kernel(x_ref, w_ref, out_ref, recv_ref, step_sem, send_sem,
@@ -45,14 +45,15 @@ def _fused_kernel(x_ref, w_ref, out_ref, recv_ref, step_sem, send_sem,
                   n_chunks: int):
     my = lax.axis_index(axis_name)
     n_steps = int(math.log2(n_devices))
+    enter_barrier(axis_name, my, n_steps)
 
     # --- step 0, fused into the GEMM epilogue ------------------------------
     # Handshake once with the step-0 peer so its recv buffer is known-free
     # before any chunk lands (same race the per-step semaphores in
     # _rd_kernel prevent).
-    peer0 = my ^ 1
+    peer0 = {axis_name: my ^ 1}   # MESH address: other coordinates kept
     pltpu.semaphore_signal(step_sem.at[0], 1, device_id=peer0,
-                           device_id_type=pltpu.DeviceIdType.LOGICAL)
+                           device_id_type=pltpu.DeviceIdType.MESH)
     pltpu.semaphore_wait(step_sem.at[0], 1)
     copies = []
     for c in range(n_chunks):
@@ -65,7 +66,7 @@ def _fused_kernel(x_ref, w_ref, out_ref, recv_ref, step_sem, send_sem,
             send_sem=send_sem.at[c],
             recv_sem=recv_sem.at[c],
             device_id=peer0,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=pltpu.DeviceIdType.MESH,
         )
         copy.start()  # chunk c rides DCN while chunk c+1 runs on the MXU
         copies.append(copy)
@@ -75,9 +76,9 @@ def _fused_kernel(x_ref, w_ref, out_ref, recv_ref, step_sem, send_sem,
 
     # --- remaining RD steps (identical to _rd_kernel) ----------------------
     for step in range(1, n_steps):
-        peer = my ^ (1 << step)
+        peer = {axis_name: my ^ (1 << step)}
         pltpu.semaphore_signal(step_sem.at[step], 1, device_id=peer,
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
+                               device_id_type=pltpu.DeviceIdType.MESH)
         pltpu.semaphore_wait(step_sem.at[step], 1)
         parity = step % 2
         copies = []
@@ -88,7 +89,7 @@ def _fused_kernel(x_ref, w_ref, out_ref, recv_ref, step_sem, send_sem,
                 send_sem=send_sem.at[c],
                 recv_sem=recv_sem.at[c],
                 device_id=peer,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id_type=pltpu.DeviceIdType.MESH,
             )
             copy.start()
             copies.append(copy)
@@ -123,7 +124,7 @@ def fused_matmul_rd_call(x, w, *, axis_name: str, n_devices: int,
             pltpu.SemaphoreType.DMA((n_chunks,)),          # send sems
             pltpu.SemaphoreType.DMA((n_chunks,)),          # recv sems
         ],
-        compiler_params=tpu_compiler_params(collective_id=collective_id),
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
         interpret=interpret,
     )(x, w)
 

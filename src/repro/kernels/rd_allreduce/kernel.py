@@ -13,9 +13,11 @@ GPU->TPU mechanism mapping (DESIGN.md §2):
   sequence-number sync       -> per-step barrier semaphore handshake with
                                 the peer (prevents recv-buffer reuse races)
 
-The kernel is written for a 1-D logical axis (the slow/DCN axis) inside
-shard_map; x must be the caller's partial sum, padded to
-(n_chunks, chunk_elems).
+The kernel exchanges over one mesh axis (the slow/DCN axis) inside
+shard_map, on a mesh of any rank; x must be the caller's partial sum,
+laid out as (n_chunks, rows, 128) with rows a multiple of the dtype's
+native tile height (8 for f32, 16 for bf16), so that each chunk is a
+DMA-able slice on a TPU (``ops.rd_all_reduce_pallas`` pads to that).
 """
 from __future__ import annotations
 
@@ -28,7 +30,18 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import tpu_compiler_params
+
+def enter_barrier(axis_name: str, my, n_steps: int):
+    """Wait until every RD peer of this device has entered the kernel, so
+    its scratch semaphores and receive buffer exist before any signal or
+    DMA lands there.  Uses the kernel's barrier semaphore, which is what
+    the ``collective_id`` compiler param names."""
+    barrier = pltpu.get_barrier_semaphore()
+    for step in range(n_steps):
+        pltpu.semaphore_signal(barrier, 1,
+                               device_id={axis_name: my ^ (1 << step)},
+                               device_id_type=pltpu.DeviceIdType.MESH)
+    pltpu.semaphore_wait(barrier, n_steps)
 
 
 def _rd_kernel(x_ref, out_ref, recv_ref, step_sem, send_sem, recv_sem, *,
@@ -36,9 +49,13 @@ def _rd_kernel(x_ref, out_ref, recv_ref, step_sem, send_sem, recv_sem, *,
     my = lax.axis_index(axis_name)
     out_ref[...] = x_ref[...]
     n_steps = int(math.log2(n_devices))
+    enter_barrier(axis_name, my, n_steps)
 
     for step in range(n_steps):
-        peer = my ^ (1 << step)
+        # the peer differs from this device in ``axis_name`` only; MESH
+        # addressing keeps every other mesh coordinate (a scalar LOGICAL id
+        # would name the wrong device on a mesh with more than one axis)
+        peer = {axis_name: my ^ (1 << step)}
         # --- per-step peer handshake (replaces the paper's sequence
         # numbers): both sides signal + wait so the peer's recv buffer for
         # this step parity is known-free before any chunk lands.  The
@@ -46,7 +63,7 @@ def _rd_kernel(x_ref, out_ref, recv_ref, step_sem, send_sem, recv_sem, *,
         # fast device's step-(i+1) signal satisfy a slow device's step-i
         # wait (the race the paper's sequence numbers also prevent).
         pltpu.semaphore_signal(step_sem.at[step], 1, device_id=peer,
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
+                               device_id_type=pltpu.DeviceIdType.MESH)
         pltpu.semaphore_wait(step_sem.at[step], 1)
 
         parity = step % 2
@@ -58,7 +75,7 @@ def _rd_kernel(x_ref, out_ref, recv_ref, step_sem, send_sem, recv_sem, *,
                 send_sem=send_sem.at[c],
                 recv_sem=recv_sem.at[c],
                 device_id=peer,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id_type=pltpu.DeviceIdType.MESH,
             )
             copy.start()           # non-blocking put (put_nbi analogue)
             copies.append(copy)
@@ -74,7 +91,7 @@ def _rd_kernel(x_ref, out_ref, recv_ref, step_sem, send_sem, recv_sem, *,
 def rd_all_reduce_kernel_call(x, *, axis_name: str, n_devices: int,
                               n_chunks: int = 1, interpret=False,
                               collective_id: int = 7):
-    """x: (n_chunks, chunk_elems) f32/bf16 partial sum (inside shard_map)."""
+    """x: (n_chunks, rows, 128) f32/bf16 partial sum (inside shard_map)."""
     kern = functools.partial(_rd_kernel, axis_name=axis_name,
                              n_devices=n_devices, n_chunks=n_chunks)
     return pl.pallas_call(
@@ -89,6 +106,6 @@ def rd_all_reduce_kernel_call(x, *, axis_name: str, n_devices: int,
             pltpu.SemaphoreType.DMA((n_chunks,)),          # send sems
             pltpu.SemaphoreType.DMA((n_chunks,)),          # recv sems
         ],
-        compiler_params=tpu_compiler_params(collective_id=collective_id),
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
         interpret=interpret,
     )(x)

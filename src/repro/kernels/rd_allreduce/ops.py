@@ -26,15 +26,18 @@ def rd_all_reduce_pallas(x: jax.Array, axis_name: str, *,
         return lax.psum(x, axis_name)
     shape, dtype = x.shape, x.dtype
     flat = x.reshape(-1)
-    # chunk_elems aligned to the 128-lane VPU/MXU width
+    # each chunk a (rows, 128) slab of its own leading index, rows a whole
+    # number of native (8 * packing, 128) tiles: a DMA-able slice at any
+    # message size (bf16 packs two rows per sublane)
+    tile = 8 * max(1, 4 // jnp.dtype(dtype).itemsize) * 128
     ce = -(-flat.shape[0] // n_chunks)
-    ce = ((ce + 127) // 128) * 128
+    ce = -(-ce // tile) * tile
     pad = n_chunks * ce - flat.shape[0]
     if pad:
         flat = jnp.pad(flat, (0, pad))
     out = rd_all_reduce_kernel_call(
-        flat.reshape(n_chunks, ce), axis_name=axis_name, n_devices=n,
-        n_chunks=n_chunks, interpret=interpret)
+        flat.reshape(n_chunks, ce // 128, 128), axis_name=axis_name,
+        n_devices=n, n_chunks=n_chunks, interpret=interpret)
     out = out.reshape(-1)
     if pad:
         out = out[:-pad]

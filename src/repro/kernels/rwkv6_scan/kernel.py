@@ -20,8 +20,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import tpu_compiler_params
-
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
                  y_ref, sout_ref, s_ref, *, chunk: int):
@@ -89,7 +87,7 @@ def rwkv6_scan_call(r, k, v, logw, u, s0, *, chunk: int = 64,
         out_shape=[jax.ShapeDtypeStruct((B, T, H, hd), jnp.float32),
                    jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, logw, u, s0)

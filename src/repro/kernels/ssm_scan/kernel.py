@@ -20,8 +20,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import tpu_compiler_params
-
 
 def _ssm_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, h0_ref,
                 y_ref, hout_ref, h_ref, *, chunk_t: int):
@@ -80,7 +78,7 @@ def ssm_scan_call(x, dt, b, c, a, h0, *, chunk_t: int = 64,
         out_shape=[jax.ShapeDtypeStruct((B, T, Ci), jnp.float32),
                    jax.ShapeDtypeStruct((B, Ci, S), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_c, S), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, b, c, a, h0)

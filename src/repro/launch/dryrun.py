@@ -4,8 +4,11 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # device count on first initialization, and the multi-pod dry-run needs 512
 # placeholder host devices to build the production mesh.  (Do NOT set this
 # globally — smoke tests and benches must see 1 device.)
-"""Multi-pod dry-run: ``.lower().compile()`` every (arch x shape x mesh)
-cell on the production meshes and record memory/cost/collective evidence.
+"""Multi-pod dry-run, a CPU-only tool: ``.lower().compile()`` every
+(arch x shape x mesh) cell on the production meshes, built from 512 forced
+host CPU devices, and record memory/cost/collective evidence.  It never
+runs on a TPU; AOT compiles for a real chip are in
+``tests/test_tpu_compile.py``.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch llama3.2-1b \

@@ -11,20 +11,20 @@ from typing import Optional, Tuple
 
 import jax
 
-from ..core.compat import make_mesh, auto_axis_types
+from ..core.compat import make_mesh
 from ..core.pcontext import ParallelCtx, single_pod_ctx, multi_pod_ctx
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=auto_axis_types(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 4),
                    axes: Tuple[str, ...] = ("data", "model")):
     """Small mesh for multi-host-device tests."""
-    return make_mesh(shape, axes, axis_types=auto_axis_types(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_ctx(mesh, *, ar_strategy: str = "flat",

@@ -1,7 +1,9 @@
 import os
 if "XLA_FLAGS" not in os.environ:
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-"""Roofline analysis per (arch x shape x mesh) — deliverable (g).
+"""Roofline analysis per (arch x shape x mesh) — deliverable (g).  A
+CPU-only tool: it compiles on 512 forced host CPU devices (set above,
+before JAX loads) and models the chip; nothing here runs on a TPU.
 
 Terms (per chip, TPU v5e):
     compute    = HLO_FLOPs / 197 TFLOP/s

@@ -51,6 +51,7 @@ from ..configs import get_config, get_smoke, ARCH_IDS
 from ..core.pcontext import AR_STRATEGIES, SEQ_PARALLEL_MODES
 from ..inference.router import Router
 from ..inference.scheduler import make_prefix_trace, make_trace
+from .compile_cache import enable_compile_cache
 from ..inference.spec import (PREFIX_MODES, ROUTER_POLICIES, ServeSpec,
                               SpecError, build_engine, build_replica)
 
@@ -434,6 +435,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     try:
         spec = ServeSpec.from_args(args)
     except SpecError as e:

@@ -22,6 +22,7 @@ roofline).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -127,19 +128,26 @@ def _stack(blocks):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *blocks)
 
 
+@functools.partial(jax.jit, static_argnames="ap")
 def init_params(key, ap: ArchPlan) -> Params:
+    """Random weights for ``ap``'s model, layers stacked on a leading axis.
+
+    Each stack is built by ``lax.map`` over the layer keys: one layer at a
+    time is written into its slot, so no second copy of the stack is ever
+    held.  Compiled once per plan; wrap it in ``jax.jit(...,
+    out_shardings=...)`` (``parallel.steps.serve_param_shardings``) to
+    create every leaf where it lives."""
     cfg = ap.cfg
-    keys = split_keys(key, cfg.n_layers + cfg.enc_layers + 2)
+    keys = jax.random.split(key, cfg.n_layers + cfg.enc_layers + 2)
+    off = 1 + cfg.n_layers
     p: Params = {
         "embed": L.init_embed(keys[0], cfg, ap.vocab_pad),
-        "blocks": _stack([_init_block(keys[1 + i], ap)
-                          for i in range(cfg.n_layers)]),
+        "blocks": lax.map(lambda k: _init_block(k, ap), keys[1:off]),
         "final_norm": L.init_norm(cfg),
     }
     if cfg.enc_layers:
-        off = 1 + cfg.n_layers
-        p["enc_blocks"] = _stack([_init_enc_block(keys[off + i], ap)
-                                  for i in range(cfg.enc_layers)])
+        p["enc_blocks"] = lax.map(lambda k: _init_enc_block(k, ap),
+                                  keys[off:off + cfg.enc_layers])
         p["enc_norm"] = L.init_norm(cfg)
     return p
 
@@ -777,6 +785,32 @@ def block_decode(bp: Params, x, cache_l: Params, ap: ArchPlan,
     return x, new_c
 
 
+def _scan_layers_in_place(body, x, blocks, cache):
+    """``lax.scan`` of ``body(x, (layer_params, layer_cache)) -> (x,
+    new_layer_cache)`` over the layers, with the stacked cache carried
+    through the loop and each layer's slice written back where it was read.
+    Scanning the cache as ``xs`` and collecting it as ``ys`` would give XLA
+    a second stacked cache to fill (a full copy of the KV pool alive at
+    once); carried, the donated cache is updated in place, one layer's
+    slice at a time.  Same per-layer math, same values."""
+    n_layers = jax.tree.leaves(blocks)[0].shape[0]
+
+    def step(carry, inp):
+        x, cache = carry
+        i, bp = inp
+        cl = jax.tree.map(
+            lambda t: lax.dynamic_index_in_dim(t, i, keepdims=False), cache)
+        x, nc = body(x, (bp, cl))
+        cache = jax.tree.map(
+            lambda t, u: lax.dynamic_update_index_in_dim(
+                t, u.astype(t.dtype), i, 0), cache, nc)
+        return (x, cache), None
+
+    (x, cache), _ = lax.scan(
+        step, (x, cache), (jnp.arange(n_layers, dtype=jnp.int32), blocks))
+    return x, cache
+
+
 def decode_step(params: Params, cache: Params, tokens, positions,
                 ap: ArchPlan, ctx: ParallelCtx, *,
                 scan_layers: bool = True, layer_map=None,
@@ -806,7 +840,8 @@ def decode_step(params: Params, cache: Params, tokens, positions,
         return x, nc
 
     if scan_layers:
-        x, new_cache = lax.scan(body, x, (params["blocks"], cache))
+        x, new_cache = _scan_layers_in_place(body, x, params["blocks"],
+                                             cache)
     else:
         ncs = []
         for i in range(cfg.n_layers):
@@ -893,7 +928,8 @@ def prefill_chunk(params: Params, cache: Params, tokens, positions,
         return x, kv_new
 
     if scan_layers:
-        x, new_cache = lax.scan(body, x, (params["blocks"], kv_cache))
+        x, new_cache = _scan_layers_in_place(body, x, params["blocks"],
+                                             kv_cache)
     else:
         ncs = []
         for i in range(cfg.n_layers):

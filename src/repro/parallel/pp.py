@@ -22,7 +22,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..core.compat import shard_map
+from jax import shard_map
 from ..core.pcontext import ParallelCtx
 from ..core import hierarchical as hier
 from ..models.transformer import ArchPlan, block_forward

@@ -42,9 +42,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec as P, NamedSharding
+from jax import shard_map
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
-from ..core.compat import shard_map
 from ..core.pcontext import ParallelCtx, LOCAL
 from ..core import autotune
 from ..core import hierarchical as hier
@@ -435,6 +436,30 @@ def _serve_params(ap: ArchPlan, serve_ctx, mesh, fsdp_serve):
         return full
 
     return pspecs, fdims, layer_map, full_params
+
+
+def local_sharding(mesh, device=None):
+    """Where a mesh-less replica's arrays live: ``device`` (the first
+    device when None).  None with a mesh, whose steps place their own."""
+    if mesh is not None:
+        return None
+    return SingleDeviceSharding(
+        jax.devices()[0] if device is None else device)
+
+
+def serve_param_shardings(ap: ArchPlan, ctx: ParallelCtx, mesh, *,
+                          fsdp_serve: bool = False, device=None):
+    """Where each parameter leaf of a serving replica lives: the serve
+    steps' param specs on ``mesh``, or the one ``device`` of a mesh-less
+    replica (one sharding for the whole tree).  Used as ``out_shardings``
+    of the weight init and as the target of ``jax.device_put`` for weights
+    a caller hands in, so a step never re-lays its weights out per call."""
+    if mesh is None:
+        return local_sharding(None, device)
+    pspecs = _serve_params(ap, _serve_ctx(ctx, mesh, fsdp_serve), mesh,
+                           fsdp_serve)[0]
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
+                        is_leaf=lambda x: isinstance(x, P))
 
 
 def _full_vocab(logits, serve_ctx: ParallelCtx):
@@ -961,6 +986,7 @@ def build_admit_chunk_step(ap: ArchPlan, ctx: ParallelCtx, mesh, *,
 
 
 __all__ = ["build_train_step", "build_decode_step", "build_prefill",
-           "build_cache_init", "build_serve_step", "build_admit_step",
-           "build_admit_chunk_step", "build_spec_verify_step",
-           "build_prefill_only_step", "build_kv_splice_step", "BuiltStep"]
+           "local_sharding", "serve_param_shardings", "build_cache_init",
+           "build_serve_step", "build_admit_step", "build_admit_chunk_step",
+           "build_spec_verify_step", "build_prefill_only_step",
+           "build_kv_splice_step", "BuiltStep"]

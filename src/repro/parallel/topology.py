@@ -42,14 +42,13 @@ def mesh_and_ctx(tp: int, pods: int = 1, *, ar_strategy: str = "flat",
     if devices is not None and len(devices) != tp:
         raise ValueError(f"device subset holds {len(devices)} devices, "
                          f"need exactly tp={tp}")
-    from ..core.compat import AxisType, make_mesh
+    from ..core.compat import make_mesh
     if pods > 1:
-        mesh = make_mesh((pods, tp // pods), ("pod", "model"),
-                         axis_types=(AxisType.Auto,) * 2, devices=devices)
+        mesh = make_mesh((pods, tp // pods), ("pod", "model"), devices=devices)
         ctx = ctx.replace(tp_fast=("model",), tp_slow=("pod",),
                           ep=("model",))
     else:
-        mesh = make_mesh((tp,), ("model",), axis_types=(AxisType.Auto,),
+        mesh = make_mesh((tp,), ("model",),
                          devices=devices)
         ctx = ctx.replace(tp_fast=("model",), ep=("model",))
     return mesh, ctx, tp
@@ -60,13 +59,13 @@ def replica_device_groups(n_replicas: int, tp: int,
     """Carve the device pool into ``n_replicas`` disjoint contiguous
     groups of ``tp`` devices each (replica i owns ``[i*tp, (i+1)*tp)``).
 
-    With ``tp == 1`` replicas run the local (mesh-less) engine path and
-    need no devices of their own — returns ``[None] * n_replicas``.
+    With ``tp == 1`` replica i runs the local (mesh-less) engine path on
+    ``devices[i]`` alone.  A caller that means to stack several replicas
+    on one device (a CPU test) passes that device repeated in ``devices``.
     """
     if n_replicas < 1:
         raise ValueError(f"need n_replicas >= 1, got {n_replicas}")
-    if tp <= 1:
-        return [None] * n_replicas
+    tp = max(tp, 1)
     pool = list(jax.devices()) if devices is None else list(devices)
     need = n_replicas * tp
     if len(pool) < need:

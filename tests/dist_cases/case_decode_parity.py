@@ -2,12 +2,12 @@
 model, across TP layouts including cross-pod TP with hierarchical RD."""
 import numpy as np, jax, jax.numpy as jnp
 from jax import lax
-from repro.core.compat import AxisType, make_mesh
+from repro.core.compat import make_mesh
 from repro.models import ModelConfig, make_plan, init_params, init_cache, forward_lm, decode_step
 from repro.core import LOCAL, ParallelCtx
 from repro.parallel.steps import build_decode_step, build_prefill
 
-mesh = make_mesh((2, 4), ("pod", "model"), axis_types=(AxisType.Auto,)*2)
+mesh = make_mesh((2, 4), ("pod", "model"))
 
 def tiny(family, **kw):
     base = dict(name=f"tiny-{family}", family=family, n_layers=2, d_model=64,

@@ -3,7 +3,7 @@ mesh (different DP width) and onto (1,4) (different TP width), continue
 training — loss stays continuous in all cases."""
 import tempfile, os
 import numpy as np, jax, jax.numpy as jnp
-from repro.core.compat import AxisType, make_mesh
+from repro.core.compat import make_mesh
 from repro.configs import get_smoke
 from repro.core.pcontext import ParallelCtx
 from repro.models.transformer import make_plan, init_params
@@ -17,8 +17,7 @@ cfg = get_smoke("llama3.2-1b")
 data = SyntheticLMData(cfg.vocab_size, 16, 8, seed=3)
 
 def make(mesh_shape, tp):
-    mesh = make_mesh(mesh_shape, ("data", "model"),
-                         axis_types=(AxisType.Auto,)*2)
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     ctx = ParallelCtx(tp_fast=("model",), dp=("data",), fsdp=("data",),
                       ep=("model",), sp=("model",))
     ap = make_plan(cfg, tp)
@@ -43,8 +42,7 @@ with tempfile.TemporaryDirectory() as d:
     # NOTE tp changes the padded weight LAYOUT; elastic restarts must keep
     # the same TP degree or re-materialize weights.  Here we restore onto a
     # mesh with the same tp=2 grouped differently:
-    mesh2 = make_mesh((4, 2), ("data", "model")[:2],
-                          axis_types=(AxisType.Auto,)*2) if False else None
+    mesh2 = make_mesh((4, 2), ("data", "model")[:2]) if False else None
 
     mesh3, ctx3, ap3, built3 = make((1, 2), 2)   # tp=2 kept, dp 2->1
     from jax.sharding import NamedSharding

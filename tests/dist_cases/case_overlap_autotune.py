@@ -14,12 +14,13 @@ and the autotuned dispatcher:
 import numpy as np, jax, jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from repro.core.compat import AxisType, make_mesh, shard_map
+from jax import shard_map
+from repro.core.compat import make_mesh
 from repro.core import (collective_matmul, collective_matmul_reduce_scatter,
                         rd_all_reduce, tp_all_reduce, tp_reduce_scatter,
                         ParallelCtx, autotune)
 
-mesh = make_mesh((2, 4), ("pod", "model"), axis_types=(AxisType.Auto,) * 2)
+mesh = make_mesh((2, 4), ("pod", "model"))
 rng = np.random.default_rng(0)
 
 B, S, F, D = 2, 3, 32, 64   # F: sharded contraction dim, D: output features
@@ -65,7 +66,7 @@ np.testing.assert_allclose(np.asarray(jax.jit(fa)(o8, wo)),
 print("collective_matmul attention-spec OK")
 
 # --- rd_all_reduce chunked-path edge cases ---------------------------------
-mesh8 = make_mesh((8,), ("pd",), axis_types=(AxisType.Auto,))
+mesh8 = make_mesh((8,), ("pd",))
 
 
 def run8(fn, xv):
@@ -82,7 +83,7 @@ for chunks in (1, 2, 3, 5, 64, 1000):   # 63 % 3 != 0; 1000 > payload
 print("rd_all_reduce chunk edge cases OK")
 
 # non-power-of-two axis falls back to psum (with chunking requested too)
-mesh3 = make_mesh((3,), ("m",), axis_types=(AxisType.Auto,))
+mesh3 = make_mesh((3,), ("m",))
 x3 = rng.standard_normal((6, 5)).astype(np.float32)
 f3 = shard_map(lambda v: rd_all_reduce(v, "m", chunks=4), mesh=mesh3,
                in_specs=P("m"), out_specs=P("m"), check_vma=False)
@@ -173,22 +174,17 @@ assert np.array_equal(qtoks["plain"], qtoks["overlap"]), \
     "lossy compress_slow + weight-quant decode must not depend on overlap"
 print("weight-quant dequant under TP x lossy overlap rule OK")
 
-# --- fused Pallas GEMM+RD kernel (interpret mode; gated on support) --------
-from repro.core.compat import tpu_interpret_params
-interp = tpu_interpret_params()
-if interp is None:
-    print("fused pallas collective matmul SKIPPED (installed pallas has no "
-          "TPU interpret mode for remote DMA)")
-else:
-    from repro.kernels.rd_allreduce.fused_matmul import (
-        collective_matmul_pallas)
-    ctx_k = ParallelCtx(tp_fast=("model",), tp_slow=("pod",),
-                        ar_strategy="hier_rd")
-    fkm = shard_map(
-        lambda xs, ws: collective_matmul_pallas(
-            xs, ws, ctx_k, spec="bsf,fd->bsd", chunks=2, interpret=interp),
-        mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False)
-    np.testing.assert_allclose(np.asarray(jax.jit(fkm)(x, w)), ref,
-                               rtol=1e-4, atol=1e-4)
-    print("fused pallas collective matmul OK")
+# --- fused Pallas GEMM+RD kernel (TPU interpret mode) ----------------------
+from jax.experimental.pallas import tpu as pltpu
+from repro.kernels.rd_allreduce.fused_matmul import collective_matmul_pallas
+ctx_k = ParallelCtx(tp_fast=("model",), tp_slow=("pod",),
+                    ar_strategy="hier_rd")
+fkm = shard_map(
+    lambda xs, ws: collective_matmul_pallas(
+        xs, ws, ctx_k, spec="bsf,fd->bsd", chunks=2,
+        interpret=pltpu.InterpretParams()),
+    mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False)
+np.testing.assert_allclose(np.asarray(jax.jit(fkm)(x, w)), ref,
+                           rtol=1e-4, atol=1e-4)
+print("fused pallas collective matmul OK")
 print("overlap+autotune OK")

@@ -1,7 +1,7 @@
 """Hybrid parallelism (GPipe PP x TP) parity + perf-feature parity on 8
 simulated devices: quantized all-gather, SP prefill, cross-pod int8 RD."""
 import numpy as np, jax, jax.numpy as jnp
-from repro.core.compat import AxisType, make_mesh
+from repro.core.compat import make_mesh
 from repro.models import ModelConfig, make_plan, init_params, forward_lm
 from repro.core import LOCAL, ParallelCtx
 from repro.parallel.pp import build_pp_forward
@@ -10,7 +10,7 @@ from repro.parallel.steps import build_prefill, build_decode_step
 cfg = ModelConfig(name="t", family="dense", n_layers=4, d_model=64, n_heads=4,
                   n_kv_heads=2, head_dim=16, d_ff=128, vocab_size=96,
                   dtype=jnp.float32)
-mesh = make_mesh((2, 4), ("pod", "model"), axis_types=(AxisType.Auto,)*2)
+mesh = make_mesh((2, 4), ("pod", "model"))
 tok = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 96)
 
 # --- PP x TP (the paper's HP scheme) vs local ------------------------------

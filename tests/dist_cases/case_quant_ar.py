@@ -4,12 +4,13 @@ parity against the fp strategy, and the serve stack end-to-end."""
 import numpy as np, jax, jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from repro.core.compat import AxisType, make_mesh, shard_map
+from jax import shard_map
+from repro.core.compat import make_mesh
 from repro.core import ParallelCtx, tp_all_reduce
 from repro.core import hierarchical as hier
 from repro.core import overlap as ov
 
-mesh = make_mesh((2, 4), ("pod", "model"), axis_types=(AxisType.Auto,) * 2)
+mesh = make_mesh((2, 4), ("pod", "model"))
 rng = np.random.default_rng(0)
 
 

@@ -1,7 +1,7 @@
 """Sharded train step: loss parity with the local model + learning + RD /
 int8-RD cross-pod gradient strategies."""
 import numpy as np, jax, jax.numpy as jnp
-from repro.core.compat import AxisType, make_mesh
+from repro.core.compat import make_mesh
 from repro.models import ModelConfig, make_plan, init_params, forward_lm
 from repro.models.layers import sharded_xent
 from repro.core import LOCAL, ParallelCtx
@@ -22,7 +22,7 @@ lab = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, 96)
 batch = {"tokens": tok, "labels": lab}
 
 def run(cfg, mesh_shape, axes, ctx, tp, mb, label):
-    mesh = make_mesh(mesh_shape, axes, axis_types=(AxisType.Auto,)*len(axes))
+    mesh = make_mesh(mesh_shape, axes)
     ap = make_plan(cfg, tp)
     params = init_params(key, ap)
     opt = adamw_init(params)

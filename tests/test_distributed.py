@@ -79,3 +79,9 @@ def test_quant_allreduce(dist_runner):
 def test_router_fleet(dist_runner):
     out = dist_runner("case_router.py")
     assert "router OK" in out
+
+
+@pytest.mark.dist
+def test_replica_devices(dist_runner):
+    out = dist_runner("case_replica_devices.py", 4)
+    assert "replica devices OK" in out

@@ -22,11 +22,10 @@ CASES = [
 def test_mini_dryrun_cell(dist_runner, arch, shape, opt):
     script = f"""
 import jax
-from repro.core.compat import AxisType, make_mesh
+from repro.core.compat import make_mesh
 from repro.launch.input_specs import build_cell
 from repro.launch.hlo_analysis import summarize_compiled
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"),
-                     axis_types=(AxisType.Auto,) * 3)
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cell = build_cell({arch!r}, {shape!r}, mesh,
                   ar_strategy={opt.get("strategy", "flat")!r},
                   cross_pod_tp={opt.get("cross", False)!r})

@@ -316,6 +316,25 @@ def test_fleet_fault_isolation_parity(tiny_lm):
                   fault_plan="nan_logits=0.3,seed=5")
 
 
+def test_one_device_replicas_need_a_device_each(tiny_lm):
+    """tp=1 replicas are carved a device each, like tp>1 ones: too few
+    devices is an error, and stacking them on one device is spelled out."""
+    from repro.parallel.topology import replica_device_groups
+    dev = jax.devices()[0]
+    with pytest.raises(ValueError, match="needs 2 devices"):
+        replica_device_groups(2, 1, [dev])
+    assert replica_device_groups(2, 1, [dev, dev]) == [[dev], [dev]]
+    cfg, ap, params = tiny_lm
+    fleet = Router.from_spec(ServeSpec(replica=RS, replicas=2), ap=ap,
+                             params=params, devices=[dev, dev])
+    for rep in fleet.replicas:
+        assert {d for leaf in jax.tree.leaves((rep.params, rep.cache))
+                for d in leaf.devices()} == {dev}
+    reqs = make_trace(4, mean_in=10, mean_out=4, rate=4.0,
+                      vocab=cfg.vocab_size, seed=1)
+    assert fleet.metrics(fleet.run(reqs)).fleet.completed == 4
+
+
 def test_policies_complete_bursty_trace(tiny_lm):
     cfg, ap, params = tiny_lm
     reqs = make_trace(10, mean_in=10, mean_out=6, rate=8.0,

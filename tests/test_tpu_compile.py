@@ -1,0 +1,195 @@
+"""Ahead-of-time compiles for a described TPU v5e 2x2, with no chip
+attached: the served decode step at codeqwen1.5-7b widths on one chip and
+at tp=4, and the Pallas kernels.  The chip's compiler runs here, so what it
+refuses (a tiling, a VMEM budget, a program that does not fit) fails in
+this file at no chip time.  Nothing runs: results and times need a chip.
+
+The topology is described inside module-scoped fixtures only, never at
+import time (one process at a time may load the TPU library), and the
+persistent compilation cache is off around these compiles (an entry
+written for a described chip cannot be read back without one).
+"""
+from __future__ import annotations
+
+import dataclasses
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from repro.configs import get_config
+from repro.core.pcontext import LOCAL, ParallelCtx
+from repro.models.transformer import init_cache, init_params, make_plan
+from repro.parallel.steps import build_serve_step
+from repro.parallel.topology import mesh_and_ctx
+
+V5E_HBM = 16 * 2**30
+SLOTS, S_MAX, BLOCK = 8, 2048, 16
+N_BLOCKS = SLOTS * (S_MAX // BLOCK) + 1
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        try:
+            t = topologies.get_topology_desc(platform="tpu",
+                                             topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler installed (jax[cpu])
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield t
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+        cc.reset_cache()
+        if log_dir is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def meshes(topo):
+    devs = np.asarray(topo.devices[:4])
+    return {"4": Mesh(devs, ("model",), axis_types=(AxisType.Auto,)),
+            "2x2": Mesh(devs.reshape(2, 2), ("pod", "model"),
+                        axis_types=(AxisType.Auto,) * 2)}
+
+
+def _codeqwen(n_layers: int, tp: int):
+    cfg = dataclasses.replace(get_config("codeqwen1.5-7b"), n_layers=n_layers)
+    return make_plan(cfg, tp)
+
+
+def _serve_args(ap, *, local: bool):
+    params = jax.eval_shape(lambda k: init_params(k, ap),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: init_cache(
+        ap, SLOTS, S_MAX, local=local, block_size=BLOCK, n_blocks=N_BLOCKS))
+    i32 = jax.ShapeDtypeStruct((SLOTS,), jnp.int32)
+    state = {"tokens": i32, "positions": i32, "remaining": i32,
+             "active": jax.ShapeDtypeStruct((SLOTS,), jnp.bool_),
+             "rng": jax.ShapeDtypeStruct((SLOTS, 2), jnp.uint32),
+             "sample_idx": i32}
+    return params, cache, state
+
+
+def _placed(tree, shardings):
+    """ShapeDtypeStructs of ``tree`` carrying ``shardings`` (one sharding
+    for every leaf, or a pytree prefix of PartitionSpecs on a mesh)."""
+    if isinstance(shardings, SingleDeviceSharding):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=shardings), tree)
+    mesh, specs = shardings
+    return jax.tree.map(
+        lambda spec, s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, spec)),
+        specs, tree, is_leaf=lambda x: isinstance(x, P))
+
+
+def _bytes_on_device(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+
+
+def test_one_chip_serve_step_fits(one_chip):
+    """The scanned one-chip decode step at codeqwen widths (2 layers)."""
+    ap = _codeqwen(2, 1)
+    step = build_serve_step(ap, LOCAL, None, s_max=S_MAX, slots=SLOTS,
+                            block_size=BLOCK, n_blocks=N_BLOCKS)
+    args = [_placed(a, one_chip) for a in _serve_args(ap, local=True)]
+    compiled = step.jit().lower(*args).compile()
+    used = _bytes_on_device(compiled)
+    # 2 layers (0.93 GB) + embedding and head (1.51 GB) + their K/V pool
+    assert 2.4e9 < used < V5E_HBM, used
+
+
+def test_tp4_serve_step_on_pod_model_mesh(meshes):
+    """The tp=4 decode step on the (pod=2, model=2) mesh with hier_rd:
+    weights and cache split four ways, the RD exchange a collective-
+    permute."""
+    mesh = meshes["2x2"]
+    _, ctx, _ = mesh_and_ctx(4, 2, ar_strategy="hier_rd",
+                             devices=list(mesh.devices.flat))
+    ap = _codeqwen(2, 4)
+    step = build_serve_step(ap, ctx, mesh, s_max=S_MAX, slots=SLOTS,
+                            block_size=BLOCK, n_blocks=N_BLOCKS)
+    args = [_placed(a, (mesh, spec)) for a, spec in
+            zip(_serve_args(ap, local=False), step.in_specs)]
+    compiled = step.jit().lower(*args).compile()
+    used = _bytes_on_device(compiled)
+    # a quarter of the one-chip footprint, plus replicated norms/biases
+    assert 0.5e9 < used < 1.2e9, used
+    assert "collective-permute" in compiled.as_text()
+
+
+@pytest.mark.parametrize("nbytes", [1024, 64 * 2**10, 2**20],
+                         ids=["1KiB", "64KiB", "1MiB"])
+@pytest.mark.parametrize("mesh_name,axis", [("4", "model"), ("2x2", "pod")])
+def test_rd_all_reduce_kernel_compiles(meshes, mesh_name, axis, nbytes):
+    """``rd_all_reduce_kernel_call`` through its wrapper, which lays a
+    bf16 message of ``nbytes`` per device out as whole tiles per chunk."""
+    from repro.kernels.rd_allreduce import rd_all_reduce_pallas
+    mesh = meshes[mesh_name]
+    every = P(mesh.axis_names)
+    f = shard_map(lambda v: rd_all_reduce_pallas(v, axis, n_chunks=4),
+                  mesh=mesh, in_specs=every, out_specs=every,
+                  check_vma=False)
+    x = jax.ShapeDtypeStruct((mesh.size * nbytes // 2,), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, every))
+    text = jax.jit(f).lower(x).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_fused_matmul_rd_kernel_compiles(meshes):
+    """The fused GEMM + slow-axis RD kernel on the (pod, model) mesh."""
+    from repro.kernels.rd_allreduce.fused_matmul import (
+        collective_matmul_pallas)
+    mesh = meshes["2x2"]
+    ctx = ParallelCtx(tp_fast=("model",), tp_slow=("pod",),
+                      ar_strategy="hier_rd")
+    B, F, D = 8, 4096, 4096      # F: sharded contraction dim
+    f = shard_map(lambda x, w: collective_matmul_pallas(
+        x, w, ctx, spec="bsf,fd->bsd", chunks=4),
+        mesh=mesh, in_specs=(P(None, None, ("pod", "model")),
+                             P(("pod", "model"), None)),
+        out_specs=P(), check_vma=False)
+    x = jax.ShapeDtypeStruct((B, 1, F), jnp.bfloat16, sharding=NamedSharding(
+        mesh, P(None, None, ("pod", "model"))))
+    w = jax.ShapeDtypeStruct((F, D), jnp.bfloat16, sharding=NamedSharding(
+        mesh, P(("pod", "model"), None)))
+    text = jax.jit(f).lower(x, w).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_attention_compiles(one_chip):
+    """The paged decode-attention kernel at head_dim 128 over the
+    one-chip cache geometry (32 heads, blocks of 16, s_max 2048)."""
+    from repro.kernels.decode_attention import paged_decode_attention
+    H, hd = 32, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = sds((SLOTS, H, hd), jnp.bfloat16)
+    kv = sds((N_BLOCKS, BLOCK, H, hd), jnp.bfloat16)
+    tbl = sds((SLOTS, S_MAX // BLOCK), jnp.int32)
+    pos = sds((SLOTS,), jnp.int32)
+    text = jax.jit(paged_decode_attention).lower(
+        q, kv, kv, tbl, pos).compile().as_text()
+    assert "tpu_custom_call" in text
