@@ -1,0 +1,11 @@
+"""Median time from a request's scheduled arrival to its first token,
+over every request due in the window, in milliseconds."""
+import numpy as np
+
+
+def read(run):
+    t = run.ttfts()
+    if not t.size:
+        return None
+    v = float(np.percentile(t, 50))
+    return v * 1e3 if np.isfinite(v) else None
