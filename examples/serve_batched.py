@@ -48,8 +48,8 @@ def main():
     m = sched.metrics(done)
     print(f"{m.completed} requests, {m.total_new_tokens} tokens in "
           f"{m.wall_s:.1f}s ({m.throughput_tok_s:.1f} tok/s)")
-    print(f"TTFT p50 {m.ttft_steps_p50:.1f} steps ({m.ttft_s_p50*1e3:.0f} "
-          f"ms), TPOT p50 {m.tpot_steps_p50:.2f} steps; KV peak "
+    print(f"TTFT p50 {m.ttft_steps_p50:.1f} steps, TPOT p50 "
+          f"{m.tpot_steps_p50:.2f} steps; KV peak "
           f"{m.peak_kv_tokens} of {args.slots * 192} dense tokens")
 
 

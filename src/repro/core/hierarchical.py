@@ -559,10 +559,11 @@ def tp_all_reduce(x: jax.Array, ctx: ParallelCtx, scatter_dim: int = -1,
     if not fast and not slow:
         return (x, ef) if ef is not None else x
     ctx = _resolve_auto(x, ctx)
-    if ctx.ar_quant in QUANT_BITS:
-        y, ef2 = _quant_tp_all_reduce(x, ctx, scatter_dim, ef)
-        return (y, ef2) if ef is not None else y
-    y = _tp_all_reduce_fp(x, ctx, scatter_dim)
+    with jax.named_scope("tp_all_reduce"):
+        if ctx.ar_quant in QUANT_BITS:
+            y, ef2 = _quant_tp_all_reduce(x, ctx, scatter_dim, ef)
+            return (y, ef2) if ef is not None else y
+        y = _tp_all_reduce_fp(x, ctx, scatter_dim)
     return (y, ef) if ef is not None else y
 
 
@@ -584,21 +585,22 @@ def tp_reduce_scatter(x: jax.Array, ctx: ParallelCtx,
         return x
     ctx = _resolve_auto(x, ctx)
     dim = dim % x.ndim
-    if ctx.ar_quant in QUANT_BITS and fast \
-            and _quant_scatter_ok(x, fast, dim, QUANT_BITS[ctx.ar_quant]):
-        bits = QUANT_BITS[ctx.ar_quant]
-        y, _ = _quant_reduce_scatter(x.astype(jnp.float32), fast, dim,
-                                     bits, want_err=False)
+    with jax.named_scope("tp_reduce_scatter"):
+        if ctx.ar_quant in QUANT_BITS and fast \
+                and _quant_scatter_ok(x, fast, dim, QUANT_BITS[ctx.ar_quant]):
+            bits = QUANT_BITS[ctx.ar_quant]
+            y, _ = _quant_reduce_scatter(x.astype(jnp.float32), fast, dim,
+                                         bits, want_err=False)
+            if slow:
+                y = _quant_slow_phase(y, slow, ctx, bits)
+            return y.astype(x.dtype)
+        if fast:
+            x = lax.psum_scatter(x, fast, scatter_dimension=dim, tiled=True)
         if slow:
-            y = _quant_slow_phase(y, slow, ctx, bits)
-        return y.astype(x.dtype)
-    if fast:
-        x = lax.psum_scatter(x, fast, scatter_dimension=dim, tiled=True)
-    if slow:
-        if ctx.ar_strategy == "flat":
-            x = lax.psum(x, slow)
-        else:
-            x = _slow_phase(x, slow, ctx)
+            if ctx.ar_strategy == "flat":
+                x = lax.psum(x, slow)
+            else:
+                x = _slow_phase(x, slow, ctx)
     return x
 
 
@@ -606,13 +608,14 @@ def tp_all_gather(x: jax.Array, ctx: ParallelCtx, dim: int) -> jax.Array:
     """Gather a sequence-sharded activation back to full along ``dim``."""
     if not ctx.tp_fast:
         return x
-    if ctx.ar_quant in QUANT_BITS:
-        return _quant_all_gather(x.astype(jnp.float32), ctx.tp_fast,
-                                 dim % x.ndim,
-                                 QUANT_BITS[ctx.ar_quant]).astype(x.dtype)
-    if ctx.quant_ag:
-        return quantized_all_gather(x, ctx.tp_fast, dim % x.ndim)
-    return lax.all_gather(x, ctx.tp_fast, axis=dim % x.ndim, tiled=True)
+    with jax.named_scope("tp_all_gather"):
+        if ctx.ar_quant in QUANT_BITS:
+            return _quant_all_gather(x.astype(jnp.float32), ctx.tp_fast,
+                                     dim % x.ndim,
+                                     QUANT_BITS[ctx.ar_quant]).astype(x.dtype)
+        if ctx.quant_ag:
+            return quantized_all_gather(x, ctx.tp_fast, dim % x.ndim)
+        return lax.all_gather(x, ctx.tp_fast, axis=dim % x.ndim, tiled=True)
 
 
 # ---------------------------------------------------------------------------

@@ -39,8 +39,17 @@ This is the *unified serving stack* over the paged KV-cache subsystem:
   drives the batcher step-by-step in that deployment (DESIGN.md §9).
 
 Scheduling time is a logical step clock (1.0 per engine step) so traces
-replay deterministically; wall-clock timestamps are recorded alongside for
-TTFT / TPOT reporting (see :class:`ServeMetrics`).
+replay deterministically; each request also carries wall-clock stamps
+(``time.perf_counter()`` seconds) of its admission, first token and
+release.
+
+Host time is named for a profiler trace by ``TraceAnnotation`` spans:
+``serve.tick``; ``serve.admit`` (keyword ``rid``, ``chunks``) with
+``.table``, ``.chunks`` and ``.first_token`` inside; ``serve.step`` with
+``.prepare``, ``.dispatch``, ``.readback`` and ``.commit`` inside (the
+speculative step is one ``serve.step``).  With the profiler off a span
+costs about a microsecond.  :meth:`ContinuousBatcher.op_scopes` names the
+device ops of the same trace by the model's ``jax.named_scope``.
 
 Invariants inherited from the cache layer (see ``kv_cache``): block-0 is
 trash (stale-slot writes are routed there, never read), and freed blocks
@@ -59,8 +68,10 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.pcontext import ParallelCtx, LOCAL
+from ..launch.hlo_analysis import module_name, op_scopes
 from ..parallel.steps import (build_admit_chunk_step, build_admit_step,
                               build_cache_init, build_kv_splice_step,
                               build_serve_step, build_spec_verify_step,
@@ -81,9 +92,10 @@ class Request:
     # TTFT deadline in logical steps from arrival (inf = none); expired
     # never-admitted requests are shed, not served (DESIGN.md §11)
     deadline_s: float = float("inf")
-    # filled by the scheduler:
-    first_token_s: float = -1.0  # wall-clock, relative to run() start
-    done_s: float = -1.0         # wall-clock, relative to run() start
+    # filled by the scheduler, wall clock (time.perf_counter() seconds):
+    admitted_s: float = -1.0     # the (last) admission began
+    first_token_s: float = -1.0  # the (last) admission handed out a token
+    done_s: float = -1.0         # released
     admit_step: int = -1         # logical step of (last) admission
     done_step: int = -1
     preempted: int = 0           # times evicted and recomputed
@@ -164,9 +176,9 @@ class ServeMetrics:
 
     TTFT (time-to-first-token) and TPOT (time-per-output-token) are
     reported in logical *steps* (deterministic: admission wait + prefill
-    counts 1 step) and converted to wall seconds via the measured mean
-    step time, so the numbers are stable under CI jitter but still carry
-    real units.
+    counts 1 step), so the numbers are stable under CI jitter.  Wall
+    times belong to a clock outside the scheduler: the stamps on each
+    :class:`Request`.
 
     Speculative-decoding fields (all zero when ``spec_mode`` is off):
 
@@ -195,10 +207,6 @@ class ServeMetrics:
     ttft_steps_p99: float
     tpot_steps_p50: float
     tpot_steps_p99: float
-    ttft_s_p50: float
-    ttft_s_p99: float
-    tpot_s_p50: float
-    tpot_s_p99: float
     preemptions: int
     peak_kv_tokens: int          # high-water cache footprint, in tokens
     kv_capacity_tokens: int      # reserved footprint of the layout
@@ -279,7 +287,6 @@ class ServeMetrics:
         tpot = [s for m in parts for s in m.tpot_steps_samples]
         steps = max(m.steps for m in parts)
         wall = max(m.wall_s for m in parts)
-        step_s = wall / steps if steps else 0.0
         total_new = sum(m.total_new_tokens for m in parts)
         drafted = sum(m.drafted_tokens for m in parts)
         accepted = sum(m.accepted_tokens for m in parts)
@@ -298,10 +305,6 @@ class ServeMetrics:
             ttft_steps_p99=_percentile(ttft, 99),
             tpot_steps_p50=_percentile(tpot, 50),
             tpot_steps_p99=_percentile(tpot, 99),
-            ttft_s_p50=_percentile(ttft, 50) * step_s,
-            ttft_s_p99=_percentile(ttft, 99) * step_s,
-            tpot_s_p50=_percentile(tpot, 50) * step_s,
-            tpot_s_p99=_percentile(tpot, 99) * step_s,
             preemptions=sum(m.preemptions for m in parts),
             peak_kv_tokens=peak,
             kv_capacity_tokens=sum(m.kv_capacity_tokens for m in parts),
@@ -620,60 +623,67 @@ class ContinuousBatcher:
         prompt-covered block is published back to the trie.  Ordering
         matters: the share happens *before* any trie reclaim, so
         allocation pressure can never evict the blocks just matched."""
+        req.admitted_s = time.perf_counter()
         S = int(req.prompt.shape[0])
         if S + 1 > self.s_max:
             raise ValueError(f"prompt len {S} + 1 exceeds s_max={self.s_max}")
-        shared_blocks: List[int] = []
-        if self.prefix is not None:
-            shared_blocks = self._prefix_match(req.prompt)
-            if shared_blocks:
-                self.alloc.share(slot, shared_blocks)
-        if self.alloc is not None:
-            # +1: the first decode write lands at position S
-            if self.prefix is not None \
-                    and not self.alloc.can_allocate(slot, S + 1):
-                # cold trie blocks before live traffic: reclaim only what
-                # the growth is short (matched blocks are slot-referenced
-                # now — unevictable)
-                need = self.alloc.blocks_for(S + 1) - \
-                    len(self.alloc.owned(slot))
-                self.prefix.reclaim(need - self.alloc.free_blocks)
-            if not self.alloc.ensure(slot, S + 1):
+        chunks = -(-S // self.admit_chunk) \
+            if self.admit_mode == "chunked" else 1
+        with TraceAnnotation("serve.admit", rid=req.rid, chunks=chunks):
+            shared_blocks: List[int] = []
+            if self.prefix is not None:
+                shared_blocks = self._prefix_match(req.prompt)
                 if shared_blocks:
-                    self.alloc.free(slot)   # drop just-taken references
-                return False
-            self._sync_table()
-        base = request_sampling_key(self.seed, req.rid)
-        first = jax.random.fold_in(base, 0)   # token 0 of the chain
-        if shared_blocks:
-            n_shared = len(shared_blocks) * self.block_size
-            self._prefix_hits += 1
-            self._prefix_tokens_saved += n_shared
-            tok, self.cache = run_chunked_prefill(
-                self.params, self.cache, req.prompt, slot,
-                self.admit_chunk, self._admit_chunked_mid,
-                self._admit_chunked, self._rng, first,
-                start_chunk=n_shared // self.admit_chunk)
-        elif self.admit_mode == "chunked":
-            tok, self.cache = run_chunked_prefill(
-                self.params, self.cache, req.prompt, slot,
-                self.admit_chunk, self._admit_chunked_mid,
-                self._admit_chunked, self._rng, first)
-        else:
-            tok, self.cache = self._admit_fn(S)(
-                self.params, self.cache, jnp.asarray(req.prompt[None]),
-                jnp.int32(slot), first)
-        if self.prefix is not None:
-            # publish before activation (max_new == 1 releases the slot
-            # inside _activate): every block whose positions are all
-            # prompt is pinned — decode writes land at >= S, never here
-            n_pub = S // self.block_size
-            if n_pub:
-                self.prefix.insert(req.prompt,
-                                   self.alloc.owned(slot)[:n_pub])
-        self._activate(slot, req, int(np.asarray(tok)[0]), S, now,
-                       rng_key=base)
-        return True
+                    self.alloc.share(slot, shared_blocks)
+            if self.alloc is not None:
+                # +1: the first decode write lands at position S
+                if self.prefix is not None \
+                        and not self.alloc.can_allocate(slot, S + 1):
+                    # cold trie blocks before live traffic: reclaim only what
+                    # the growth is short (matched blocks are slot-referenced
+                    # now — unevictable)
+                    need = self.alloc.blocks_for(S + 1) - \
+                        len(self.alloc.owned(slot))
+                    self.prefix.reclaim(need - self.alloc.free_blocks)
+                if not self.alloc.ensure(slot, S + 1):
+                    if shared_blocks:
+                        self.alloc.free(slot)   # drop just-taken references
+                    return False
+                with TraceAnnotation("serve.admit.table"):
+                    self._sync_table()
+            base = request_sampling_key(self.seed, req.rid)
+            first = jax.random.fold_in(base, 0)   # token 0 of the chain
+            with TraceAnnotation("serve.admit.chunks"):
+                if shared_blocks:
+                    n_shared = len(shared_blocks) * self.block_size
+                    self._prefix_hits += 1
+                    self._prefix_tokens_saved += n_shared
+                    tok, self.cache = run_chunked_prefill(
+                        self.params, self.cache, req.prompt, slot,
+                        self.admit_chunk, self._admit_chunked_mid,
+                        self._admit_chunked, self._rng, first,
+                        start_chunk=n_shared // self.admit_chunk)
+                elif self.admit_mode == "chunked":
+                    tok, self.cache = run_chunked_prefill(
+                        self.params, self.cache, req.prompt, slot,
+                        self.admit_chunk, self._admit_chunked_mid,
+                        self._admit_chunked, self._rng, first)
+                else:
+                    tok, self.cache = self._admit_fn(S)(
+                        self.params, self.cache, jnp.asarray(req.prompt[None]),
+                        jnp.int32(slot), first)
+            if self.prefix is not None:
+                # publish before activation (max_new == 1 releases the slot
+                # inside _activate): every block whose positions are all
+                # prompt is pinned — decode writes land at >= S, never here
+                n_pub = S // self.block_size
+                if n_pub:
+                    self.prefix.insert(req.prompt,
+                                       self.alloc.owned(slot)[:n_pub])
+            with TraceAnnotation("serve.admit.first_token"):
+                nxt = int(np.asarray(tok)[0])
+            self._activate(slot, req, nxt, S, now, rng_key=base)
+            return True
 
     def _activate(self, slot: int, req: Request, nxt: int, S: int,
                   now: float, rng_key=None) -> None:
@@ -698,7 +708,7 @@ class ContinuousBatcher:
         self._seq += 1
         self.outputs[req.rid] = [nxt]
         req.admit_step = int(now)
-        req.first_token_s = time.perf_counter() - self._wall0
+        req.first_token_s = time.perf_counter()
         self._dirty = True
         if self.remaining[slot] == 0:   # max_new == 1: prefill token only
             self._release(slot, now)
@@ -724,6 +734,7 @@ class ContinuousBatcher:
         Raises :class:`~repro.inference.kv_cache.BundleIntegrityError`
         (before any state change) when a sealed bundle's checksum does not
         match — in-flight corruption; the coordinator re-prefills."""
+        req.admitted_s = time.perf_counter()
         bundle.verify()
         S = bundle.n_tokens
         if S + 1 > self.s_max:
@@ -746,7 +757,7 @@ class ContinuousBatcher:
 
     def _release(self, slot: int, now: float):
         req = self.active[slot]
-        req.done_s = time.perf_counter() - self._wall0
+        req.done_s = time.perf_counter()
         req.done_step = int(now)
         req.output = np.asarray(self.outputs[req.rid], np.int32)
         self.active[slot] = None
@@ -1053,57 +1064,63 @@ class ContinuousBatcher:
 
     def step(self, now: float):
         """One decode step over all slots (no-op when none active)."""
-        if self.spec_mode:
-            return self._spec_step(now)
-        return self._plain_step(now)
+        with TraceAnnotation("serve.step"):
+            if self.spec_mode:
+                return self._spec_step(now)
+            return self._plain_step(now)
 
     def _plain_step(self, now: float, faults_done: bool = False):
         """One plain decode step (``faults_done``: the spec path already
         ran this step's fault hooks before falling back here)."""
         if not self.active_mask.any():
             return
-        if not faults_done:
-            self._pre_step_faults(now)
-            if not self.active_mask.any():   # faults evicted every slot
-                return
-        if self.alloc is not None:
-            for s in range(self.slots):
-                # growth only at block boundaries: next write position is
-                # positions[s], covered unless it opens a fresh block
-                if self.active_mask[s] \
-                        and self.positions[s] % self.block_size == 0:
-                    self._ensure_growth(s)
-            if not self.active_mask.any():   # OOM burst evicted them all
-                return
-        occ = int(self.positions[self.active_mask].sum()) + \
-            int(self.active_mask.sum())
-        self._peak_occupied = max(self._peak_occupied, occ)
-        if self._dirty:
-            self._push_state()
-        was_active = self.active_mask.copy()
-        emitted, done, finite, self._state, self.cache = self._serve(
-            self.params, self.cache, self._state)
-        emitted = np.asarray(emitted)
-        done = np.asarray(done)
-        finite = np.asarray(finite)
-        self.steps_run += 1
-        for s in range(self.slots):
-            if not was_active[s]:
-                continue
-            if not finite[s]:
-                # drop this step's garbage token and recompute the whole
-                # request through the preemption path — exact replay
-                self._quarantine(s)
-                continue
-            self.outputs[self.active[s].rid].append(int(emitted[s]))
-            self.tokens[s] = emitted[s]
-            self.positions[s] += 1
-            self.remaining[s] -= 1
-            self.sample_idx[s] += 1
+        with TraceAnnotation("serve.step.prepare"):
+            if not faults_done:
+                self._pre_step_faults(now)
+                if not self.active_mask.any():   # faults evicted every slot
+                    return
             if self.alloc is not None:
-                self.alloc.note_usage(s, int(self.positions[s]))
-            if done[s]:
-                self._release(s, now)
+                for s in range(self.slots):
+                    # growth only at block boundaries: next write position
+                    # is positions[s], covered unless it opens a fresh block
+                    if self.active_mask[s] \
+                            and self.positions[s] % self.block_size == 0:
+                        self._ensure_growth(s)
+                if not self.active_mask.any():   # OOM burst evicted them all
+                    return
+            occ = int(self.positions[self.active_mask].sum()) + \
+                int(self.active_mask.sum())
+            self._peak_occupied = max(self._peak_occupied, occ)
+            if self._dirty:
+                self._push_state()
+        was_active = self.active_mask.copy()
+        with TraceAnnotation("serve.step.dispatch"):
+            emitted, done, finite, self._state, self.cache = self._serve(
+                self.params, self.cache, self._state)
+        with TraceAnnotation("serve.step.readback"):
+            emitted = np.asarray(emitted)
+            done = np.asarray(done)
+            finite = np.asarray(finite)
+        self.steps_run += 1
+        with TraceAnnotation("serve.step.commit"):
+            for s in range(self.slots):
+                if not was_active[s]:
+                    continue
+                if not finite[s]:
+                    # drop this step's garbage token and recompute the
+                    # whole request through the preemption path — exact
+                    # replay
+                    self._quarantine(s)
+                    continue
+                self.outputs[self.active[s].rid].append(int(emitted[s]))
+                self.tokens[s] = emitted[s]
+                self.positions[s] += 1
+                self.remaining[s] -= 1
+                self.sample_idx[s] += 1
+                if self.alloc is not None:
+                    self.alloc.note_usage(s, int(self.positions[s]))
+                if done[s]:
+                    self._release(s, now)
 
     # -- trace replay --------------------------------------------------------
 
@@ -1162,23 +1179,24 @@ class ContinuousBatcher:
         external driver (``inference.router.Router``) owns a per-replica
         queue and calls this directly — one code path, so a routed
         replica schedules exactly like a standalone batcher."""
-        expired = [r for r in arrived
-                   if now - r.arrival_s > self._deadline(r)]
-        for r in expired:
-            self._shed_req(r, now, "deadline")
-            arrived.remove(r)
-        # admit preempted requests first, then due arrivals
-        for s in range(self.slots):
-            if self.active[s] is not None:
-                continue
-            if self._requeue:
-                if self._admit(s, self._requeue[0], now):
-                    self._requeue.pop(0)
-                continue
-            if arrived:
-                if self._admit(s, arrived[0], now):
-                    arrived.pop(0)
-        self.step(now)
+        with TraceAnnotation("serve.tick"):
+            expired = [r for r in arrived
+                       if now - r.arrival_s > self._deadline(r)]
+            for r in expired:
+                self._shed_req(r, now, "deadline")
+                arrived.remove(r)
+            # admit preempted requests first, then due arrivals
+            for s in range(self.slots):
+                if self.active[s] is not None:
+                    continue
+                if self._requeue:
+                    if self._admit(s, self._requeue[0], now):
+                        self._requeue.pop(0)
+                    continue
+                if arrived:
+                    if self._admit(s, arrived[0], now):
+                        arrived.pop(0)
+            self.step(now)
 
     def drained(self, arrived: List[Request]) -> bool:
         """No queued, requeued, or active work left for this batcher."""
@@ -1227,11 +1245,48 @@ class ContinuousBatcher:
         self.cache["v"] = jnp.take(self.cache["v"], p, axis=1)
         self._sync_table()
 
+    def op_scopes(self) -> Dict[str, List[Dict[str, str]]]:
+        """The model scope (``hlo_analysis.SCOPES``) of each instruction of
+        the executables that serve this batcher: the plain decode step
+        and, with chunked admission, the middle- and final-chunk
+        executables, each built, lowered and compiled anew on the
+        arguments it runs with (the same program, so the same instruction
+        names).  Keyed by module name as a profiler trace gives it, one map
+        per executable: both chunk executables are ``admit_chunk``.  Call
+        it outside a measured window."""
+        if self._state is None:
+            self._push_state()
+        kw = self._admit_kw
+        runs = [(build_serve_step(self.ap, self.ctx, self.mesh, **kw),
+                 (self.params, self.cache, self._state))]
+        if self._admit_chunked is not None:
+            tok = jax.ShapeDtypeStruct((1, self.admit_chunk), jnp.int32)
+            i32 = jax.ShapeDtypeStruct((), jnp.int32)
+            args = (self.params, self.cache, tok, tok, i32, i32, self._rng)
+            runs += [(build_admit_chunk_step(
+                self.ap, self.ctx, self.mesh, chunk=self.admit_chunk,
+                sample=sample, **kw), args) for sample in (False, True)]
+        out: Dict[str, List[Dict[str, str]]] = {}
+        # fresh executables, and the metadata in the persistent cache's
+        # key: an executable compiled before, or an entry keyed without
+        # metadata (the default), may carry another build's op names
+        keyed = jax.config.jax_compilation_cache_include_metadata_in_key
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
+        try:
+            for built, args in runs:
+                text = built.jit().lower(*args).compile().as_text()
+                out.setdefault(module_name(text), []).append(
+                    op_scopes(text))
+        finally:
+            jax.config.update(
+                "jax_compilation_cache_include_metadata_in_key", keyed)
+        return out
+
     def metrics(self, requests: List[Request]) -> ServeMetrics:
         done = [r for r in requests if r.output is not None]
         wall = self._wall_run   # captured at run() drain, not call time
         total_new = sum(len(r.output) for r in done)
-        step_s = wall / self.steps_run if self.steps_run else 0.0
         # TTFT: queueing wait + the admission (prefill) tick.
         ttft = [max(r.admit_step - r.arrival_s, 0.0) + 1.0 for r in done]
         # TPOT over decode tokens only: a request admitted at step t decodes
@@ -1266,10 +1321,6 @@ class ContinuousBatcher:
             ttft_steps_p99=_percentile(ttft, 99),
             tpot_steps_p50=_percentile(tpot, 50),
             tpot_steps_p99=_percentile(tpot, 99),
-            ttft_s_p50=_percentile(ttft, 50) * step_s,
-            ttft_s_p99=_percentile(ttft, 99) * step_s,
-            tpot_s_p50=_percentile(tpot, 50) * step_s,
-            tpot_s_p99=_percentile(tpot, 99) * step_s,
             preemptions=preempt, peak_kv_tokens=int(peak_tok),
             kv_capacity_tokens=int(cap), cache_utilization=float(util),
             cache_stats=cache_stats,

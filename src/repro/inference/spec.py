@@ -484,48 +484,50 @@ def build_replica(spec: ReplicaSpec, *, ap=None, params=None, drafter=None,
     plans per pool — the dist cases feed both pools one tiny non-registry
     model this way.
     """
-    spec.validate(mode="trace")
-    if injector is None:
-        injector = make_injector(spec, replica_id)
-    if not spec.disagg:
-        return _build_batcher(
-            spec, ap=ap, params=params, drafter=drafter, injector=injector,
-            devices=devices, ar_table=spec.ar_table,
-            seq_parallel=spec.seq_parallel, deadline=spec.deadline_ms)
-    # -- disaggregated replica: prefill pool + decode batcher + coordinator
-    from .disagg import DisaggCoordinator, pool_tuner
-    tuner_p = pool_tuner(spec.prefill_ar_table or spec.ar_table)
-    tuner_d = pool_tuner(spec.decode_ar_table or spec.ar_table)
-    # caller-supplied ap/params are honored only when both pools share one
-    # TP layout (the common local-test shape); otherwise each pool gets
-    # its own plan + params from PRNGKey(spec.seed) — same weights, each
-    # pool's layout (the run_disagg contract) — unless the caller passed
-    # explicit per-pool plans
-    shared = spec.prefill_tp == spec.decode_tp
-    if prefill_ap is None:
-        prefill_ap = ap if shared else None
-        prefill_params = params if shared else None
-    pool = build_prefill_pool(
-        spec, ap=prefill_ap, params=prefill_params,
-        ar_table=tuner_p, devices=devices)
-    # the decode pool admits via handoff splice, never from prompts —
-    # force full-admission executables, fused (non-SP) residuals
-    decode_spec = spec.replace(tp=spec.decode_tp, pods=spec.decode_pods,
-                               admit_mode="full")
-    if decode_ap is None:
-        decode_ap = ap if shared else None
-        decode_params = pool.params if shared else None
-    decode = _build_batcher(
-        decode_spec, ap=decode_ap, params=decode_params, drafter=drafter,
-        injector=injector,
-        devices=None if devices is None else devices[:spec.decode_tp],
-        ar_table=tuner_d, seq_parallel="off", deadline=None)
-    return DisaggCoordinator(
-        pool, decode, prefill_per_step=spec.prefill_per_step,
-        decode_tuner=tuner_d, injector=injector,
-        max_handoff_retries=spec.max_handoff_retries,
-        retry_backoff=spec.retry_backoff, max_ready=spec.max_ready,
-        max_reprefills=spec.max_reprefills, deadline_s=spec.deadline_ms)
+    from jax.profiler import TraceAnnotation
+    with TraceAnnotation("serve.build"):
+        spec.validate(mode="trace")
+        if injector is None:
+            injector = make_injector(spec, replica_id)
+        if not spec.disagg:
+            return _build_batcher(
+                spec, ap=ap, params=params, drafter=drafter,
+                injector=injector, devices=devices, ar_table=spec.ar_table,
+                seq_parallel=spec.seq_parallel, deadline=spec.deadline_ms)
+        # -- disaggregated replica: prefill pool, decode batcher, coordinator
+        from .disagg import DisaggCoordinator, pool_tuner
+        tuner_p = pool_tuner(spec.prefill_ar_table or spec.ar_table)
+        tuner_d = pool_tuner(spec.decode_ar_table or spec.ar_table)
+        # caller-supplied ap/params are honored only when both pools share
+        # one TP layout (the common local-test shape); otherwise each pool
+        # gets its own plan + params from PRNGKey(spec.seed) — same
+        # weights, each pool's layout (the run_disagg contract) — unless
+        # the caller passed explicit per-pool plans
+        shared = spec.prefill_tp == spec.decode_tp
+        if prefill_ap is None:
+            prefill_ap = ap if shared else None
+            prefill_params = params if shared else None
+        pool = build_prefill_pool(
+            spec, ap=prefill_ap, params=prefill_params,
+            ar_table=tuner_p, devices=devices)
+        # the decode pool admits via handoff splice, never from prompts —
+        # force full-admission executables, fused (non-SP) residuals
+        decode_spec = spec.replace(tp=spec.decode_tp,
+                                   pods=spec.decode_pods, admit_mode="full")
+        if decode_ap is None:
+            decode_ap = ap if shared else None
+            decode_params = pool.params if shared else None
+        decode = _build_batcher(
+            decode_spec, ap=decode_ap, params=decode_params, drafter=drafter,
+            injector=injector,
+            devices=None if devices is None else devices[:spec.decode_tp],
+            ar_table=tuner_d, seq_parallel="off", deadline=None)
+        return DisaggCoordinator(
+            pool, decode, prefill_per_step=spec.prefill_per_step,
+            decode_tuner=tuner_d, injector=injector,
+            max_handoff_retries=spec.max_handoff_retries,
+            retry_backoff=spec.retry_backoff, max_ready=spec.max_ready,
+            max_reprefills=spec.max_reprefills, deadline_s=spec.deadline_ms)
 
 
 __all__ = ["ReplicaSpec", "ServeSpec", "SpecError", "ROUTER_POLICIES",

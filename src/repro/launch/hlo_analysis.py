@@ -1,9 +1,16 @@
-"""Compiled-HLO analysis: collective-byte accounting and memory/cost capture.
+"""Compiled-HLO analysis: collective-byte accounting, memory/cost capture,
+and the program scope of each instruction.
 
 ``collective_bytes`` parses ``compiled.as_text()``, resolves every
 collective's *operand* sizes (the payload each device injects), and splits
 them into ICI (intra-pod) vs DCN (cross-pod) traffic by inspecting
 replica_groups / source_target_pairs against the pod boundary.
+
+``op_scopes`` maps each instruction of ``compiled.as_text()`` to the
+innermost of the model's ``jax.named_scope`` names (:data:`SCOPES`) in its
+``op_name`` metadata.  A profiler trace names a device op by its
+instruction (``fusion.12``), so the map turns device time into time per
+scope.
 """
 from __future__ import annotations
 
@@ -19,6 +26,12 @@ _DTYPE_BYTES = {
 
 _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
+
+# the jax.named_scope names the serving path carries (models/layers.py,
+# models/transformer.py, parallel/steps.py, core/hierarchical.py)
+SCOPES = ("kv_carry", "kv_write", "kv_gather", "attention", "qkv", "o_proj",
+          "mlp", "norm", "embed", "head", "sample", "tp_all_reduce",
+          "tp_reduce_scatter", "tp_all_gather")
 
 _DEF_RE = re.compile(r"(%?[\w.\-]+)\s*=\s*(\([^)]*\)|[\w\[\],{}\s]+?)\s+"
                      r"([\w\-]+)\(")
@@ -173,6 +186,70 @@ def collective_bytes(hlo_text: str, n_devices: int,
     return stats
 
 
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_MODULE_RE = re.compile(r"^HloModule\s+([^\s,]+)", re.M)
+
+
+def module_name(hlo_text: str) -> str:
+    """The module's jit name as a profiler trace gives it: ``jit_step``
+    -> ``step``."""
+    m = _MODULE_RE.search(hlo_text)
+    name = m.group(1) if m else ""
+    return name[4:] if name.startswith("jit_") else name
+
+
+_NAME_RE = re.compile(r"%?[A-Za-z_][\w\-]*(?:\.[\w\-]+)*")
+_DEBUG_SECTIONS = ("FileNames", "FunctionNames", "FileLocations",
+                   "StackFrames")
+
+
+def program_text(hlo_text: str, renumber: bool = False) -> str:
+    """``hlo_text`` without what only names source places: each
+    ``metadata={...}`` and the debug-info sections (``FileNames`` ...
+    ``StackFrames``).  With ``renumber`` every numbered name (``%fusion.12``)
+    becomes its order of first appearance, so two texts that differ only in
+    how instructions were numbered compare equal."""
+    out, skip = [], False
+    for line in hlo_text.splitlines():
+        if line in _DEBUG_SECTIONS:
+            skip = True
+            continue
+        if skip and line.startswith(("%", "ENTRY")):
+            skip = False
+        if not skip:
+            out.append(re.sub(r",? metadata=\{[^}]*\}", "", line))
+    if renumber:
+        ids: Dict[str, str] = {}
+
+        def canon(m):
+            t = m.group(0)
+            return ids.setdefault(t, f"#{len(ids)}") \
+                if re.search(r"\.\d", t) else t
+
+        out = [_NAME_RE.sub(canon, line) for line in out]
+    return "\n".join(out)
+
+
+def op_scopes(hlo_text: str, scopes=SCOPES) -> Dict[str, str]:
+    """{instruction name: scope} for every instruction whose ``op_name``
+    holds one of ``scopes``; the innermost one counts."""
+    wanted = set(scopes)
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _INSTR_RE.match(line)
+        if not m:
+            continue
+        meta = _OP_NAME_RE.search(line)
+        if not meta:
+            continue
+        for part in reversed(meta.group(1).split("/")):
+            if part in wanted:
+                out[m.group(1)] = part
+                break
+    return out
+
+
 def summarize_compiled(compiled, mesh, lowered=None) -> Dict[str, object]:
     """memory_analysis + cost_analysis + collective stats for one
     executable.
@@ -214,4 +291,5 @@ def summarize_compiled(compiled, mesh, lowered=None) -> Dict[str, object]:
     }
 
 
-__all__ = ["collective_bytes", "summarize_compiled", "CollectiveStats"]
+__all__ = ["collective_bytes", "summarize_compiled", "CollectiveStats",
+           "SCOPES", "module_name", "op_scopes", "program_text"]

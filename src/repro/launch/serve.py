@@ -158,10 +158,8 @@ def _print_trace_metrics(spec: ServeSpec, m, slots: int):
           f"in {m.wall_s:.1f}s ({m.throughput_tok_s:.0f} tok/s, "
           f"slots={slots}, {m.steps} steps)")
     print(f"[serve]   TTFT p50/p99: {m.ttft_steps_p50:.1f}/"
-          f"{m.ttft_steps_p99:.1f} steps = {m.ttft_s_p50*1e3:.0f}/"
-          f"{m.ttft_s_p99*1e3:.0f} ms | TPOT p50/p99: "
-          f"{m.tpot_steps_p50:.2f}/{m.tpot_steps_p99:.2f} steps = "
-          f"{m.tpot_s_p50*1e3:.1f}/{m.tpot_s_p99*1e3:.1f} ms")
+          f"{m.ttft_steps_p99:.1f} steps | TPOT p50/p99: "
+          f"{m.tpot_steps_p50:.2f}/{m.tpot_steps_p99:.2f} steps")
     print(f"[serve]   KV peak {m.peak_kv_tokens} tokens of "
           f"{m.kv_capacity_tokens} reserved "
           f"(util {m.cache_utilization:.2f}), "

@@ -156,6 +156,23 @@ def _qkv(p: Params, h: jax.Array, plan: GQAPlan):
     return q, k, v
 
 
+def _qkv_rope(p: Params, h: jax.Array, cfg: ModelConfig, plan: GQAPlan,
+              positions: jax.Array):
+    """Q, K and V of ``h`` with RoPE at ``positions`` ((B, S) or (S,))."""
+    with jax.named_scope("qkv"):
+        q, k, v = _qkv(p, h, plan)
+        if cfg.rope_theta > 0:
+            cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+    return q, k, v
+
+
+def _o_proj(o: jax.Array, wo: jax.Array) -> jax.Array:
+    with jax.named_scope("o_proj"):
+        return jnp.einsum("bsqh,qhd->bsd", o, wo)
+
+
 def _mask(q_pos: jax.Array, k_pos: jax.Array, *, causal: bool,
           window: int, k_valid: Optional[jax.Array] = None) -> jax.Array:
     """Boolean mask (..., Sq, Sk).  q_pos: (..., Sq); k_pos: (Sk,) or (..., Sk)."""
@@ -259,17 +276,14 @@ def attention(p: Params, h: jax.Array, cfg: ModelConfig, plan: GQAPlan,
               chunk: int = 0) -> jax.Array:
     """Full-sequence attention (train / prefill).  Returns the TP-partial
     output projection; caller reduces."""
-    q, k, v = _qkv(p, h, plan)
-    if cfg.rope_theta > 0:
-        cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-    mask = _mask(positions, positions, causal=causal,
-                 window=cfg.sliding_window)
-    o = attn_core(q, k, v, mask, plan.g, chunk=chunk)
-    if q_mask_tbl is not None:
-        o = o * take_local(q_mask_tbl, ctx)[None, None, :, None].astype(o.dtype)
-    return jnp.einsum("bsqh,qhd->bsd", o, p["wo"])
+    q, k, v = _qkv_rope(p, h, cfg, plan, positions)
+    with jax.named_scope("attention"):
+        mask = _mask(positions, positions, causal=causal,
+                     window=cfg.sliding_window)
+        o = attn_core(q, k, v, mask, plan.g, chunk=chunk)
+        if q_mask_tbl is not None:
+            o = o * take_local(q_mask_tbl, ctx)[None, None, :, None].astype(o.dtype)
+    return _o_proj(o, p["wo"])
 
 
 def attention_decode(p: Params, h: jax.Array, cache: Dict[str, jax.Array],
@@ -310,50 +324,48 @@ def attention_decode(p: Params, h: jax.Array, cache: Dict[str, jax.Array],
                                        positions=positions,
                                        q_mask_tbl=q_mask_tbl, chunk=chunk,
                                        project=project, block_tbl=block_tbl)
-    q, k_new, v_new = _qkv(p, h, plan)
-    if cfg.rope_theta > 0:
-        cos, sin = rope_tables(positions[:, None], cfg.head_dim,
-                               cfg.rope_theta)
-        q = apply_rope(q, cos, sin)
-        k_new = apply_rope(k_new, cos, sin)
+    q, k_new, v_new = _qkv_rope(p, h, cfg, plan, positions[:, None])
     B, S_max = cache["k"].shape[0], cache["k"].shape[1]
     quant = cache["k"].dtype == jnp.int8
     write_pos = positions % S_max if ring else positions
     bidx = jnp.arange(B)
-    if quant:
-        def q8(t):  # (B,1,U,hd) -> int8 payload + (B,U) scale
-            tf = t[:, 0].astype(jnp.float32)
-            sc = jnp.maximum(jnp.max(jnp.abs(tf), axis=-1) / 127.0, 1e-30)
-            qq = jnp.clip(jnp.round(tf / sc[..., None]), -127, 127)
-            return qq.astype(jnp.int8), sc.astype(jnp.bfloat16)
-        kq, ksc = q8(k_new)
-        vq, vsc = q8(v_new)
-        k = cache["k"].at[bidx, write_pos].set(kq)
-        v = cache["v"].at[bidx, write_pos].set(vq)
-        k_scale = cache["k_scale"].at[bidx, write_pos].set(ksc)
-        v_scale = cache["v_scale"].at[bidx, write_pos].set(vsc)
-    else:
-        k = cache["k"].at[bidx, write_pos].set(
-            k_new[:, 0].astype(cache["k"].dtype))
-        v = cache["v"].at[bidx, write_pos].set(
-            v_new[:, 0].astype(cache["v"].dtype))
-        k_scale = v_scale = None
-    kpos = jnp.arange(S_max, dtype=jnp.int32)
-    if ring:
-        # every live slot is within the window by construction; only slots
-        # not yet written (pos < W) are masked out
-        mask = kpos[None, :] <= positions[:, None]
-        mask = jnp.broadcast_to(mask[:, None, :], (B, 1, S_max))
-    else:
-        mask = _mask(positions[:, None], kpos, causal=True,
-                     window=cfg.sliding_window)
-    if chunk is None:
-        chunk = 1024 if S_max > 8192 else 0
-    o = attn_core(q, k, v, mask, plan.g, chunk=chunk, k_scale=k_scale,
-                  v_scale=v_scale)
-    if q_mask_tbl is not None:
-        o = o * take_local(q_mask_tbl, ctx)[None, None, :, None].astype(o.dtype)
-    out = jnp.einsum("bsqh,qhd->bsd", o, p["wo"]) if project else o
+    with jax.named_scope("kv_write"):
+        if quant:
+            def q8(t):  # (B,1,U,hd) -> int8 payload + (B,U) scale
+                tf = t[:, 0].astype(jnp.float32)
+                sc = jnp.maximum(jnp.max(jnp.abs(tf), axis=-1) / 127.0,
+                                 1e-30)
+                qq = jnp.clip(jnp.round(tf / sc[..., None]), -127, 127)
+                return qq.astype(jnp.int8), sc.astype(jnp.bfloat16)
+            kq, ksc = q8(k_new)
+            vq, vsc = q8(v_new)
+            k = cache["k"].at[bidx, write_pos].set(kq)
+            v = cache["v"].at[bidx, write_pos].set(vq)
+            k_scale = cache["k_scale"].at[bidx, write_pos].set(ksc)
+            v_scale = cache["v_scale"].at[bidx, write_pos].set(vsc)
+        else:
+            k = cache["k"].at[bidx, write_pos].set(
+                k_new[:, 0].astype(cache["k"].dtype))
+            v = cache["v"].at[bidx, write_pos].set(
+                v_new[:, 0].astype(cache["v"].dtype))
+            k_scale = v_scale = None
+    with jax.named_scope("attention"):
+        kpos = jnp.arange(S_max, dtype=jnp.int32)
+        if ring:
+            # every live slot is within the window by construction; only
+            # slots not yet written (pos < W) are masked out
+            mask = kpos[None, :] <= positions[:, None]
+            mask = jnp.broadcast_to(mask[:, None, :], (B, 1, S_max))
+        else:
+            mask = _mask(positions[:, None], kpos, causal=True,
+                         window=cfg.sliding_window)
+        if chunk is None:
+            chunk = 1024 if S_max > 8192 else 0
+        o = attn_core(q, k, v, mask, plan.g, chunk=chunk, k_scale=k_scale,
+                      v_scale=v_scale)
+        if q_mask_tbl is not None:
+            o = o * take_local(q_mask_tbl, ctx)[None, None, :, None].astype(o.dtype)
+    out = _o_proj(o, p["wo"]) if project else o
     new_cache = {"k": k, "v": v}
     if quant:
         new_cache["k_scale"] = k_scale
@@ -372,32 +384,30 @@ def _attention_decode_paged(p: Params, h: jax.Array,
     Table rows of inactive slots point at the reserved trash block (0);
     their writes land there and their reads are discarded by the caller, so
     the whole fixed-shape batch keeps stepping without masking."""
-    q, k_new, v_new = _qkv(p, h, plan)
-    if cfg.rope_theta > 0:
-        cos, sin = rope_tables(positions[:, None], cfg.head_dim,
-                               cfg.rope_theta)
-        q = apply_rope(q, cos, sin)
-        k_new = apply_rope(k_new, cos, sin)
+    q, k_new, v_new = _qkv_rope(p, h, cfg, plan, positions[:, None])
     B = h.shape[0]
     bs_blk = cache["k"].shape[1]
     S_max = block_tbl.shape[1] * bs_blk
     bidx = jnp.arange(B)
-    pb = block_tbl[bidx, positions // bs_blk]        # (B,) physical block
-    off = positions % bs_blk
-    k = cache["k"].at[pb, off].set(k_new[:, 0].astype(cache["k"].dtype))
-    v = cache["v"].at[pb, off].set(v_new[:, 0].astype(cache["v"].dtype))
+    with jax.named_scope("kv_write"):
+        pb = block_tbl[bidx, positions // bs_blk]    # (B,) physical block
+        off = positions % bs_blk
+        k = cache["k"].at[pb, off].set(k_new[:, 0].astype(cache["k"].dtype))
+        v = cache["v"].at[pb, off].set(v_new[:, 0].astype(cache["v"].dtype))
     U, hd = k.shape[-2], k.shape[-1]
-    k_log = k[block_tbl].reshape(B, S_max, U, hd)
-    v_log = v[block_tbl].reshape(B, S_max, U, hd)
-    kpos = jnp.arange(S_max, dtype=jnp.int32)
-    mask = _mask(positions[:, None], kpos, causal=True,
-                 window=cfg.sliding_window)
-    if chunk is None:
-        chunk = 1024 if S_max > 8192 else 0
-    o = attn_core(q, k_log, v_log, mask, plan.g, chunk=chunk)
-    if q_mask_tbl is not None:
-        o = o * take_local(q_mask_tbl, ctx)[None, None, :, None].astype(o.dtype)
-    out = jnp.einsum("bsqh,qhd->bsd", o, p["wo"]) if project else o
+    with jax.named_scope("kv_gather"):
+        k_log = k[block_tbl].reshape(B, S_max, U, hd)
+        v_log = v[block_tbl].reshape(B, S_max, U, hd)
+    with jax.named_scope("attention"):
+        kpos = jnp.arange(S_max, dtype=jnp.int32)
+        mask = _mask(positions[:, None], kpos, causal=True,
+                     window=cfg.sliding_window)
+        if chunk is None:
+            chunk = 1024 if S_max > 8192 else 0
+        o = attn_core(q, k_log, v_log, mask, plan.g, chunk=chunk)
+        if q_mask_tbl is not None:
+            o = o * take_local(q_mask_tbl, ctx)[None, None, :, None].astype(o.dtype)
+    out = _o_proj(o, p["wo"]) if project else o
     return out, {"k": k, "v": v}
 
 
@@ -424,11 +434,7 @@ def attention_chunk_step(p: Params, h: jax.Array,
     decode position q only exposes kpos <= q, and position q is overwritten
     by the real decode write before any such read (see DESIGN.md §7).
     """
-    q, k_new, v_new = _qkv(p, h, plan)
-    if cfg.rope_theta > 0:
-        cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-        q = apply_rope(q, cos, sin)
-        k_new = apply_rope(k_new, cos, sin)
+    q, k_new, v_new = _qkv_rope(p, h, cfg, plan, positions)
     B = h.shape[0]
     paged = block_tbl is not None
     if paged:
@@ -442,45 +448,53 @@ def attention_chunk_step(p: Params, h: jax.Array,
         assert B == 1, "slot admission is per-request"
         pos_row = positions[0]                       # (C,)
         if paged:
-            row = lax.dynamic_index_in_dim(block_tbl, slot, 0,
-                                           keepdims=False)  # (max_blocks,)
-            # pads beyond the logical capacity must not clamp into the
-            # slot's last live block — route them to the trash block (0)
-            pb = jnp.where(pos_row < S_max, row[jnp.minimum(
-                pos_row // bs_blk, block_tbl.shape[1] - 1)], 0)
-            off = pos_row % bs_blk
-            k = cache["k"].at[pb, off].set(k_new[0].astype(kd))
-            v = cache["v"].at[pb, off].set(v_new[0].astype(kd))
+            with jax.named_scope("kv_write"):
+                row = lax.dynamic_index_in_dim(block_tbl, slot, 0,
+                                               keepdims=False)
+                # pads beyond the logical capacity must not clamp into the
+                # slot's last live block — route them to the trash block
+                pb = jnp.where(pos_row < S_max, row[jnp.minimum(
+                    pos_row // bs_blk, block_tbl.shape[1] - 1)], 0)
+                off = pos_row % bs_blk
+                k = cache["k"].at[pb, off].set(k_new[0].astype(kd))
+                v = cache["v"].at[pb, off].set(v_new[0].astype(kd))
             U, hd = k.shape[-2], k.shape[-1]
-            k_att = k[row].reshape(1, S_max, U, hd)
-            v_att = v[row].reshape(1, S_max, U, hd)
+            with jax.named_scope("kv_gather"):
+                k_att = k[row].reshape(1, S_max, U, hd)
+                v_att = v[row].reshape(1, S_max, U, hd)
         else:
-            k = cache["k"].at[slot, pos_row].set(k_new[0].astype(kd))
-            v = cache["v"].at[slot, pos_row].set(v_new[0].astype(kd))
-            k_att = lax.dynamic_index_in_dim(k, slot, 0, keepdims=True)
-            v_att = lax.dynamic_index_in_dim(v, slot, 0, keepdims=True)
+            with jax.named_scope("kv_write"):
+                k = cache["k"].at[slot, pos_row].set(k_new[0].astype(kd))
+                v = cache["v"].at[slot, pos_row].set(v_new[0].astype(kd))
+            with jax.named_scope("kv_gather"):
+                k_att = lax.dynamic_index_in_dim(k, slot, 0, keepdims=True)
+                v_att = lax.dynamic_index_in_dim(v, slot, 0, keepdims=True)
     else:
         bidx = jnp.arange(B)[:, None]
         if paged:
-            pb = block_tbl[bidx, jnp.minimum(positions // bs_blk,
-                                             block_tbl.shape[1] - 1)]
-            pb = jnp.where(positions < S_max, pb, 0)     # (B, C)
-            off = positions % bs_blk
-            k = cache["k"].at[pb, off].set(k_new.astype(kd))
-            v = cache["v"].at[pb, off].set(v_new.astype(kd))
+            with jax.named_scope("kv_write"):
+                pb = block_tbl[bidx, jnp.minimum(positions // bs_blk,
+                                                 block_tbl.shape[1] - 1)]
+                pb = jnp.where(positions < S_max, pb, 0)     # (B, C)
+                off = positions % bs_blk
+                k = cache["k"].at[pb, off].set(k_new.astype(kd))
+                v = cache["v"].at[pb, off].set(v_new.astype(kd))
             U, hd = k.shape[-2], k.shape[-1]
-            k_att = k[block_tbl].reshape(B, S_max, U, hd)
-            v_att = v[block_tbl].reshape(B, S_max, U, hd)
+            with jax.named_scope("kv_gather"):
+                k_att = k[block_tbl].reshape(B, S_max, U, hd)
+                v_att = v[block_tbl].reshape(B, S_max, U, hd)
         else:
-            k = cache["k"].at[bidx, positions].set(k_new.astype(kd))
-            v = cache["v"].at[bidx, positions].set(v_new.astype(kd))
+            with jax.named_scope("kv_write"):
+                k = cache["k"].at[bidx, positions].set(k_new.astype(kd))
+                v = cache["v"].at[bidx, positions].set(v_new.astype(kd))
             k_att, v_att = k, v
-    kpos = jnp.arange(S_max, dtype=jnp.int32)
-    mask = _mask(positions, kpos, causal=True, window=cfg.sliding_window)
-    o = attn_core(q, k_att, v_att, mask, plan.g, chunk=chunk)
-    if q_mask_tbl is not None:
-        o = o * take_local(q_mask_tbl, ctx)[None, None, :, None].astype(o.dtype)
-    out = jnp.einsum("bsqh,qhd->bsd", o, p["wo"]) if project else o
+    with jax.named_scope("attention"):
+        kpos = jnp.arange(S_max, dtype=jnp.int32)
+        mask = _mask(positions, kpos, causal=True, window=cfg.sliding_window)
+        o = attn_core(q, k_att, v_att, mask, plan.g, chunk=chunk)
+        if q_mask_tbl is not None:
+            o = o * take_local(q_mask_tbl, ctx)[None, None, :, None].astype(o.dtype)
+    out = _o_proj(o, p["wo"]) if project else o
     return out, {"k": k, "v": v}
 
 
@@ -569,18 +583,19 @@ def init_embed(key, cfg: ModelConfig, vocab_pad: int) -> Params:
 def embed_lookup(p: Params, ids: jax.Array, ctx: ParallelCtx,
                  vocab_pad: int, *, sp: bool = False) -> jax.Array:
     """Vocab-parallel lookup: local gather + TP reduce (paper AR site #0)."""
-    table = p["tok"]
-    v_loc = table.shape[0]
-    if v_loc == vocab_pad and not ctx.has_tp:
-        return table[ids]
-    start = tp_rank(ctx) * v_loc
-    local = ids - start
-    ok = (local >= 0) & (local < v_loc)
-    x = table[jnp.clip(local, 0, v_loc - 1)]
-    x = jnp.where(ok[..., None], x, 0)
-    if sp:
-        return hier.tp_reduce_scatter(x, ctx, dim=1)
-    return hier.tp_all_reduce(x, ctx, scatter_dim=-1)
+    with jax.named_scope("embed"):
+        table = p["tok"]
+        v_loc = table.shape[0]
+        if v_loc == vocab_pad and not ctx.has_tp:
+            return table[ids]
+        start = tp_rank(ctx) * v_loc
+        local = ids - start
+        ok = (local >= 0) & (local < v_loc)
+        x = table[jnp.clip(local, 0, v_loc - 1)]
+        x = jnp.where(ok[..., None], x, 0)
+        if sp:
+            return hier.tp_reduce_scatter(x, ctx, dim=1)
+        return hier.tp_all_reduce(x, ctx, scatter_dim=-1)
 
 
 def lm_logits(p: Params, x: jax.Array) -> jax.Array:

@@ -235,6 +235,20 @@ def _gathered(x, ctx: ParallelCtx, sp: bool):
     return hier.tp_all_gather(x, ctx, dim=1) if sp else x
 
 
+def _norm(x, p: Params, cfg):
+    with jax.named_scope("norm"):
+        return L.apply_norm(x, p, cfg)
+
+
+def _head(params: Params, x, cfg, ctx: ParallelCtx, sp: bool = False):
+    """Final norm and the (vocab-sharded) logits."""
+    with jax.named_scope("head"):
+        x = L.apply_norm(x, params["final_norm"], cfg)
+        if sp:
+            x = hier.tp_all_gather(x, ctx, dim=1)
+        return L.lm_logits(params["embed"], x)
+
+
 def _moe_tokens(h, ctx: ParallelCtx, sp: bool):
     """MoE consumes per-device-unique tokens: under SP the shard already is;
     otherwise slice this device's sequence chunk (no comm)."""
@@ -261,14 +275,14 @@ def block_forward(bp: Params, x, ap: ArchPlan, ctx: ParallelCtx, *,
     aux = jnp.zeros((), jnp.float32)
     state = {}
     if cfg.family == "ssm":
-        h = _gathered(L.apply_norm(x, bp["ln1"], cfg), ctx, sp)
+        h = _gathered(_norm(x, bp["ln1"], cfg), ctx, sp)
         if collect_state:
             tm, st = R.rwkv_time_mix(bp["tm"], h, cfg, ctx, return_state=True)
             state.update(st)
         else:
             tm = R.rwkv_time_mix(bp["tm"], h, cfg, ctx)
         x = _residual(x, tm, ctx, sp)
-        h2 = _gathered(L.apply_norm(x, bp["ln2"], cfg), ctx, sp)
+        h2 = _gathered(_norm(x, bp["ln2"], cfg), ctx, sp)
         if collect_state:
             stacked, st2 = R.rwkv_channel_mix(bp["cm"], h2, cfg, ctx,
                                               return_state=True)
@@ -283,7 +297,7 @@ def block_forward(bp: Params, x, ap: ArchPlan, ctx: ParallelCtx, *,
             * red[0]
         return x, aux, (state or None)
 
-    h = _gathered(L.apply_norm(x, bp["ln1"], cfg), ctx, sp)
+    h = _gathered(_norm(x, bp["ln1"], cfg), ctx, sp)
     # hybrid mixes attn + ssm partials before reducing, so the projection
     # cannot be fused with the reduction there — overlap dense-ish only.
     attn_ov = _use_overlap(ctx) and cfg.family != "hybrid"
@@ -309,12 +323,12 @@ def block_forward(bp: Params, x, ap: ArchPlan, ctx: ParallelCtx, *,
         x = _residual(x, attn_out, ctx, sp)
 
     if enc_kv is not None:
-        hx = _gathered(L.apply_norm(x, bp["ln_x"], cfg), ctx, sp)
+        hx = _gathered(_norm(x, bp["ln_x"], cfg), ctx, sp)
         xo = L.cross_attention(bp["xattn"], hx, enc_kv[0], enc_kv[1], cfg,
                                ap.gqa, ctx, ap.q_mask_tbl)
         x = _residual(x, xo, ctx, sp)
 
-    h2 = L.apply_norm(x, bp["ln2"], cfg)
+    h2 = _norm(x, bp["ln2"], cfg)
     if cfg.is_moe:
         toks = _moe_tokens(_gathered(h2, ctx, sp) if not sp else h2, ctx, sp)
         out, aux_l = M.moe_ffn(bp["moe"], toks, cfg, ctx, decode=False)
@@ -323,30 +337,28 @@ def block_forward(bp: Params, x, ap: ArchPlan, ctx: ParallelCtx, *,
         x = x + _moe_restore(out, ctx, sp)
     else:
         h2g = _gathered(h2, ctx, sp)
-        if _use_overlap(ctx):
-            x = _residual_proj(x, L.mlp_hidden(bp["mlp"], h2g, cfg),
-                               L.mlp_down_w(bp["mlp"], cfg), "bsf,fd->bsd",
-                               ctx, sp)
-        else:
-            x = _residual(x, L.mlp(bp["mlp"], h2g, cfg), ctx, sp)
+        with jax.named_scope("mlp"):
+            if _use_overlap(ctx):
+                x = _residual_proj(x, L.mlp_hidden(bp["mlp"], h2g, cfg),
+                                   L.mlp_down_w(bp["mlp"], cfg),
+                                   "bsf,fd->bsd", ctx, sp)
+            else:
+                x = _residual(x, L.mlp(bp["mlp"], h2g, cfg), ctx, sp)
     return x, aux, (state or None)
 
 
 def _attention_with_kv(p, h, ap: ArchPlan, ctx, *, positions, causal, chunk,
                        project: bool = True):
     cfg = ap.cfg
-    q, k, v = L._qkv(p, h, ap.gqa)
-    if cfg.rope_theta > 0:
-        cos, sin = L.rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-        q = L.apply_rope(q, cos, sin)
-        k = L.apply_rope(k, cos, sin)
-    mask = L._mask(positions, positions, causal=causal,
-                   window=cfg.sliding_window)
-    o = L.attn_core(q, k, v, mask, ap.gqa.g, chunk=chunk)
-    if ap.q_mask_tbl is not None:
-        o = o * L.take_local(ap.q_mask_tbl, ctx)[None, None, :, None] \
-            .astype(o.dtype)
-    out = jnp.einsum("bsqh,qhd->bsd", o, p["wo"]) if project else o
+    q, k, v = L._qkv_rope(p, h, cfg, ap.gqa, positions)
+    with jax.named_scope("attention"):
+        mask = L._mask(positions, positions, causal=causal,
+                       window=cfg.sliding_window)
+        o = L.attn_core(q, k, v, mask, ap.gqa.g, chunk=chunk)
+        if ap.q_mask_tbl is not None:
+            o = o * L.take_local(ap.q_mask_tbl, ctx)[None, None, :, None] \
+                .astype(o.dtype)
+    out = L._o_proj(o, p["wo"]) if project else o
     return out, (k, v)
 
 
@@ -466,10 +478,7 @@ def forward_lm(params: Params, tokens, ap: ArchPlan, ctx: ParallelCtx, *,
                 states_list.append(st)
         states = _stack(states_list) if states_list else None
 
-    x = L.apply_norm(x, params["final_norm"], cfg)
-    if sp and ctx.tp_fast:
-        x = hier.tp_all_gather(x, ctx, dim=1)
-    logits = L.lm_logits(params["embed"], x)
+    logits = _head(params, x, cfg, ctx, sp)
     return logits, aux, states, enc_out
 
 
@@ -706,13 +715,13 @@ def block_decode(bp: Params, x, cache_l: Params, ap: ArchPlan,
         return jnp.swapaxes(ef[site], 0, 1)
 
     if cfg.family == "ssm":
-        h = L.apply_norm(x, bp["ln1"], cfg)
+        h = _norm(x, bp["ln1"], cfg)
         tm, st = R.rwkv_time_mix_step(
             bp["tm"], h, {"shift_tm": cache_l["shift_tm"],
                           "wkv": cache_l["wkv"]}, cfg, ctx)
         new_c["shift_tm"], new_c["wkv"] = st["shift_tm"], st["wkv"]
         x = x + hier.tp_all_reduce(tm, ctx, scatter_dim=-1)
-        h2 = L.apply_norm(x, bp["ln2"], cfg)
+        h2 = _norm(x, bp["ln2"], cfg)
         stacked, st2 = R.rwkv_channel_mix(
             bp["cm"], h2, cfg, ctx, state={"shift_cm": cache_l["shift_cm"]},
             return_state=True)
@@ -724,7 +733,7 @@ def block_decode(bp: Params, x, cache_l: Params, ap: ArchPlan,
             new_c["ef"] = ef
         return x, new_c
 
-    h = L.apply_norm(x, bp["ln1"], cfg)
+    h = _norm(x, bp["ln1"], cfg)
     kv_in = {k2: cache_l[k2] for k2 in
              ("k", "v", "k_scale", "v_scale") if k2 in cache_l}
     # Decode is the paper's regime: the wo projection + all-reduce pair
@@ -749,36 +758,41 @@ def block_decode(bp: Params, x, cache_l: Params, ap: ArchPlan,
         if ef is not None:
             ef_attn = _ef_in(0)
     elif ef is not None:
-        x, ef_attn = _residual_proj(x, attn_out, bp["attn"]["wo"],
-                                    "bsqh,qhd->bsd", ctx, sp=False,
-                                    ef=_ef_in(0))
+        with jax.named_scope("o_proj"):
+            x, ef_attn = _residual_proj(x, attn_out, bp["attn"]["wo"],
+                                        "bsqh,qhd->bsd", ctx, sp=False,
+                                        ef=_ef_in(0))
     else:
-        x = _residual_proj(x, attn_out, bp["attn"]["wo"], "bsqh,qhd->bsd",
-                           ctx, sp=False)
+        with jax.named_scope("o_proj"):
+            x = _residual_proj(x, attn_out, bp["attn"]["wo"],
+                               "bsqh,qhd->bsd", ctx, sp=False)
 
     if cfg.enc_layers:
-        hx = L.apply_norm(x, bp["ln_x"], cfg)
+        hx = _norm(x, bp["ln_x"], cfg)
         xo = L.cross_attention(bp["xattn"], hx, cache_l["enc_k"],
                                cache_l["enc_v"], cfg, ap.gqa, ctx,
                                ap.q_mask_tbl)
         x = x + hier.tp_all_reduce(xo, ctx, scatter_dim=-1)
         new_c["enc_k"], new_c["enc_v"] = cache_l["enc_k"], cache_l["enc_v"]
 
-    h2 = L.apply_norm(x, bp["ln2"], cfg)
+    h2 = _norm(x, bp["ln2"], cfg)
     if cfg.is_moe:
-        out = M.moe_ffn_dense(bp["moe"], h2, cfg, ctx)
-        x = x + hier.tp_all_reduce(out, ctx, scatter_dim=-1)
+        with jax.named_scope("mlp"):
+            out = M.moe_ffn_dense(bp["moe"], h2, cfg, ctx)
+            x = x + hier.tp_all_reduce(out, ctx, scatter_dim=-1)
         if ef is not None:
             ef_mlp = _ef_in(1)
     elif ef is not None:
-        x, ef_mlp = _residual_proj(x, L.mlp_hidden(bp["mlp"], h2, cfg),
-                                   L.mlp_down_w(bp["mlp"], cfg),
-                                   "bsf,fd->bsd", ctx, sp=False,
-                                   ef=_ef_in(1))
+        with jax.named_scope("mlp"):
+            x, ef_mlp = _residual_proj(x, L.mlp_hidden(bp["mlp"], h2, cfg),
+                                       L.mlp_down_w(bp["mlp"], cfg),
+                                       "bsf,fd->bsd", ctx, sp=False,
+                                       ef=_ef_in(1))
     else:
-        x = _residual_proj(x, L.mlp_hidden(bp["mlp"], h2, cfg),
-                           L.mlp_down_w(bp["mlp"], cfg), "bsf,fd->bsd",
-                           ctx, sp=False)
+        with jax.named_scope("mlp"):
+            x = _residual_proj(x, L.mlp_hidden(bp["mlp"], h2, cfg),
+                               L.mlp_down_w(bp["mlp"], cfg), "bsf,fd->bsd",
+                               ctx, sp=False)
     if ef is not None:
         new_c["ef"] = jnp.stack([jnp.swapaxes(ef_attn, 0, 1),
                                  jnp.swapaxes(ef_mlp, 0, 1)])
@@ -798,12 +812,15 @@ def _scan_layers_in_place(body, x, blocks, cache):
     def step(carry, inp):
         x, cache = carry
         i, bp = inp
-        cl = jax.tree.map(
-            lambda t: lax.dynamic_index_in_dim(t, i, keepdims=False), cache)
+        with jax.named_scope("kv_carry"):
+            cl = jax.tree.map(
+                lambda t: lax.dynamic_index_in_dim(t, i, keepdims=False),
+                cache)
         x, nc = body(x, (bp, cl))
-        cache = jax.tree.map(
-            lambda t, u: lax.dynamic_update_index_in_dim(
-                t, u.astype(t.dtype), i, 0), cache, nc)
+        with jax.named_scope("kv_carry"):
+            cache = jax.tree.map(
+                lambda t, u: lax.dynamic_update_index_in_dim(
+                    t, u.astype(t.dtype), i, 0), cache, nc)
         return (x, cache), None
 
     (x, cache), _ = lax.scan(
@@ -853,8 +870,7 @@ def decode_step(params: Params, cache: Params, tokens, positions,
 
     if block_tbl is not None:
         new_cache["block_tbl"] = block_tbl
-    x = L.apply_norm(x, params["final_norm"], cfg)
-    logits = L.lm_logits(params["embed"], x)[:, 0]
+    logits = _head(params, x, cfg, ctx)[:, 0]
     return logits, new_cache
 
 
@@ -912,19 +928,21 @@ def prefill_chunk(params: Params, cache: Params, tokens, positions,
         bp, cl = inp
         if layer_map is not None:
             bp = layer_map(bp)
-        h = _gathered(L.apply_norm(x, bp["ln1"], cfg), ctx, sp)
+        h = _gathered(_norm(x, bp["ln1"], cfg), ctx, sp)
         # Same residual idiom as block_decode: unprojected attention output
         # through _residual_proj (overlapped when ctx asks for it).
         attn_out, kv_new = L.attention_chunk_step(
             bp["attn"], h, cl, cfg, ap.gqa, ctx, positions=positions,
             q_mask_tbl=ap.q_mask_tbl, chunk=attn_chunk,
             project=False, block_tbl=block_tbl, slot=slot)
-        x = _residual_proj(x, attn_out, bp["attn"]["wo"],
-                           "bsqh,qhd->bsd", ctx, sp=sp)
-        h2 = _gathered(L.apply_norm(x, bp["ln2"], cfg), ctx, sp)
-        x = _residual_proj(x, L.mlp_hidden(bp["mlp"], h2, cfg),
-                           L.mlp_down_w(bp["mlp"], cfg), "bsf,fd->bsd",
-                           ctx, sp=sp)
+        with jax.named_scope("o_proj"):
+            x = _residual_proj(x, attn_out, bp["attn"]["wo"],
+                               "bsqh,qhd->bsd", ctx, sp=sp)
+        h2 = _gathered(_norm(x, bp["ln2"], cfg), ctx, sp)
+        with jax.named_scope("mlp"):
+            x = _residual_proj(x, L.mlp_hidden(bp["mlp"], h2, cfg),
+                               L.mlp_down_w(bp["mlp"], cfg), "bsf,fd->bsd",
+                               ctx, sp=sp)
         return x, kv_new
 
     if scan_layers:
@@ -950,11 +968,7 @@ def prefill_chunk(params: Params, cache: Params, tokens, positions,
                 ef_buf, zero, (0, 0, 0, slot, 0))
     if not return_logits:
         return None, new_cache
-    x = L.apply_norm(x, params["final_norm"], cfg)
-    if sp:
-        x = hier.tp_all_gather(x, ctx, dim=1)
-    logits = L.lm_logits(params["embed"], x)
-    return logits, new_cache
+    return _head(params, x, cfg, ctx, sp), new_cache
 
 
 __all__ = ["ArchPlan", "make_plan", "init_params", "init_cache",

@@ -597,18 +597,19 @@ def build_serve_step(ap: ArchPlan, ctx: ParallelCtx, mesh, *, s_max: int,
                 params, cache, state["tokens"], state["positions"], ap,
                 serve_ctx, scan_layers=scan_layers, layer_map=layer_map,
                 attn_chunk=attn_chunk)
-        nxt = _sample_next_slots(logits, serve_ctx, cfg, state["rng"],
-                                 state["sample_idx"], temperature, top_k)
-        finite = _finite_slots(logits, serve_ctx)
-        emitted = jnp.where(active, nxt, state["tokens"])
-        act_i = active.astype(jnp.int32)
-        positions = state["positions"] + act_i
-        remaining = state["remaining"] - act_i
-        done = active & ((remaining <= 0) | (positions >= s_max - 1))
-        state2 = {"tokens": emitted, "positions": positions,
-                  "remaining": remaining, "active": active & ~done,
-                  "rng": state["rng"],
-                  "sample_idx": state["sample_idx"] + act_i}
+        with jax.named_scope("sample"):
+            nxt = _sample_next_slots(logits, serve_ctx, cfg, state["rng"],
+                                     state["sample_idx"], temperature, top_k)
+            finite = _finite_slots(logits, serve_ctx)
+            emitted = jnp.where(active, nxt, state["tokens"])
+            act_i = active.astype(jnp.int32)
+            positions = state["positions"] + act_i
+            remaining = state["remaining"] - act_i
+            done = active & ((remaining <= 0) | (positions >= s_max - 1))
+            state2 = {"tokens": emitted, "positions": positions,
+                      "remaining": remaining, "active": active & ~done,
+                      "rng": state["rng"],
+                      "sample_idx": state["sample_idx"] + act_i}
         return emitted, done, finite, state2, new_cache
 
     if mesh is None:
@@ -964,9 +965,10 @@ def build_admit_chunk_step(ap: ArchPlan, ctx: ParallelCtx, mesh, *,
                 return_logits=sample)
         if not sample:
             return cache2
-        last = lax.dynamic_index_in_dim(logits, last_idx, 1,
-                                        keepdims=False)   # (1, V_loc)
-        nxt = _sample_next(last, serve_ctx, cfg, rng, temperature, top_k)
+        with jax.named_scope("sample"):
+            last = lax.dynamic_index_in_dim(logits, last_idx, 1,
+                                            keepdims=False)   # (1, V_loc)
+            nxt = _sample_next(last, serve_ctx, cfg, rng, temperature, top_k)
         return nxt, cache2
 
     if mesh is None:

@@ -1,6 +1,7 @@
 """Ahead-of-time compiles for a described TPU v5e 2x2, with no chip
 attached: the served decode step at codeqwen1.5-7b widths on one chip and
-at tp=4, and the Pallas kernels.  The chip's compiler runs here, so what it
+at tp=4, the admission-chunk steps and the model's named scopes in them,
+and the Pallas kernels.  The chip's compiler runs here, so what it
 refuses (a tiling, a VMEM budget, a program that does not fit) fails in
 this file at no chip time.  Nothing runs: results and times need a chip.
 
@@ -13,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 
 import numpy as np
 import pytest
@@ -117,6 +119,90 @@ def test_one_chip_serve_step_fits(one_chip):
     used = _bytes_on_device(compiled)
     # 2 layers (0.93 GB) + embedding and head (1.51 GB) + their K/V pool
     assert 2.4e9 < used < V5E_HBM, used
+
+
+CHUNK = 256
+KV_SCOPES = {"kv_carry", "kv_write", "kv_gather", "attention"}
+
+
+def _one_chip_texts(one_chip) -> dict:
+    """Compiled text of the one-chip decode step and of the middle- and
+    final-chunk admission executables (codeqwen widths, 2 layers)."""
+    from repro.parallel.steps import build_admit_chunk_step
+    ap = _codeqwen(2, 1)
+    params, cache, state = [_placed(a, one_chip)
+                            for a in _serve_args(ap, local=True)]
+    kw = dict(s_max=S_MAX, slots=SLOTS, block_size=BLOCK, n_blocks=N_BLOCKS)
+    tok = jax.ShapeDtypeStruct((1, CHUNK), jnp.int32, sharding=one_chip)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    chunk = (params, cache, tok, tok, i32, i32, rng)
+    jobs = {"step": (build_serve_step(ap, LOCAL, None, **kw),
+                     (params, cache, state)),
+            "admit_mid": (build_admit_chunk_step(
+                ap, LOCAL, None, chunk=CHUNK, sample=False, **kw), chunk),
+            "admit_final": (build_admit_chunk_step(
+                ap, LOCAL, None, chunk=CHUNK, **kw), chunk)}
+    return {k: st.jit().lower(*a).compile().as_text()
+            for k, (st, a) in jobs.items()}
+
+
+@pytest.fixture(scope="module")
+def one_chip_texts(one_chip):
+    return _one_chip_texts(one_chip)
+
+
+def _top_level(text: str):
+    """(name, dims, opcode) of each instruction outside fused
+    computations: the ops a profiler trace names."""
+    fused = False
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            fused = line.startswith("%fused_computation")
+            continue
+        m = re.match(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m and not fused:
+            yield m.groups()
+
+
+@pytest.mark.parametrize("which", ["step", "admit_mid", "admit_final"])
+def test_one_chip_steps_name_their_cache_ops(one_chip_texts, which):
+    """Every op of the chip's executable that makes a layer's K/V pool,
+    the stacked pool or the gathered logical view carries a cache or
+    attention scope; the model's other scopes are there too."""
+    from repro.launch.hlo_analysis import op_scopes
+    text = one_chip_texts[which]
+    scopes = op_scopes(text)
+    nb, U, hd = N_BLOCKS, 32, 128
+    shapes = {f"2,{nb},{BLOCK},{U},{hd}", f"{nb},{BLOCK},{U},{hd}",
+              f"{SLOTS * S_MAX // BLOCK},{BLOCK},{U},{hd}",
+              f"{S_MAX // BLOCK},{BLOCK},{U},{hd}"}
+    seen = [(n, op) for n, dims, op in _top_level(text) if dims in shapes
+            and op not in ("parameter", "get-tuple-element", "tuple",
+                           "bitcast")]
+    assert len(seen) >= 6, seen
+    assert all(scopes.get(n) in KV_SCOPES for n, _ in seen), \
+        [(n, op, scopes.get(n)) for n, op in seen]
+    want = KV_SCOPES | {"qkv", "o_proj", "mlp", "norm", "embed"}
+    if which != "admit_mid":
+        want |= {"head", "sample"}
+    assert want <= set(scopes.values()), want - set(scopes.values())
+
+
+def test_scopes_leave_the_one_chip_steps_unchanged(one_chip, one_chip_texts,
+                                                   monkeypatch):
+    """Compiled for the chip without the named scopes, each executable is
+    the same program, and every op a trace names keeps its name."""
+    import contextlib
+    from repro.launch.hlo_analysis import program_text
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _one_chip_texts(one_chip)
+    for k, text in one_chip_texts.items():
+        assert program_text(text, renumber=True) \
+            == program_text(plain[k], renumber=True), k
+        assert list(_top_level(text)) == list(_top_level(plain[k])), k
 
 
 def test_tp4_serve_step_on_pod_model_mesh(meshes):
