@@ -28,7 +28,10 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
 
 # the jax.named_scope names the serving path carries (models/layers.py,
-# models/transformer.py, parallel/steps.py, core/hierarchical.py)
+# models/transformer.py, parallel/steps.py, core/hierarchical.py).
+# ``kv_carry`` is how a layer reaches its part of the stacked cache: its
+# slice out and back for a dense leaf, only the offset of its block table
+# for a paged K/V pool.
 SCOPES = ("kv_carry", "kv_write", "kv_gather", "attention", "qkv", "o_proj",
           "mlp", "norm", "embed", "head", "sample", "tp_all_reduce",
           "tp_reduce_scatter", "tp_all_gather")

@@ -381,7 +381,7 @@ def _attention_decode_paged(p: Params, h: jax.Array,
     """Paged one-token decode: scatter the new K/V through the block table,
     gather the logical view, then attend exactly like the dense path.
 
-    Table rows of inactive slots point at the reserved trash block (0);
+    Table rows of inactive slots point at the pool's reserved trash block;
     their writes land there and their reads are discarded by the caller, so
     the whole fixed-shape batch keeps stepping without masking."""
     q, k_new, v_new = _qkv_rope(p, h, cfg, plan, positions[:, None])
@@ -418,8 +418,8 @@ def attention_chunk_step(p: Params, h: jax.Array,
                          q_mask_tbl: Optional[np.ndarray] = None,
                          chunk: int = 0, project: bool = True,
                          block_tbl: Optional[jax.Array] = None,
-                         slot: Optional[jax.Array] = None
-                         ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+                         slot: Optional[jax.Array] = None,
+                         trash=0) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Chunked-prefill attention: C prompt tokens write into the decode
     cache, then attend causally over everything written so far.
 
@@ -427,7 +427,9 @@ def attention_chunk_step(p: Params, h: jax.Array,
     traced scalar) admits a single request (B == 1) into one row of a
     batch-wide cache — the on-device splice the continuous batcher's
     admission step uses.  Dense and paged (``block_tbl``) layouts share the
-    call; the paged path scatters/gathers through the table first.
+    call; the paged path scatters/gathers through the table first, and
+    ``trash`` is the pool's reserved block that pads past the logical
+    capacity write to.
 
     Trailing pad tokens are safe *by the write-ordering invariant*: a pad at
     position p >= prompt_len writes garbage K/V, but every later read at
@@ -454,7 +456,7 @@ def attention_chunk_step(p: Params, h: jax.Array,
                 # pads beyond the logical capacity must not clamp into the
                 # slot's last live block — route them to the trash block
                 pb = jnp.where(pos_row < S_max, row[jnp.minimum(
-                    pos_row // bs_blk, block_tbl.shape[1] - 1)], 0)
+                    pos_row // bs_blk, block_tbl.shape[1] - 1)], trash)
                 off = pos_row % bs_blk
                 k = cache["k"].at[pb, off].set(k_new[0].astype(kd))
                 v = cache["v"].at[pb, off].set(v_new[0].astype(kd))
@@ -475,7 +477,7 @@ def attention_chunk_step(p: Params, h: jax.Array,
             with jax.named_scope("kv_write"):
                 pb = block_tbl[bidx, jnp.minimum(positions // bs_blk,
                                                  block_tbl.shape[1] - 1)]
-                pb = jnp.where(positions < S_max, pb, 0)     # (B, C)
+                pb = jnp.where(positions < S_max, pb, trash)  # (B, C)
                 off = positions % bs_blk
                 k = cache["k"].at[pb, off].set(k_new.astype(kd))
                 v = cache["v"].at[pb, off].set(v_new.astype(kd))

@@ -799,32 +799,82 @@ def block_decode(bp: Params, x, cache_l: Params, ap: ArchPlan,
     return x, new_c
 
 
-def _scan_layers_in_place(body, x, blocks, cache):
-    """``lax.scan`` of ``body(x, (layer_params, layer_cache)) -> (x,
-    new_layer_cache)`` over the layers, with the stacked cache carried
-    through the loop and each layer's slice written back where it was read.
+def _layers_in_place(body, x, blocks, cache, block_tbl=None, *,
+                     scan: bool = True):
+    """Run ``body(x, layer_params, layer_cache, layer_tbl, trash) -> (x,
+    new_layer_cache)`` over the layers, the stacked cache updated where it
+    lies.
+
+    Scanned, the stacked cache is carried through the loop and each
+    layer's slice is written back where it was read (scope ``kv_carry``).
     Scanning the cache as ``xs`` and collecting it as ``ys`` would give XLA
     a second stacked cache to fill (a full copy of the KV pool alive at
     once); carried, the donated cache is updated in place, one layer's
-    slice at a time.  Same per-layer math, same values."""
+    slice at a time.  Unscanned (``scan=False``), each layer's slice is
+    taken and the slices are stacked again.
+
+    A paged cache (``block_tbl`` given) is not sliced at all: its K/V pools
+    (L, n_blocks, bs, U, hd) are viewed once as one pool (L*n_blocks, bs,
+    U, hd) -- a bitcast, the merged dims are never sharded -- and layer
+    ``i`` addresses its own blocks through ``block_tbl + i*n_blocks``, its
+    reserved trash block being ``i*n_blocks``.  The body's scatter and
+    gather then work on the whole pool, so no layer's pool is copied out
+    and back; ``kv_carry`` holds only that index offset and the two views.
+    ``layer_tbl`` is None and ``trash`` 0 for every other layout.  Every
+    other leaf (dense K/V, int8 scales, recurrent states, encoder K/V,
+    ``ef``) is sliced and written back.  Same per-layer math, same
+    values."""
     n_layers = jax.tree.leaves(blocks)[0].shape[0]
-
-    def step(carry, inp):
-        x, cache = carry
-        i, bp = inp
+    pools = {}
+    if block_tbl is not None:
+        pool_shape = cache["k"].shape
         with jax.named_scope("kv_carry"):
-            cl = jax.tree.map(
-                lambda t: lax.dynamic_index_in_dim(t, i, keepdims=False),
-                cache)
-        x, nc = body(x, (bp, cl))
-        with jax.named_scope("kv_carry"):
-            cache = jax.tree.map(
-                lambda t, u: lax.dynamic_update_index_in_dim(
-                    t, u.astype(t.dtype), i, 0), cache, nc)
-        return (x, cache), None
+            pools = {k2: cache[k2].reshape((-1,) + pool_shape[2:])
+                     for k2 in ("k", "v")}
+        cache = {k2: t for k2, t in cache.items() if k2 not in pools}
 
-    (x, cache), _ = lax.scan(
-        step, (x, cache), (jnp.arange(n_layers, dtype=jnp.int32), blocks))
+    def layer_tbl(i):
+        if block_tbl is None:
+            return None, 0
+        with jax.named_scope("kv_carry"):
+            trash = i * pool_shape[1]
+            return block_tbl + trash, trash
+
+    def with_pools(cl, pools):
+        return {**cl, **pools} if pools else cl
+
+    if scan:
+        def step(carry, inp):
+            x, pools, cache = carry
+            i, bp = inp
+            with jax.named_scope("kv_carry"):
+                cl = jax.tree.map(
+                    lambda t: lax.dynamic_index_in_dim(t, i, keepdims=False),
+                    cache)
+            x, nc = body(x, bp, with_pools(cl, pools), *layer_tbl(i))
+            pools = {k2: nc.pop(k2) for k2 in pools}
+            with jax.named_scope("kv_carry"):
+                cache = jax.tree.map(
+                    lambda t, u: lax.dynamic_update_index_in_dim(
+                        t, u.astype(t.dtype), i, 0), cache, nc)
+            return (x, pools, cache), None
+
+        (x, pools, cache), _ = lax.scan(
+            step, (x, pools, cache),
+            (jnp.arange(n_layers, dtype=jnp.int32), blocks))
+    else:
+        ncs = []
+        for i in range(n_layers):
+            bp = jax.tree.map(lambda t: t[i], blocks)
+            cl = jax.tree.map(lambda t: t[i], cache)
+            x, nc = body(x, bp, with_pools(cl, pools), *layer_tbl(i))
+            pools = {k2: nc.pop(k2) for k2 in pools}
+            ncs.append(nc)
+        cache = _stack(ncs)
+    if pools:
+        with jax.named_scope("kv_carry"):
+            cache = {**cache, **{k2: p.reshape(pool_shape)
+                                 for k2, p in pools.items()}}
     return x, cache
 
 
@@ -847,27 +897,15 @@ def decode_step(params: Params, cache: Params, tokens, positions,
         cache = {k2: v for k2, v in cache.items() if k2 != "block_tbl"}
     x = L.embed_lookup(params["embed"], tokens[:, None], ctx, ap.vocab_pad)
 
-    def body(x, inp):
-        bp, cl = inp
+    def body(x, bp, cl, tbl, trash):
         if layer_map is not None:
             bp = layer_map(bp)
-        x, nc = block_decode(bp, x, cl, ap, ctx, positions=positions,
-                             attn_chunk=attn_chunk, kv_ring=kv_ring,
-                             block_tbl=block_tbl)
-        return x, nc
+        return block_decode(bp, x, cl, ap, ctx, positions=positions,
+                            attn_chunk=attn_chunk, kv_ring=kv_ring,
+                            block_tbl=tbl)
 
-    if scan_layers:
-        x, new_cache = _scan_layers_in_place(body, x, params["blocks"],
-                                             cache)
-    else:
-        ncs = []
-        for i in range(cfg.n_layers):
-            bp = jax.tree.map(lambda t: t[i], params["blocks"])
-            cl = jax.tree.map(lambda t: t[i], cache)
-            x, nc = body(x, (bp, cl))
-            ncs.append(nc)
-        new_cache = _stack(ncs)
-
+    x, new_cache = _layers_in_place(body, x, params["blocks"], cache,
+                                    block_tbl, scan=scan_layers)
     if block_tbl is not None:
         new_cache["block_tbl"] = block_tbl
     logits = _head(params, x, cfg, ctx)[:, 0]
@@ -924,8 +962,7 @@ def prefill_chunk(params: Params, cache: Params, tokens, positions,
                 if k2 not in ("block_tbl", "ef")}
     x = L.embed_lookup(params["embed"], tokens, ctx, ap.vocab_pad, sp=sp)
 
-    def body(x, inp):
-        bp, cl = inp
+    def body(x, bp, cl, tbl, trash):
         if layer_map is not None:
             bp = layer_map(bp)
         h = _gathered(_norm(x, bp["ln1"], cfg), ctx, sp)
@@ -934,7 +971,7 @@ def prefill_chunk(params: Params, cache: Params, tokens, positions,
         attn_out, kv_new = L.attention_chunk_step(
             bp["attn"], h, cl, cfg, ap.gqa, ctx, positions=positions,
             q_mask_tbl=ap.q_mask_tbl, chunk=attn_chunk,
-            project=False, block_tbl=block_tbl, slot=slot)
+            project=False, block_tbl=tbl, slot=slot, trash=trash)
         with jax.named_scope("o_proj"):
             x = _residual_proj(x, attn_out, bp["attn"]["wo"],
                                "bsqh,qhd->bsd", ctx, sp=sp)
@@ -945,17 +982,8 @@ def prefill_chunk(params: Params, cache: Params, tokens, positions,
                                ctx, sp=sp)
         return x, kv_new
 
-    if scan_layers:
-        x, new_cache = _scan_layers_in_place(body, x, params["blocks"],
-                                             kv_cache)
-    else:
-        ncs = []
-        for i in range(cfg.n_layers):
-            bp = jax.tree.map(lambda t: t[i], params["blocks"])
-            cl = jax.tree.map(lambda t: t[i], kv_cache)
-            x, nc = body(x, (bp, cl))
-            ncs.append(nc)
-        new_cache = _stack(ncs)
+    x, new_cache = _layers_in_place(body, x, params["blocks"], kv_cache,
+                                    block_tbl, scan=scan_layers)
     if block_tbl is not None:
         new_cache["block_tbl"] = block_tbl
     if ef_buf is not None:

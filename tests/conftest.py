@@ -38,3 +38,55 @@ def run_dist_case(script_name: str, n_devices: int = 8,
 @pytest.fixture(scope="session")
 def dist_runner():
     return run_dist_case
+
+
+def _sliced_layers(body, x, blocks, cache, block_tbl=None, *, scan=True):
+    """The model's layer loop with every cache leaf, paged K/V pools
+    included, sliced out of the stack per layer and written back, the
+    block table as given: the reference that
+    ``transformer._layers_in_place`` must match bitwise."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    n = jax.tree.leaves(blocks)[0].shape[0]
+    if scan:
+        def step(carry, inp):
+            x, cache = carry
+            i, bp = inp
+            with jax.named_scope("kv_carry"):
+                cl = jax.tree.map(
+                    lambda t: lax.dynamic_index_in_dim(t, i, keepdims=False),
+                    cache)
+            x, nc = body(x, bp, cl, block_tbl, 0)
+            with jax.named_scope("kv_carry"):
+                cache = jax.tree.map(
+                    lambda t, u: lax.dynamic_update_index_in_dim(
+                        t, u.astype(t.dtype), i, 0), cache, nc)
+            return (x, cache), None
+
+        (x, cache), _ = lax.scan(step, (x, cache),
+                                 (jnp.arange(n, dtype=jnp.int32), blocks))
+        return x, cache
+    ncs = []
+    for i in range(n):
+        x, nc = body(x, jax.tree.map(lambda t: t[i], blocks),
+                     jax.tree.map(lambda t: t[i], cache), block_tbl, 0)
+        ncs.append(nc)
+    return x, jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
+
+
+@pytest.fixture
+def sliced_layers(monkeypatch):
+    """A context manager inside which the model runs its layers through
+    the sliced reference loop (``_sliced_layers``)."""
+    import contextlib
+
+    from repro.models import transformer
+
+    @contextlib.contextmanager
+    def patched():
+        with monkeypatch.context() as m:
+            m.setattr(transformer, "_layers_in_place", _sliced_layers)
+            yield
+
+    return patched

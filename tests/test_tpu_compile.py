@@ -123,6 +123,7 @@ def test_one_chip_serve_step_fits(one_chip):
 
 CHUNK = 256
 KV_SCOPES = {"kv_carry", "kv_write", "kv_gather", "attention"}
+MOVES_NOTHING = ("parameter", "get-tuple-element", "tuple", "bitcast")
 
 
 def _one_chip_texts(one_chip) -> dict:
@@ -152,18 +153,26 @@ def one_chip_texts(one_chip):
     return _one_chip_texts(one_chip)
 
 
-def _top_level(text: str):
-    """(name, dims, opcode) of each instruction outside fused
-    computations: the ops a profiler trace names."""
+def _instructions(text: str):
+    """(name, dtype, dims, opcode, fused) of each array-valued instruction,
+    ``fused`` when it lies inside a fused computation."""
     fused = False
     for line in text.splitlines():
         if line.startswith(("%", "ENTRY")):
             fused = line.startswith("%fused_computation")
             continue
-        m = re.match(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+        m = re.match(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
                      r"([\w\-]+)\(", line)
-        if m and not fused:
-            yield m.groups()
+        if m:
+            yield m.groups() + (fused,)
+
+
+def _top_level(text: str):
+    """(name, dims, opcode) of each instruction outside fused
+    computations: the ops a profiler trace names."""
+    for name, _, dims, op, fused in _instructions(text):
+        if not fused:
+            yield name, dims, op
 
 
 @pytest.mark.parametrize("which", ["step", "admit_mid", "admit_final"])
@@ -176,18 +185,57 @@ def test_one_chip_steps_name_their_cache_ops(one_chip_texts, which):
     scopes = op_scopes(text)
     nb, U, hd = N_BLOCKS, 32, 128
     shapes = {f"2,{nb},{BLOCK},{U},{hd}", f"{nb},{BLOCK},{U},{hd}",
+              f"{2 * nb},{BLOCK},{U},{hd}",
               f"{SLOTS * S_MAX // BLOCK},{BLOCK},{U},{hd}",
               f"{S_MAX // BLOCK},{BLOCK},{U},{hd}"}
     seen = [(n, op) for n, dims, op in _top_level(text) if dims in shapes
-            and op not in ("parameter", "get-tuple-element", "tuple",
-                           "bitcast")]
-    assert len(seen) >= 6, seen
+            and op not in MOVES_NOTHING]
+    assert len(seen) >= 4, seen
     assert all(scopes.get(n) in KV_SCOPES for n, _ in seen), \
         [(n, op, scopes.get(n)) for n, op in seen]
     want = KV_SCOPES | {"qkv", "o_proj", "mlp", "norm", "embed"}
     if which != "admit_mid":
         want |= {"head", "sample"}
     assert want <= set(scopes.values()), want - set(scopes.values())
+
+
+@pytest.mark.parametrize("which", ["step", "admit_mid", "admit_final"])
+def test_one_chip_steps_copy_no_layer_pool(one_chip_texts, which):
+    """The layers address their blocks inside the stacked paged pool: no
+    instruction makes one layer's pool, and ``kv_carry`` holds only the
+    s32 offset of each layer's block table and the bitcasts that view the
+    stacked pools as one."""
+    text = one_chip_texts[which]
+    layer_pool = f"{N_BLOCKS},{BLOCK},32,128"
+    made = [(n, op) for n, _, dims, op, _ in _instructions(text)
+            if dims == layer_pool and op not in MOVES_NOTHING]
+    assert not made, made
+    from repro.launch.hlo_analysis import op_scopes
+    carry = {n for n, s in op_scopes(text).items() if s == "kv_carry"}
+    kinds = {(n, dt, op) for n, dt, _, op, _ in _instructions(text)
+             if n in carry}
+    assert any(dt == "s32" for _, dt, _ in kinds), kinds
+    assert all(dt == "s32" or op == "bitcast" for _, dt, op in kinds), kinds
+
+
+def test_one_chip_step_temp_drops_a_layer_pool(one_chip, sliced_layers):
+    """The decode step's scratch memory, against the same step with each
+    layer's pools sliced out and written back: at least one layer's K and
+    V pool less."""
+    ap = _codeqwen(2, 1)
+    args = [_placed(a, one_chip) for a in _serve_args(ap, local=True)]
+
+    def temp():
+        step = build_serve_step(ap, LOCAL, None, s_max=S_MAX, slots=SLOTS,
+                                block_size=BLOCK, n_blocks=N_BLOCKS)
+        compiled = step.jit().lower(*args).compile()
+        return compiled.memory_analysis().temp_size_in_bytes
+
+    got = temp()
+    with sliced_layers():
+        ref = temp()
+    layer_kv = 2 * N_BLOCKS * BLOCK * 32 * 128 * 2     # K and V, bf16
+    assert ref - got >= layer_kv, (ref, got)
 
 
 def test_scopes_leave_the_one_chip_steps_unchanged(one_chip, one_chip_texts,
@@ -205,23 +253,34 @@ def test_scopes_leave_the_one_chip_steps_unchanged(one_chip, one_chip_texts,
         assert list(_top_level(text)) == list(_top_level(plain[k])), k
 
 
-def test_tp4_serve_step_on_pod_model_mesh(meshes):
+def test_tp4_serve_step_on_pod_model_mesh(meshes, sliced_layers):
     """The tp=4 decode step on the (pod=2, model=2) mesh with hier_rd:
     weights and cache split four ways, the RD exchange a collective-
-    permute."""
+    permute.  The paged pools, split on their heads, reach each layer
+    with no collective more than each layer's slice did."""
+    from repro.launch.hlo_analysis import collective_bytes
     mesh = meshes["2x2"]
     _, ctx, _ = mesh_and_ctx(4, 2, ar_strategy="hier_rd",
                              devices=list(mesh.devices.flat))
     ap = _codeqwen(2, 4)
-    step = build_serve_step(ap, ctx, mesh, s_max=S_MAX, slots=SLOTS,
-                            block_size=BLOCK, n_blocks=N_BLOCKS)
-    args = [_placed(a, (mesh, spec)) for a, spec in
-            zip(_serve_args(ap, local=False), step.in_specs)]
-    compiled = step.jit().lower(*args).compile()
+
+    def compile_step():
+        step = build_serve_step(ap, ctx, mesh, s_max=S_MAX, slots=SLOTS,
+                                block_size=BLOCK, n_blocks=N_BLOCKS)
+        args = [_placed(a, (mesh, spec)) for a, spec in
+                zip(_serve_args(ap, local=False), step.in_specs)]
+        return step.jit().lower(*args).compile()
+
+    compiled = compile_step()
     used = _bytes_on_device(compiled)
     # a quarter of the one-chip footprint, plus replicated norms/biases
     assert 0.5e9 < used < 1.2e9, used
-    assert "collective-permute" in compiled.as_text()
+    text = compiled.as_text()
+    assert "collective-permute" in text
+    with sliced_layers():
+        ref = compile_step().as_text()
+    got, want = collective_bytes(text, 4, 2), collective_bytes(ref, 4, 2)
+    assert (got.count, got.by_kind) == (want.count, want.by_kind)
 
 
 @pytest.mark.parametrize("nbytes", [1024, 64 * 2**10, 2**20],
