@@ -1,6 +1,9 @@
-"""mistral-large-123b [dense].
-[hf:mistralai/Mistral-Large-Instruct-2407; unverified]  88L d_model=12288
-96H (GQA kv=8) d_ff=28672 vocab=32768."""
+"""mistral-large-123b [dense] — Mistral-Large-Instruct-2407,
+MistralForCausalLM.  Checked against the published config.json
+(https://huggingface.co/mistralai/Mistral-Large-Instruct-2407/blob/main/config.json):
+88L d_model=12288 96H (GQA kv=8) d_ff=28672 vocab=32768, untied head, no
+QKV bias, RMSNorm eps 1e-5, rope theta 1e6, no sliding window.  head_dim
+128 = 12288 / 96 (the config states none)."""
 from ..models.common import ModelConfig
 
 CONFIG = ModelConfig(

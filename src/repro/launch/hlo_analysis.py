@@ -36,8 +36,11 @@ SCOPES = ("kv_carry", "kv_write", "kv_gather", "attention", "qkv", "o_proj",
           "mlp", "norm", "embed", "head", "sample", "tp_all_reduce",
           "tp_reduce_scatter", "tp_all_gather")
 
-_DEF_RE = re.compile(r"(%?[\w.\-]+)\s*=\s*(\([^)]*\)|[\w\[\],{}\s]+?)\s+"
-                     r"([\w\-]+)\(")
+# an instruction's name, its type and its opcode; a type is an array
+# (`bf16[32,12288]`), with the layout a compiled text gives it
+# (`{2,0,1:T(8,128)(2,1)S(1)}`), or a tuple of them
+_DEF_RE = re.compile(r"(%?[\w.\-]+)\s*=\s*(\((?:[^()]|\([^()]*\))*\)|"
+                     r"\w+\[[\d,]*\](?:\{[^}]*\})?)\s+([\w\-]+)\(")
 _TYPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _GROUPS_RE = re.compile(r"replica_groups=\{(.*?)\}\}|replica_groups=\{(.*?)\}")
 _PAIRS_RE = re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)+)\}")

@@ -1,7 +1,8 @@
 """Ahead-of-time compiles for a described TPU v5e 2x2, with no chip
 attached: the served decode step at codeqwen1.5-7b widths on one chip and
 at tp=4, the admission-chunk steps and the model's named scopes in them,
-and the Pallas kernels.  The chip's compiler runs here, so what it
+Mistral-Large-2407's tp=4 step and chunks at its published widths, and
+the Pallas kernels.  The chip's compiler runs here, so what it
 refuses (a tiling, a VMEM budget, a program that does not fit) fails in
 this file at no chip time.  Nothing runs: results and times need a chip.
 
@@ -77,15 +78,16 @@ def _codeqwen(n_layers: int, tp: int):
     return make_plan(cfg, tp)
 
 
-def _serve_args(ap, *, local: bool):
+def _serve_args(ap, *, local: bool, slots: int = SLOTS):
     params = jax.eval_shape(lambda k: init_params(k, ap),
                             jax.random.PRNGKey(0))
     cache = jax.eval_shape(lambda: init_cache(
-        ap, SLOTS, S_MAX, local=local, block_size=BLOCK, n_blocks=N_BLOCKS))
-    i32 = jax.ShapeDtypeStruct((SLOTS,), jnp.int32)
+        ap, slots, S_MAX, local=local, block_size=BLOCK,
+        n_blocks=slots * (S_MAX // BLOCK) + 1))
+    i32 = jax.ShapeDtypeStruct((slots,), jnp.int32)
     state = {"tokens": i32, "positions": i32, "remaining": i32,
-             "active": jax.ShapeDtypeStruct((SLOTS,), jnp.bool_),
-             "rng": jax.ShapeDtypeStruct((SLOTS, 2), jnp.uint32),
+             "active": jax.ShapeDtypeStruct((slots,), jnp.bool_),
+             "rng": jax.ShapeDtypeStruct((slots, 2), jnp.uint32),
              "sample_idx": i32}
     return params, cache, state
 
@@ -281,6 +283,106 @@ def test_tp4_serve_step_on_pod_model_mesh(meshes, sliced_layers):
         ref = compile_step().as_text()
     got, want = collective_bytes(text, 4, 2), collective_bytes(ref, 4, 2)
     assert (got.count, got.by_kind) == (want.count, want.by_kind)
+
+
+MISTRAL_LAYERS, MISTRAL_SLOTS, MISTRAL_D = 16, 32, 12288
+V5E_COMPILER_HBM = 15.75 * 2**30       # what the v5e compiler lets one chip use
+AR_BYTES = {"step": MISTRAL_SLOTS * MISTRAL_D * 2,      # 786,432 B, bf16
+            "admit_mid": CHUNK * MISTRAL_D * 2,         # 6,291,456 B
+            "admit_final": CHUNK * MISTRAL_D * 2}
+
+
+@pytest.fixture(scope="module")
+def mistral_tp4(meshes):
+    """The tp=4 (one pod, flat all-reduce) decode step and middle- and
+    final-chunk executables at Mistral-Large-2407 widths, as the
+    benchmark serves them: 16 layers, 32 slots x 2048, blocks of 16."""
+    from repro.parallel.steps import build_admit_chunk_step
+    mesh = meshes["4"]
+    _, ctx, _ = mesh_and_ctx(4, 1, ar_strategy="flat",
+                             devices=list(mesh.devices.flat))
+    cfg = dataclasses.replace(get_config("mistral-large-123b"),
+                              n_layers=MISTRAL_LAYERS)
+    ap = make_plan(cfg, 4)
+    n_blocks = MISTRAL_SLOTS * (S_MAX // BLOCK) + 1
+    kw = dict(s_max=S_MAX, slots=MISTRAL_SLOTS, block_size=BLOCK,
+              n_blocks=n_blocks)
+    step = build_serve_step(ap, ctx, mesh, **kw)
+    params, cache, state = [
+        _placed(a, (mesh, spec)) for a, spec in
+        zip(_serve_args(ap, local=False, slots=MISTRAL_SLOTS), step.in_specs)]
+    every = NamedSharding(mesh, P())
+    tok = jax.ShapeDtypeStruct((1, CHUNK), jnp.int32, sharding=every)
+    s32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=every)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=every)
+    chunk = (params, cache, tok, tok, s32, s32, rng)
+    jobs = {"step": (step, (params, cache, state)),
+            "admit_mid": (build_admit_chunk_step(
+                ap, ctx, mesh, chunk=CHUNK, sample=False, **kw), chunk),
+            "admit_final": (build_admit_chunk_step(
+                ap, ctx, mesh, chunk=CHUNK, **kw), chunk)}
+    return {k: st.jit().lower(*a).compile() for k, (st, a) in jobs.items()}
+
+
+def _computations(text: str):
+    """{computation name: its instruction lines} of a compiled text."""
+    out, name = {}, None
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            name = line.split()[1] if line.startswith("ENTRY") \
+                else line.split()[0]
+            out[name.lstrip("%")] = []
+        elif name is not None and line.startswith("  "):
+            out[name.lstrip("%")].append(line)
+    return out
+
+
+def _all_reduces_per_run(text: str):
+    """(instruction, bytes, times it runs per run of the executable) of
+    each all-reduce: once, or once a layer inside the layer loop, whose
+    trip count is the constant its condition compares with."""
+    from repro.launch.hlo_analysis import collective_bytes
+    comps = _computations(text)
+    loop = re.search(r"while\(.*condition=%?([\w.\-]+), body=%?([\w.\-]+)",
+                     text)
+    cond, body = loop.groups()
+    trips = [int(c) for line in comps[cond]
+             for c in re.findall(r"constant\((\d+)\)", line)]
+    assert len(trips) == 1, comps[cond]
+    for comp, lines in comps.items():
+        for line in lines:
+            if re.search(r" all-reduce(-start)?\(", line):
+                name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) =", line).group(1)
+                nbytes = collective_bytes(line, 4).by_kind["all-reduce"]
+                yield name, nbytes, trips[0] if comp == body else 1
+
+
+@pytest.mark.parametrize("which", ["step", "admit_mid", "admit_final"])
+def test_mistral_tp4_executables_fit_one_chip(mistral_tp4, which):
+    """Each chip holds a quarter of the weights (11.48 GB; the middle
+    chunk leaves the output head, 0.2 GB, out of its arguments), its
+    quarter of the paged pool (1.07 GB) and the executable's scratch,
+    within what the v5e compiler allows."""
+    used = _bytes_on_device(mistral_tp4[which])
+    assert 12.3e9 < used < V5E_COMPILER_HBM, used
+
+
+@pytest.mark.parametrize("which", ["step", "admit_mid", "admit_final"])
+def test_mistral_tp4_all_reduces_and_their_scope(mistral_tp4, which):
+    """Two all-reduces of the residual stream a layer (after attention
+    and after the MLP) and one after the embedding lookup over the split
+    vocabulary, each of 32 tokens x 12288 x bf16 in a decode step and 256
+    x 12288 x bf16 in an admission chunk, every one in the
+    `tp_all_reduce` scope that `allreduce_ms.decode` reads.  Sampling's
+    own small all-reduces are not counted."""
+    from repro.launch.hlo_analysis import op_scopes
+    text = mistral_tp4[which].as_text()
+    scopes = op_scopes(text)
+    big = [(n, runs) for n, nbytes, runs in _all_reduces_per_run(text)
+           if nbytes == AR_BYTES[which]]
+    assert sum(runs for _, runs in big) == 2 * MISTRAL_LAYERS + 1, big
+    assert {scopes.get(n) for n, _ in big} == {"tp_all_reduce"}, \
+        [(n, scopes.get(n)) for n, _ in big]
 
 
 @pytest.mark.parametrize("nbytes", [1024, 64 * 2**10, 2**20],
