@@ -211,6 +211,10 @@ class ServeMetrics:
     peak_kv_tokens: int          # high-water cache footprint, in tokens
     kv_capacity_tokens: int      # reserved footprint of the layout
     cache_utilization: float     # occupied / reserved at peak-usage basis
+    # paged decode steps: mean over steps of the K/V blocks the step's
+    # attention reads (each live slot's ``ceil((pos + 1) / block_size)``)
+    # over the whole table (``slots * max_blocks``); 0 when not paged
+    kv_read_share: float = 0.0
     cache_stats: Optional[Dict[str, Any]] = None
     # speculative decoding (see class docstring; zeros when disabled)
     spec_steps: int = 0
@@ -309,6 +313,9 @@ class ServeMetrics:
             peak_kv_tokens=peak,
             kv_capacity_tokens=sum(m.kv_capacity_tokens for m in parts),
             cache_utilization=occupied / peak if peak else 0.0,
+            kv_read_share=sum(m.kv_read_share * m.steps for m in parts)
+            / sum(m.steps for m in parts)
+            if sum(m.steps for m in parts) else 0.0,
             cache_stats=None,
             spec_steps=spec_steps,
             drafted_tokens=drafted, accepted_tokens=accepted,
@@ -539,6 +546,7 @@ class ContinuousBatcher:
         self._wall0 = None
         self._wall_run = 0.0     # wall seconds of the last run(), at drain
         self._peak_occupied = 0  # max sum of live positions, in tokens
+        self._kv_read = [0.0, 0]  # sum of per-step kv_read_share, steps
         self._requeue: List[Request] = []   # preempted, awaiting re-admission
         # evictions this run, counted at the batcher so the dense layout
         # reports them too (the allocator's counter only exists when
@@ -1091,6 +1099,8 @@ class ContinuousBatcher:
             occ = int(self.positions[self.active_mask].sum()) + \
                 int(self.active_mask.sum())
             self._peak_occupied = max(self._peak_occupied, occ)
+            if self.paged:
+                self._count_kv_read()
             if self._dirty:
                 self._push_state()
         was_active = self.active_mask.copy()
@@ -1122,6 +1132,16 @@ class ContinuousBatcher:
                 if done[s]:
                     self._release(s, now)
 
+    def _count_kv_read(self) -> None:
+        """The blocks this plain step's paged attention reads, each live
+        slot's ``ceil((pos + 1) / block_size)``, as a share of the
+        ``slots * max_blocks`` that gathering the whole table reads."""
+        live = self.positions[self.active_mask] + 1
+        read = int((-(-live // self.block_size)).sum())
+        self._kv_read[0] += read / (self.slots * self.s_max
+                                    // self.block_size)
+        self._kv_read[1] += 1
+
     # -- trace replay --------------------------------------------------------
 
     def reset_run_stats(self) -> None:
@@ -1132,6 +1152,7 @@ class ContinuousBatcher:
         itself; current slot ownership is untouched."""
         self.steps_run = 0
         self._peak_occupied = 0
+        self._kv_read = [0.0, 0]
         self.outputs = {}
         self._spec_steps = self._spec_drafted = 0
         self._spec_accepted = self._spec_k_sum = 0
@@ -1323,6 +1344,8 @@ class ContinuousBatcher:
             tpot_steps_p99=_percentile(tpot, 99),
             preemptions=preempt, peak_kv_tokens=int(peak_tok),
             kv_capacity_tokens=int(cap), cache_utilization=float(util),
+            kv_read_share=self._kv_read[0] / self._kv_read[1]
+            if self._kv_read[1] else 0.0,
             cache_stats=cache_stats,
             spec_steps=self._spec_steps,
             drafted_tokens=self._spec_drafted,

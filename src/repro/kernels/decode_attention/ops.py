@@ -1,11 +1,10 @@
-"""Public wrappers: batch/sequence padding for the decode-attention kernel
-and the paged (block-table) variant."""
+"""Public wrapper: batch/sequence padding for the decode-attention kernel."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from .kernel import decode_attention_call, paged_decode_attention_call
+from .kernel import decode_attention_call
 
 
 def decode_attention(q, k, v, positions, *, window: int = 0,
@@ -32,21 +31,4 @@ def decode_attention(q, k, v, positions, *, window: int = 0,
     return out[:B]
 
 
-def paged_decode_attention(q, k_phys, v_phys, block_tbl, positions, *,
-                           window: int = 0, interpret=False):
-    """Paged decode attention: K/V gathered through a block table.
-
-    q: (B, Hq, hd); k_phys/v_phys: (n_blocks, block_size, Hkv, hd);
-    block_tbl: (B, max_blocks) int32 (trash entries must only cover
-    positions > pos); positions: (B,) -> (B, Hq, hd).
-
-    No padding is applied: the grid iterates (B, Hq, max_blocks) directly —
-    sequence length is already block-quantized by construction and batch is
-    unblocked (see kernel docstring).
-    """
-    return paged_decode_attention_call(
-        q, k_phys, v_phys, block_tbl, positions, window=window,
-        q_per_kv=q.shape[1] // k_phys.shape[2], interpret=interpret)
-
-
-__all__ = ["decode_attention", "paged_decode_attention"]
+__all__ = ["decode_attention"]

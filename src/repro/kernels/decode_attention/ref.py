@@ -25,20 +25,4 @@ def decode_attention_ref(q, k, v, positions, *, window: int = 0):
     return o.reshape(B, Hq, hd).astype(q.dtype)
 
 
-def paged_decode_attention_ref(q, k_phys, v_phys, block_tbl, positions, *,
-                               window: int = 0):
-    """Oracle for the paged kernel: gather the logical K/V view through the
-    block table, then run the dense oracle.
-
-    q: (B, Hq, hd); k_phys/v_phys: (n_blocks, bs, Hkv, hd);
-    block_tbl: (B, max_blocks) int32; positions: (B,).
-    """
-    B = q.shape[0]
-    mb, bs = block_tbl.shape[1], k_phys.shape[1]
-    Hkv, hd = k_phys.shape[2], k_phys.shape[3]
-    k = k_phys[block_tbl].reshape(B, mb * bs, Hkv, hd)
-    v = v_phys[block_tbl].reshape(B, mb * bs, Hkv, hd)
-    return decode_attention_ref(q, k, v, positions, window=window)
-
-
-__all__ = ["decode_attention_ref", "paged_decode_attention_ref"]
+__all__ = ["decode_attention_ref"]

@@ -21,6 +21,7 @@ from jax import lax
 from ..core.pcontext import ParallelCtx
 from ..core import hierarchical as hier
 from ..core import overlap as ov
+from ..kernels.decode_attention import paged_decode_attention
 from .common import ModelConfig, GQAPlan, dense_init, split_keys, place_heads
 
 Params = Dict[str, jax.Array]
@@ -292,7 +293,8 @@ def attention_decode(p: Params, h: jax.Array, cache: Dict[str, jax.Array],
                      q_mask_tbl: Optional[np.ndarray] = None,
                      chunk: Optional[int] = None, ring: bool = False,
                      project: bool = True,
-                     block_tbl: Optional[jax.Array] = None
+                     block_tbl: Optional[jax.Array] = None,
+                     kv_lens: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One-token decode step against a KV cache.
 
@@ -312,9 +314,11 @@ def attention_decode(p: Params, h: jax.Array, cache: Dict[str, jax.Array],
       positions, so the sliding-window mask degenerates to slot-validity.
     * paged: ``block_tbl`` (B, max_blocks) int32 maps logical blocks to
       physical blocks of cache['k'] (n_blocks, block_size, U, hd); the new
-      token scatters through the table and K/V are gathered back to the
-      logical (B, max_blocks*block_size, U, hd) layout before attention, so
-      the math is identical to the dense path on the same logical contents.
+      token scatters through the table and each slot attends over its
+      first ``kv_lens`` positions (default ``positions + 1``): on a TPU
+      read in place by the paged kernel, elsewhere gathered back to the
+      logical (B, max_blocks*block_size, U, hd) layout, the same math as
+      the dense path on the same logical contents.
     """
     if block_tbl is not None:
         assert not ring, "paged cache is incompatible with the ring buffer"
@@ -323,7 +327,8 @@ def attention_decode(p: Params, h: jax.Array, cache: Dict[str, jax.Array],
         return _attention_decode_paged(p, h, cache, cfg, plan, ctx,
                                        positions=positions,
                                        q_mask_tbl=q_mask_tbl, chunk=chunk,
-                                       project=project, block_tbl=block_tbl)
+                                       project=project, block_tbl=block_tbl,
+                                       kv_lens=kv_lens)
     q, k_new, v_new = _qkv_rope(p, h, cfg, plan, positions[:, None])
     B, S_max = cache["k"].shape[0], cache["k"].shape[1]
     quant = cache["k"].dtype == jnp.int8
@@ -377,38 +382,70 @@ def _attention_decode_paged(p: Params, h: jax.Array,
                             cache: Dict[str, jax.Array], cfg: ModelConfig,
                             plan: GQAPlan, ctx: ParallelCtx, *,
                             positions: jax.Array, q_mask_tbl, chunk,
-                            project: bool, block_tbl: jax.Array):
+                            project: bool, block_tbl: jax.Array,
+                            kv_lens: Optional[jax.Array]):
     """Paged one-token decode: scatter the new K/V through the block table,
-    gather the logical view, then attend exactly like the dense path.
+    then attend over each slot's first ``kv_lens`` positions.
 
-    Table rows of inactive slots point at the pool's reserved trash block;
-    their writes land there and their reads are discarded by the caller, so
-    the whole fixed-shape batch keeps stepping without masking."""
+    On a TPU the Pallas kernel reads each slot's live blocks where they lie
+    in the pool; elsewhere (the CPU) :func:`paged_attention_gathered`
+    gathers the logical view and masks it, the kernel's reference.  Table
+    rows of inactive slots point at the pool's reserved trash block; their
+    writes land there and their reads (none, at ``kv_lens`` 0) are
+    discarded by the caller, so the fixed-shape batch keeps stepping."""
     q, k_new, v_new = _qkv_rope(p, h, cfg, plan, positions[:, None])
     B = h.shape[0]
     bs_blk = cache["k"].shape[1]
-    S_max = block_tbl.shape[1] * bs_blk
     bidx = jnp.arange(B)
     with jax.named_scope("kv_write"):
         pb = block_tbl[bidx, positions // bs_blk]    # (B,) physical block
         off = positions % bs_blk
         k = cache["k"].at[pb, off].set(k_new[:, 0].astype(cache["k"].dtype))
         v = cache["v"].at[pb, off].set(v_new[:, 0].astype(cache["v"].dtype))
+    if kv_lens is None:
+        kv_lens = positions + 1
+    window = cfg.sliding_window
+
+    def in_place(q, k, v, tbl, pos, lens):
+        with jax.named_scope("attention"):
+            return paged_decode_attention(q[:, 0], k, v, tbl, lens,
+                                          window=window)[:, None]
+
+    def gathered(q, k, v, tbl, pos, lens):
+        return paged_attention_gathered(q, k, v, tbl, pos, g=plan.g,
+                                        window=window, chunk=chunk)
+
+    o = lax.platform_dependent(q, k, v, block_tbl, positions, kv_lens,
+                               tpu=in_place, default=gathered)
+    if q_mask_tbl is not None:
+        with jax.named_scope("attention"):
+            o = o * take_local(q_mask_tbl, ctx)[None, None, :, None].astype(o.dtype)
+    out = _o_proj(o, p["wo"]) if project else o
+    return out, {"k": k, "v": v}
+
+
+def paged_attention_gathered(q: jax.Array, k: jax.Array, v: jax.Array,
+                             block_tbl: jax.Array, positions: jax.Array, *,
+                             g: int, window: int = 0,
+                             chunk: Optional[int] = None) -> jax.Array:
+    """One-token attention over a paged pool through its logical view:
+    gather every slot's (max_blocks*bs, U, hd) rows through the table,
+    then mask past ``positions`` and attend as the dense path does.
+
+    q: (B, 1, U*g, hd); k/v: (n_blocks, bs, U, hd); block_tbl:
+    (B, max_blocks).  Returns (B, 1, U*g, hd)."""
+    B = q.shape[0]
+    S_max = block_tbl.shape[1] * k.shape[1]
     U, hd = k.shape[-2], k.shape[-1]
     with jax.named_scope("kv_gather"):
         k_log = k[block_tbl].reshape(B, S_max, U, hd)
         v_log = v[block_tbl].reshape(B, S_max, U, hd)
     with jax.named_scope("attention"):
         kpos = jnp.arange(S_max, dtype=jnp.int32)
-        mask = _mask(positions[:, None], kpos, causal=True,
-                     window=cfg.sliding_window)
+        mask = _mask(positions[:, None], kpos, causal=True, window=window)
         if chunk is None:
             chunk = 1024 if S_max > 8192 else 0
-        o = attn_core(q, k_log, v_log, mask, plan.g, chunk=chunk)
-        if q_mask_tbl is not None:
-            o = o * take_local(q_mask_tbl, ctx)[None, None, :, None].astype(o.dtype)
-    out = _o_proj(o, p["wo"]) if project else o
-    return out, {"k": k, "v": v}
+        return attn_core(q, k_log, v_log, mask, g, chunk=chunk)
 
 
 def attention_chunk_step(p: Params, h: jax.Array,

@@ -696,10 +696,12 @@ def seed_cache(cache: Params, states: Params, *, slot=None,
 def block_decode(bp: Params, x, cache_l: Params, ap: ArchPlan,
                  ctx: ParallelCtx, *, positions,
                  attn_chunk=None, kv_ring: bool = False,
-                 block_tbl=None) -> Tuple[Any, Params]:
+                 block_tbl=None, kv_lens=None) -> Tuple[Any, Params]:
     """One block, one token.  x: (B,1,D) replicated; cache_l: this layer's
-    cache slice.  Returns (x, new_cache_l).  Every sublayer output is a
-    TP-partial reduced by tp_all_reduce — the collective the paper targets.
+    cache slice; ``kv_lens`` the positions each slot attends in a paged
+    cache (see ``layers.attention_decode``).  Returns (x, new_cache_l).
+    Every sublayer output is a TP-partial reduced by tp_all_reduce — the
+    collective the paper targets.
 
     When the cache carries an ``ef`` leaf (quantized all-reduce with error
     feedback, shape (sites, 1, B, D) per layer locally), the dense attn-wo
@@ -745,7 +747,7 @@ def block_decode(bp: Params, x, cache_l: Params, ap: ArchPlan,
         bp["attn"], h, kv_in, cfg, ap.gqa,
         ctx, positions=positions, q_mask_tbl=ap.q_mask_tbl,
         chunk=attn_chunk, ring=kv_ring, project=hybrid,
-        block_tbl=block_tbl)
+        block_tbl=block_tbl, kv_lens=kv_lens)
     new_c.update(kv_new)
     ef_attn = ef_mlp = None
     if hybrid:
@@ -881,7 +883,7 @@ def _layers_in_place(body, x, blocks, cache, block_tbl=None, *,
 def decode_step(params: Params, cache: Params, tokens, positions,
                 ap: ArchPlan, ctx: ParallelCtx, *,
                 scan_layers: bool = True, layer_map=None,
-                attn_chunk=None, kv_ring: bool = False):
+                attn_chunk=None, kv_ring: bool = False, kv_lens=None):
     """One decode step for the whole batch.
 
     tokens: (B,) int32; positions: (B,) write index.  Returns
@@ -889,7 +891,9 @@ def decode_step(params: Params, cache: Params, tokens, positions,
 
     A paged cache (``cache['block_tbl']`` present) routes K/V writes/reads
     through the table; the table itself has no layer axis, so it rides
-    outside the layer scan and is returned unchanged.
+    outside the layer scan and is returned unchanged.  ``kv_lens`` (B,)
+    bounds the positions each slot reads there (default ``positions + 1``;
+    0 for a slot whose output is discarded).
     """
     cfg = ap.cfg
     block_tbl = cache.get("block_tbl") if isinstance(cache, dict) else None
@@ -902,7 +906,7 @@ def decode_step(params: Params, cache: Params, tokens, positions,
             bp = layer_map(bp)
         return block_decode(bp, x, cl, ap, ctx, positions=positions,
                             attn_chunk=attn_chunk, kv_ring=kv_ring,
-                            block_tbl=tbl)
+                            block_tbl=tbl, kv_lens=kv_lens)
 
     x, new_cache = _layers_in_place(body, x, params["blocks"], cache,
                                     block_tbl, scan=scan_layers)

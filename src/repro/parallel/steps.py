@@ -575,7 +575,8 @@ def build_serve_step(ap: ArchPlan, ctx: ParallelCtx, mesh, *, s_max: int,
     with ``fold_in(rng[s], sample_idx[s])`` — the request's own chain, so
     sampled streams are schedule-independent (see ``_sample_next_slots``).
     Inactive slots keep decoding into their own (dense) row or the
-    trash block (paged) — no masking in the hot path; ``emitted`` holds the
+    trash block (paged), and a paged cache reads none of their blocks —
+    no masking in the hot path; ``emitted`` holds the
     sampled token where active, the stale token elsewhere, and ``done``
     flags slots that finished this step (caller frees/refills them).
     ``ar_table`` / ``ctx.overlap_matmul`` behave as in build_decode_step.
@@ -596,7 +597,8 @@ def build_serve_step(ap: ArchPlan, ctx: ParallelCtx, mesh, *, s_max: int,
             logits, new_cache = decode_step(
                 params, cache, state["tokens"], state["positions"], ap,
                 serve_ctx, scan_layers=scan_layers, layer_map=layer_map,
-                attn_chunk=attn_chunk)
+                attn_chunk=attn_chunk,
+                kv_lens=jnp.where(active, state["positions"] + 1, 0))
         with jax.named_scope("sample"):
             nxt = _sample_next_slots(logits, serve_ctx, cfg, state["rng"],
                                      state["sample_idx"], temperature, top_k)

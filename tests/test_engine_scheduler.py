@@ -301,3 +301,37 @@ def test_trace_metrics_sane(tiny_lm):
     assert m.peak_kv_tokens <= m.kv_capacity_tokens
     d = m.to_dict()
     assert d["cache_stats"]["block_size"] == 8
+
+
+def test_kv_read_share_counts_live_blocks(tiny_lm):
+    """``kv_read_share`` is the mean over decode steps of the blocks the
+    paged attention reads, ``ceil((pos + 1) / block_size)`` summed over the
+    live slots, over ``slots * max_blocks``; slots left idle (their stale
+    positions included) add nothing."""
+    cfg, ap, params = tiny_lm
+    bs, spec = 8, RS.replace(block_size=8)
+    sched = build_replica(spec, ap=ap, params=params)
+    seen = []
+    serve = sched._serve
+
+    def recording(*a):
+        seen.append((sched.positions.copy(), sched.active_mask.copy()))
+        return serve(*a)
+
+    sched._serve = recording
+    rng = np.random.default_rng(5)
+    reqs = [Request(rid=i, prompt=rng.integers(
+        0, cfg.vocab_size, 5 + 11 * i).astype(np.int32),
+        max_new=4 + 9 * (i % 2), arrival_s=0.0) for i in range(4)]
+    done = sched.run(reqs)
+    m = sched.metrics(done)
+    table = spec.slots * spec.s_max // bs
+    per_step = [(-(-(pos[act] + 1) // bs)).sum() / table
+                for pos, act in seen]
+    assert m.steps == len(seen) > 0
+    assert m.kv_read_share == pytest.approx(np.mean(per_step))
+    idle = [(pos, act) for pos, act in seen if not act.all()]
+    assert any((pos[~act] > 0).any() for pos, act in idle), \
+        "the trace should leave a slot idle with a stale position"
+    assert m.kv_read_share < np.mean(
+        [(-(-(pos + 1) // bs)).sum() / table for pos, _ in seen])

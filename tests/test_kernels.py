@@ -12,8 +12,7 @@ except ImportError:  # pure-pytest fallback (requirements-dev.txt)
 from repro.kernels.flash_attention import flash_attention, flash_attention_ref
 from repro.kernels.decode_attention import (decode_attention,
                                             decode_attention_ref,
-                                            paged_decode_attention,
-                                            paged_decode_attention_ref)
+                                            paged_decode_attention)
 from repro.kernels.rwkv6_scan import rwkv6_scan, rwkv6_scan_ref
 from repro.kernels.ssm_scan import ssm_scan, ssm_scan_ref
 
@@ -99,74 +98,84 @@ def test_decode_attention_position_property(b_seed, pos_val):
 
 
 # ---------------------------------------------------------------------------
-# paged decode attention (block-table gather)
+# paged decode attention (live blocks read in place through the table)
 # ---------------------------------------------------------------------------
 
-# (B, Hq, Hkv, bs, max_blocks, n_blocks, hd, window, dtype)
+# (B, U, g, lengths, window, layer of n_layers, share, dtype); the pool is a
+# stacked (n_layers*n_blocks, bs, U, hd) one and the table is offset to the
+# layer's blocks, as the layer loop passes them.  ``share``: slot 1 reads
+# slot 0's first two blocks (a shared prefix).  Length 0 is an inactive
+# slot, its row on the trash block.
 PAGED_CASES = [
-    (4, 4, 2, 16, 8, 40, 64, 0, jnp.float32),
-    (3, 8, 1, 32, 4, 16, 128, 0, jnp.float32),
-    (2, 2, 2, 64, 4, 12, 64, 128, jnp.bfloat16),   # sliding window
-    (5, 6, 2, 8, 8, 48, 64, 0, jnp.float32),
+    (4, 4, 8, (1, 16, 17, 2047), 0, (0, 1), False, jnp.bfloat16),
+    (4, 2, 12, (2047, 17, 16, 1), 0, (1, 2), False, jnp.bfloat16),
+    (3, 2, 4, (300, 64, 5), 128, (0, 1), False, jnp.float32),
+    (4, 4, 2, (40, 40, 0, 33), 0, (2, 3), True, jnp.float32),
+    (4, 2, 8, (128, 0, 129, 256), 48, (1, 2), True, jnp.float32),
+    (4, 2, 8, (0, 0, 33, 0), 0, (0, 1), False, jnp.float32),
 ]
+PAGED_BS, PAGED_MB, PAGED_HD = 16, 128, 128
+
+
+def _paged_problem(B, U, g, lengths, layer, share, dt, seed=0):
+    """Query, stacked pools, the layer's offset table and the lengths."""
+    li, n_layers = layer
+    r = np.random.default_rng(seed)
+    bs, mb, hd = PAGED_BS, PAGED_MB, PAGED_HD
+    nb = B * mb + 1                               # per layer; block 0 trash
+    q = jnp.asarray(r.standard_normal((B, U * g, hd)), dt)
+    k = jnp.asarray(r.standard_normal((n_layers * nb, bs, U, hd)), dt)
+    v = jnp.asarray(r.standard_normal((n_layers * nb, bs, U, hd)), dt)
+    tbl = (1 + r.permutation(nb - 1)[:B * mb]).reshape(B, mb)
+    lengths = np.asarray(lengths, np.int32)
+    tbl[lengths == 0] = 0                         # inactive: trash row
+    if share:
+        tbl[1, :2] = tbl[0, :2]
+    tbl = jnp.asarray(tbl + li * nb, jnp.int32)
+    return q, k, v, tbl, jnp.asarray(lengths)
 
 
 @pytest.mark.parametrize("case", PAGED_CASES,
                          ids=[f"case{i}" for i in range(len(PAGED_CASES))])
 def test_paged_decode_attention(case):
-    B, Hq, Hkv, bs, mb, nb, hd, window, dt = case
-    assert nb >= B * mb + 1
-    q = jnp.asarray(rng.standard_normal((B, Hq, hd)), dt)
-    k = jnp.asarray(rng.standard_normal((nb, bs, Hkv, hd)), dt)
-    v = jnp.asarray(rng.standard_normal((nb, bs, Hkv, hd)), dt)
-    # random (collision-free) logical->physical mapping; block 0 is trash
-    tbl = jnp.asarray(1 + rng.permutation(nb - 1)[:B * mb].reshape(B, mb),
-                      jnp.int32)
-    pos = jnp.asarray(rng.integers(0, mb * bs, B), jnp.int32)
-    out = paged_decode_attention(q, k, v, tbl, pos, window=window,
+    """The kernel against the gathered XLA path (its reference) on the
+    same pool and table: every live slot's output; an inactive slot reads
+    nothing and returns zeros."""
+    from repro.models.layers import paged_attention_gathered
+    B, U, g, lengths, window, layer, share, dt = case
+    q, k, v, tbl, lens = _paged_problem(B, U, g, lengths, layer, share, dt)
+    out = paged_decode_attention(q, k, v, tbl, lens, window=window,
                                  interpret=True)
-    ref = paged_decode_attention_ref(q, k, v, tbl, pos, window=window)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
+    ref = paged_attention_gathered(q[:, None], k, v, tbl, lens - 1, g=g,
+                                   window=window)[:, 0]
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(ref, np.float32)[live],
                                atol=_tol(dt), rtol=_tol(dt))
+    assert not np.asarray(out, np.float32)[~live].any()
 
 
-def test_paged_decode_attention_matches_dense_gather():
-    """Paged kernel on a scattered table == dense kernel on the gathered
-    logical view (the model-level parity the serving stack relies on)."""
-    B, Hq, Hkv, bs, mb, nb, hd = 3, 4, 2, 32, 4, 16, 64
-    r = np.random.default_rng(7)
-    q = jnp.asarray(r.standard_normal((B, Hq, hd)), jnp.float32)
-    k = jnp.asarray(r.standard_normal((nb, bs, Hkv, hd)), jnp.float32)
-    v = jnp.asarray(r.standard_normal((nb, bs, Hkv, hd)), jnp.float32)
-    tbl = jnp.asarray(1 + r.permutation(nb - 1)[:B * mb].reshape(B, mb),
-                      jnp.int32)
-    pos = jnp.asarray([5, 63, 127], jnp.int32)
-    out_p = paged_decode_attention(q, k, v, tbl, pos, interpret=True)
-    k_log = k[tbl].reshape(B, mb * bs, Hkv, hd)
-    v_log = v[tbl].reshape(B, mb * bs, Hkv, hd)
-    out_d = decode_attention(q, k_log, v_log, pos, interpret=True)
-    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_d),
-                               atol=1e-5, rtol=1e-5)
-
-
-def test_paged_decode_attention_trash_isolation():
-    """Scribbling on the trash block (0) and on unreachable blocks must not
-    change the output — the isolation invariant preemption relies on."""
-    B, Hq, Hkv, bs, mb, nb, hd = 2, 2, 1, 16, 4, 32, 64
-    r = np.random.default_rng(11)
-    q = jnp.asarray(r.standard_normal((B, Hq, hd)), jnp.float32)
-    k = jnp.asarray(r.standard_normal((nb, bs, Hkv, hd)), jnp.float32)
-    v = jnp.asarray(r.standard_normal((nb, bs, Hkv, hd)), jnp.float32)
-    tbl = jnp.asarray(1 + r.permutation(nb - 1)[:B * mb].reshape(B, mb),
-                      jnp.int32)
-    pos = jnp.asarray([30, 61], jnp.int32)
-    out1 = paged_decode_attention(q, k, v, tbl, pos, interpret=True)
-    live = np.unique(np.asarray(tbl))
-    dead = np.setdiff1d(np.arange(nb), live)
-    k2 = k.at[dead].set(999.0)
-    v2 = v.at[dead].set(-999.0)
-    out2 = paged_decode_attention(q, k2, v2, tbl, pos, interpret=True)
+@pytest.mark.parametrize("case", [PAGED_CASES[0], PAGED_CASES[4]],
+                         ids=["ragged", "window_shared"])
+def test_paged_decode_attention_ignores_unread_blocks(case):
+    """Scribbling on every block no slot reads (the trash block, other
+    layers' blocks, table entries past each length or before the window)
+    leaves the output bitwise unchanged."""
+    B, U, g, lengths, window, layer, share, dt = case
+    q, k, v, tbl, lens = _paged_problem(B, U, g, lengths, layer, share, dt,
+                                        seed=3)
+    out1 = paged_decode_attention(q, k, v, tbl, lens, window=window,
+                                  interpret=True)
+    t, n = np.asarray(tbl), np.asarray(lens)
+    hi = -(-n // PAGED_BS)
+    lo = np.maximum(n - window, 0) // PAGED_BS if window else 0 * n
+    read = np.unique(np.concatenate(
+        [t[b, lo[b]:hi[b]] for b in range(B)]))
+    dead = np.setdiff1d(np.arange(k.shape[0]), read)
+    k2 = k.at[dead].set(jnp.nan)
+    v2 = v.at[dead].set(jnp.nan)
+    out2 = paged_decode_attention(q, k2, v2, tbl, lens, window=window,
+                                  interpret=True)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
 
 

@@ -192,12 +192,17 @@ def test_one_chip_steps_name_their_cache_ops(one_chip_texts, which):
               f"{S_MAX // BLOCK},{BLOCK},{U},{hd}"}
     seen = [(n, op) for n, dims, op in _top_level(text) if dims in shapes
             and op not in MOVES_NOTHING]
-    assert len(seen) >= 4, seen
+    # the chunks write K and V and gather their slot's row; the decode
+    # step only writes, its kernel reading the blocks in place
+    assert len(seen) >= (2 if which == "step" else 4), seen
     assert all(scopes.get(n) in KV_SCOPES for n, _ in seen), \
         [(n, op, scopes.get(n)) for n, op in seen]
     want = KV_SCOPES | {"qkv", "o_proj", "mlp", "norm", "embed"}
     if which != "admit_mid":
         want |= {"head", "sample"}
+    if which == "step":
+        assert "kv_gather" not in set(scopes.values())
+        want -= {"kv_gather"}
     assert want <= set(scopes.values()), want - set(scopes.values())
 
 
@@ -425,18 +430,76 @@ def test_fused_matmul_rd_kernel_compiles(meshes):
 
 
 def test_paged_decode_attention_compiles(one_chip):
-    """The paged decode-attention kernel at head_dim 128 over the
-    one-chip cache geometry (32 heads, blocks of 16, s_max 2048)."""
+    """The paged decode-attention kernel over the cells' cache geometry
+    (blocks of 16, s_max 2048): 64 slots of codeqwen's 32 query and 4 KV
+    heads on one chip, and 32 slots of Mistral's 24 query and 2 KV heads
+    a chip at tp=4, each over a stacked pool of 16 layers."""
     from repro.kernels.decode_attention import paged_decode_attention
-    H, hd = 32, 128
+    hd = 128
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    q = sds((SLOTS, H, hd), jnp.bfloat16)
-    kv = sds((N_BLOCKS, BLOCK, H, hd), jnp.bfloat16)
-    tbl = sds((SLOTS, S_MAX // BLOCK), jnp.int32)
-    pos = sds((SLOTS,), jnp.int32)
-    text = jax.jit(paged_decode_attention).lower(
-        q, kv, kv, tbl, pos).compile().as_text()
-    assert "tpu_custom_call" in text
+    for slots, Hq, U in ((64, 32, 4), (32, 24, 2)):
+        n_blocks = 16 * (slots * (S_MAX // BLOCK) + 1)
+        q = sds((slots, Hq, hd), jnp.bfloat16)
+        kv = sds((n_blocks, BLOCK, U, hd), jnp.bfloat16)
+        tbl = sds((slots, S_MAX // BLOCK), jnp.int32)
+        lens = sds((slots,), jnp.int32)
+        text = jax.jit(paged_decode_attention).lower(
+            q, kv, kv, tbl, lens).compile().as_text()
+        assert "tpu_custom_call" in text
+
+
+CELL_SLOTS = 64
+
+
+@pytest.fixture(scope="module")
+def codeqwen_cell_step(one_chip):
+    """The one-chip decode step at the codeqwen cell's geometry (4 KV
+    heads, 64 slots x 2048, blocks of 16), 2 of its 16 layers."""
+    cfg = dataclasses.replace(get_config("codeqwen1.5-7b"), n_layers=2,
+                              n_kv_heads=4)
+    ap = make_plan(cfg, 1)
+    step = build_serve_step(ap, LOCAL, None, s_max=S_MAX, slots=CELL_SLOTS,
+                            block_size=BLOCK,
+                            n_blocks=CELL_SLOTS * (S_MAX // BLOCK) + 1)
+    args = [_placed(a, one_chip)
+            for a in _serve_args(ap, local=True, slots=CELL_SLOTS)]
+    return step.jit().lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("cell", ["codeqwen_1chip", "mistral_tp4"])
+def test_served_decode_step_reads_the_pool_in_place(request, cell):
+    """The served decode step at each decode cell's geometry reads K/V
+    through the paged kernel: the stacked pools go to its
+    ``tpu_custom_call`` as they lie, no instruction makes the gathered
+    view (``(slots*max_blocks, bs, U, hd)`` or ``(slots, s_max, U,
+    hd)``), and no instruction on the pool's shape copies or transposes
+    it: the only ones that make it are the ``kv_write`` scatters."""
+    from repro.launch.hlo_analysis import op_scopes
+    if cell == "codeqwen_1chip":
+        text = request.getfixturevalue("codeqwen_cell_step")
+        slots, U, layers = CELL_SLOTS, 4, 2
+    else:
+        text = request.getfixturevalue("mistral_tp4")["step"].as_text()
+        slots, U, layers = MISTRAL_SLOTS, 2, MISTRAL_LAYERS
+    mb, hd = S_MAX // BLOCK, 128
+    nb = slots * mb + 1
+    pool = f"{layers * nb},{BLOCK},{U},{hd}"
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "custom-call(" in line]
+    assert len(kernels) == 1, kernels
+    assert kernels[0].count(f"bf16[{pool}]") == 2, kernels[0][:400]
+    gathered = {f"{slots * mb},{BLOCK},{U},{hd}", f"{slots},{S_MAX},{U},{hd}"}
+    made = [(n, op) for n, _, dims, op, _ in _instructions(text)
+            if dims in gathered]
+    assert not made, made
+    scopes = op_scopes(text)
+    on_pool = [(n, op, scopes.get(n)) for n, _, dims, op, _ in
+               _instructions(text)
+               if dims in (pool, f"{layers},{nb},{BLOCK},{U},{hd}")
+               and op not in MOVES_NOTHING]
+    assert on_pool, "no instruction writes the pool"
+    assert all(op in ("fusion", "scatter") and sc == "kv_write"
+               for _, op, sc in on_pool), on_pool
