@@ -12,12 +12,12 @@ This module provides the dispatcher behind ``ar_strategy="auto"``:
 
 * a **dispatch table** keyed on (message-byte bucket, fast-axis size,
   slow-axis size, dtype) mapping to an :class:`ARChoice`
-  (strategy + rd_chunks + compression);
+  (strategy + rd_chunks + wire quantization);
 * **analytic seeding** from the alpha-beta models in
   :mod:`repro.core.comm_model` (each strategy's predicted time on the
   configured :class:`NetworkSpec`, honest full-exchange form for RD since
   that is what :func:`repro.core.hierarchical.rd_all_reduce` implements);
-* **measurement refinement**: benchmarks record observed latencies with
+* **measurement refinement**: callers record observed latencies with
   :meth:`AutoTuner.record`; :meth:`AutoTuner.refine` overrides the analytic
   pick wherever a measured winner exists;
 * **JSON persistence** (:meth:`AutoTuner.save` / :meth:`AutoTuner.load`) so a
@@ -65,7 +65,6 @@ class ARChoice:
 
     strategy: str                 # one of DISPATCHABLE
     rd_chunks: int = 1            # slow-axis pipeline chunks (hier_rd only)
-    compress_slow: bool = False   # int8-compress the slow exchange (lossy)
     quant: str = "none"           # wire quantization level (QUANT_LEVELS)
 
     def apply(self, ctx):
@@ -76,8 +75,7 @@ class ARChoice:
         decision and must survive dispatch.  Both fields go through one
         ``replace`` so the ctx validator never sees the half-resolved
         state (ar_quant='auto' with a concrete strategy)."""
-        kw = dict(ar_strategy=self.strategy, rd_chunks=self.rd_chunks,
-                  compress_slow=self.compress_slow)
+        kw = dict(ar_strategy=self.strategy, rd_chunks=self.rd_chunks)
         if getattr(ctx, "ar_quant", "none") == "auto":
             kw["ar_quant"] = self.quant
         return ctx.replace(**kw)
@@ -162,8 +160,7 @@ def analytic_sp_choice(msg_bytes: float, fast_size: int, slow_size: int,
 
 
 def analytic_choice(msg_bytes: float, fast_size: int, slow_size: int,
-                    net: cm.NetworkSpec, *,
-                    allow_lossy: bool = False) -> ARChoice:
+                    net: cm.NetworkSpec) -> ARChoice:
     """Best strategy under the alpha-beta model (ties break toward the
     fewest-latency-steps strategy by dict order: flat < hier_ring < hier_rd
     is not the right order, so we order candidates explicitly)."""
@@ -174,16 +171,7 @@ def analytic_choice(msg_bytes: float, fast_size: int, slow_size: int,
     rd_chunks = 1
     if best == "hier_rd" and slow_size > 1:
         rd_chunks = _rd_chunks_for(msg_bytes, fast_size)
-    compress = False
-    if allow_lossy and slow_size > 1:
-        # int8 exchange quarters (f32) / halves (bf16) the slow payload at
-        # eta = 1 + 2/group overhead; worth it only when bandwidth-bound.
-        shard = msg_bytes / max(1, fast_size)
-        bw_term = (slow_size - 1) / slow_size * shard / net.beta_inter
-        lat_term = math.log2(max(2, slow_size)) * net.alpha_inter
-        compress = bw_term > 4.0 * lat_term
-    return ARChoice(strategy=best, rd_chunks=rd_chunks,
-                    compress_slow=compress)
+    return ARChoice(strategy=best, rd_chunks=rd_chunks)
 
 
 def predict_quant_times(msg_bytes: float, fast_size: int, slow_size: int,
@@ -273,6 +261,9 @@ def _parse_key(key: str) -> Tuple[int, int, int, str]:
     return 2 ** int(b[1:]), int(f[1:]), int(s[1:]), dtype
 
 
+_CHOICE_FIELDS = frozenset(f.name for f in dataclasses.fields(ARChoice))
+
+
 @dataclasses.dataclass
 class _Measurement:
     strategy: str
@@ -283,16 +274,14 @@ class _Measurement:
 class AutoTuner:
     """Per-call-site all-reduce dispatcher.
 
-    Analytic predictions seed every lookup; measurements (from
-    ``benchmarks/bench_allreduce.py --sweep`` or production telemetry)
-    override them after :meth:`refine`.  Thread-safe for the trace-time
-    lookup pattern.
+    Analytic predictions seed every lookup; measurements filed with
+    :meth:`record` (a caller's own timings of each strategy, or
+    production telemetry) override them after :meth:`refine`.
+    Thread-safe for the trace-time lookup pattern.
     """
 
-    def __init__(self, net: cm.NetworkSpec = cm.TPU_V5E, *,
-                 allow_lossy: bool = False):
+    def __init__(self, net: cm.NetworkSpec = cm.TPU_V5E):
         self.net = net
-        self.allow_lossy = allow_lossy
         self.table: Dict[str, ARChoice] = {}
         self.measurements: Dict[str, List[_Measurement]] = {}
         # trace-time lookup log: key -> times dispatched.  Lets a caller
@@ -324,8 +313,7 @@ class AutoTuner:
                 return hit
             if quant == "none":
                 choice = analytic_choice(msg_bytes, fast_size, slow_size,
-                                         self.net,
-                                         allow_lossy=self.allow_lossy)
+                                         self.net)
             else:
                 choice = analytic_quant_choice(msg_bytes, fast_size,
                                                slow_size, self.net, quant)
@@ -403,8 +391,6 @@ class AutoTuner:
                     if slow > 1:
                         rd_chunks = _rd_chunks_for(bucket_bytes, fast)
                 new = ARChoice(strategy=best.strategy, rd_chunks=rd_chunks,
-                               compress_slow=prev.compress_slow
-                               if prev else False,
                                quant=best.quant)
                 if prev != new:
                     self.table[key] = new
@@ -417,7 +403,6 @@ class AutoTuner:
         return {
             "version": TABLE_VERSION,
             "net": self.net.name,
-            "allow_lossy": self.allow_lossy,
             "table": {k: dataclasses.asdict(v)
                       for k, v in sorted(self.table.items())},
             "sp_table": dict(sorted(self.sp_table.items())),
@@ -444,16 +429,17 @@ class AutoTuner:
         or wrong-schema file: unreadable JSON, a non-object document, or
         an unknown schema version falls back to a fresh analytic tuner
         with a ``RuntimeWarning``; individually malformed table entries
-        are dropped (counted in the warning) and the rest kept."""
+        are dropped (counted in the warning) and the rest kept.
+
+        Tables saved by older versions carry fields of options since
+        removed (the int8 slow-exchange flag, in every entry).  Such a
+        field left off selects the path that still exists and is ignored;
+        one set on names a removed path, and its entry is dropped."""
         try:
             with open(path) as f:
                 doc = json.load(f)
         except (OSError, ValueError) as e:
             return cls._degraded(path, f"unreadable: {e}")
-        if isinstance(doc, dict) and "tuned_table" in doc \
-                and "table" not in doc:
-            # accept a BENCH_allreduce.json sweep artifact directly
-            doc = doc["tuned_table"]
         if not isinstance(doc, dict):
             return cls._degraded(path, f"JSON {type(doc).__name__}, "
                                        f"not an object")
@@ -462,7 +448,7 @@ class AutoTuner:
             return cls._degraded(path, f"schema version {version!r} != "
                                        f"{TABLE_VERSION}")
         net = cm.NETWORKS.get(doc.get("net", "tpu_v5e"), cm.TPU_V5E)
-        t = cls(net, allow_lossy=bool(doc.get("allow_lossy", False)))
+        t = cls(net)
         table = doc.get("table", {})
         sp_table = doc.get("sp_table", {})
         if not isinstance(table, dict) or not isinstance(sp_table, dict):
@@ -471,6 +457,10 @@ class AutoTuner:
         for k, v in table.items():
             try:
                 _parse_key(k)   # malformed keys never dispatch — reject
+                v = dict(v)
+                gone = [f for f in v if f not in _CHOICE_FIELDS]
+                if any(v.pop(f) for f in gone):
+                    raise ValueError(f"removed option set: {gone}")
                 c = ARChoice(**v)
                 if c.strategy not in DISPATCHABLE:
                     raise ValueError(f"unknown strategy {c.strategy!r}")
@@ -491,7 +481,8 @@ class AutoTuner:
             t.sp_table[k] = bool(v)
         if dropped:
             warnings.warn(f"ar-table {path!r}: dropped {dropped} "
-                          f"malformed entr{'y' if dropped == 1 else 'ies'}"
+                          f"malformed or removed-path "
+                          f"entr{'y' if dropped == 1 else 'ies'}"
                           f"; kept {len(t.table) + len(t.sp_table)}",
                           RuntimeWarning, stacklevel=2)
         return t

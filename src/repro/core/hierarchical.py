@@ -20,9 +20,10 @@ hier_rd_halving       RS(fast) + recursive halving/doubling(slow) + AG(fast)
 ====================  =======================================================
 
 Extras mirroring the paper's Sec. 4.2 optimizations where they transfer to
-TPU: chunked slow-axis exchange (4.2.1) and an int8-compressed exchange whose
-piggybacked scales play the role of the paper's fused payload metadata (4.2.2;
-see DESIGN.md for why flag words themselves do not transfer).
+TPU: chunked slow-axis exchange (4.2.1) and the low-bit exchange of
+``ParallelCtx.ar_quant`` (:func:`quant_rd_all_reduce`), whose piggybacked
+scales play the role of the paper's fused payload metadata (4.2.2; see
+DESIGN.md for why flag words themselves do not transfer).
 """
 from __future__ import annotations
 
@@ -175,45 +176,6 @@ def rd_halving_all_reduce(x: jax.Array, axis: str) -> jax.Array:
     return out.reshape(shape)
 
 
-def compressed_rd_all_reduce(x: jax.Array, axis: str,
-                             group: int = 128) -> jax.Array:
-    """Recursive doubling with int8-quantized exchanges.
-
-    Each step quantizes the outgoing partial sum to int8 with per-group
-    (``group`` elements) bf16 scales, exchanges payload+scales (the TPU
-    analogue of the paper's eta-packed fused payload), dequantizes and
-    reduces in f32.  eta = 1 + 2/group /? (int8 payload is 4x smaller than
-    f32; scales add 2/group bytes per element).
-    """
-    n = lax.axis_size(axis)
-    if n == 1:
-        return x
-    if not _is_pow2(n):
-        return lax.psum(x, axis)
-    orig_dtype = x.dtype
-    shape = x.shape
-    flat = x.reshape(-1).astype(jnp.float32)
-    pad = (-flat.shape[0]) % group
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    acc = flat
-    step = 1
-    while step < n:
-        g = acc.reshape(-1, group)
-        scale = jnp.max(jnp.abs(g), axis=-1, keepdims=True) / 127.0
-        scale = jnp.maximum(scale, 1e-30)
-        q = jnp.clip(jnp.round(g / scale), -127, 127).astype(jnp.int8)
-        perm = _xor_perm(n, step)
-        q_peer = lax.ppermute(q, axis, perm)
-        s_peer = lax.ppermute(scale.astype(jnp.bfloat16), axis, perm)
-        acc = acc + (q_peer.astype(jnp.float32)
-                     * s_peer.astype(jnp.float32)).reshape(-1)
-        step <<= 1
-    if pad:
-        acc = acc[: acc.shape[0] - pad]
-    return acc.reshape(shape).astype(orig_dtype)
-
-
 # ---------------------------------------------------------------------------
 # Quantized collective phases (ar_quant = int8 | int4)
 # ---------------------------------------------------------------------------
@@ -236,9 +198,9 @@ def compressed_rd_all_reduce(x: jax.Array, axis: str,
 def quant_rd_all_reduce(x: jax.Array, axis: str, bits: int) -> jax.Array:
     """Recursive doubling with a symmetric low-bit exchange.
 
-    Unlike :func:`compressed_rd_all_reduce` (which keeps its own
-    accumulator unquantized and lets XOR peers drift apart), BOTH sides of
-    every step requantize: ``acc <- deq(Q(acc)) + deq(Q(acc_peer))``.
+    BOTH sides of every step requantize (quantizing only the outgoing
+    partial and keeping one's own accumulator exact would let XOR peers
+    drift apart): ``acc <- deq(Q(acc)) + deq(Q(acc_peer))``.
     The two peers of a step hold the same pair {acc, acc_peer}, so they
     compute identical sums — by induction the final accumulator is exactly
     replicated across the axis, which the all-gather phase requires.
@@ -462,10 +424,7 @@ def _slow_phase(x: jax.Array, slow: Axes, ctx: ParallelCtx) -> jax.Array:
         if ctx.ar_strategy == "hier_ring":
             x = lax.psum(x, ax)
         elif ctx.ar_strategy == "hier_rd":
-            if ctx.compress_slow:
-                x = compressed_rd_all_reduce(x, ax)
-            else:
-                x = rd_all_reduce(x, ax, chunks=ctx.rd_chunks)
+            x = rd_all_reduce(x, ax, chunks=ctx.rd_chunks)
         elif ctx.ar_strategy == "hier_rd_halving":
             x = rd_halving_all_reduce(x, ax)
         else:  # pragma: no cover
@@ -473,45 +432,11 @@ def _slow_phase(x: jax.Array, slow: Axes, ctx: ParallelCtx) -> jax.Array:
     return x
 
 
-def quantized_all_gather(x: jax.Array, axes: Axes, dim: int,
-                         group: int = 128) -> jax.Array:
-    """All-gather with int8 payload + per-group bf16 scales.
-
-    The gathered value is each shard's FINAL (already-reduced) slice, so
-    quantization error does not accumulate across devices — one rounding of
-    the output activations (per-128-group scales keep it ~0.3% relative).
-    """
-    orig_dtype = x.dtype
-    moved = jnp.moveaxis(x, dim, -1)
-    shape = moved.shape
-    flat = moved.reshape(-1)
-    pad = (-flat.shape[0]) % group
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    g = flat.reshape(-1, group).astype(jnp.float32)
-    scale = jnp.maximum(jnp.max(jnp.abs(g), axis=-1, keepdims=True)
-                        / 127.0, 1e-30)
-    q = jnp.clip(jnp.round(g / scale), -127, 127).astype(jnp.int8)
-    qg = lax.all_gather(q.reshape(-1), axes, axis=0, tiled=False)
-    sg = lax.all_gather(scale.astype(jnp.bfloat16).reshape(-1), axes,
-                        axis=0, tiled=False)
-    # qg: (n, flat) stacked shards -> dequantize and stitch along dim
-    n = qg.shape[0]
-    deq = (qg.reshape(n, -1, group).astype(jnp.float32)
-           * sg.reshape(n, -1, 1).astype(jnp.float32)).reshape(n, -1)
-    if pad:
-        deq = deq[:, :-pad]
-    out = deq.reshape((n,) + shape)
-    out = jnp.concatenate(list(out), axis=-1)
-    return jnp.moveaxis(out, -1, dim).astype(orig_dtype)
-
-
 def _tp_all_reduce_fp(x: jax.Array, ctx: ParallelCtx,
                       scatter_dim: int) -> jax.Array:
     """Full-precision-wire all-reduce body (strategy already resolved)."""
     fast, slow = ctx.tp_fast, ctx.tp_slow
-    if (ctx.ar_strategy == "flat" or (not slow and len(fast) <= 1)) \
-            and not ctx.quant_ag:
+    if ctx.ar_strategy == "flat" or (not slow and len(fast) <= 1):
         # Single-level group: hand the whole reduction to XLA (the paper's
         # "NCCL default" baseline) — hierarchy needs two levels to matter.
         return lax.psum(x, slow + fast)
@@ -526,11 +451,8 @@ def _tp_all_reduce_fp(x: jax.Array, ctx: ParallelCtx,
     y = lax.psum_scatter(x, fast, scatter_dimension=dim, tiled=True)
     # Phase 2: recursive doubling (or ring) over the slow level (Eq. 4).
     if slow:
-        y = _slow_phase(y, slow, ctx if ctx.ar_strategy != "flat"
-                        else ctx.replace(ar_strategy="hier_ring"))
+        y = _slow_phase(y, slow, ctx)
     # Phase 3: all-gather over the fast level (Eq. 5).
-    if ctx.quant_ag:
-        return quantized_all_gather(y, fast, dim)
     return lax.all_gather(y, fast, axis=dim, tiled=True)
 
 
@@ -613,8 +535,6 @@ def tp_all_gather(x: jax.Array, ctx: ParallelCtx, dim: int) -> jax.Array:
             return _quant_all_gather(x.astype(jnp.float32), ctx.tp_fast,
                                      dim % x.ndim,
                                      QUANT_BITS[ctx.ar_quant]).astype(x.dtype)
-        if ctx.quant_ag:
-            return quantized_all_gather(x, ctx.tp_fast, dim % x.ndim)
         return lax.all_gather(x, ctx.tp_fast, axis=dim % x.ndim, tiled=True)
 
 
@@ -631,7 +551,6 @@ def grad_cross_pod_reduce(grads, ctx: ParallelCtx, pod_axes: Axes):
     phase.  Strategy per ``ctx.grad_reduce_strategy``:
       flat     - lax.psum (XLA default)
       rd       - recursive doubling (NVRAR inter-node phase)
-      rd_int8  - recursive doubling with int8-compressed exchange
     """
     if not pod_axes:
         return grads
@@ -644,8 +563,6 @@ def grad_cross_pod_reduce(grads, ctx: ParallelCtx, pod_axes: Axes):
                 out = lax.psum(out, ax)
             elif strat == "rd":
                 out = rd_all_reduce(out, ax, chunks=ctx.rd_chunks)
-            elif strat == "rd_int8":
-                out = compressed_rd_all_reduce(out, ax)
             else:  # pragma: no cover
                 raise ValueError(strat)
         return out
@@ -662,8 +579,7 @@ def dp_psum_mean(x: jax.Array, ctx: ParallelCtx) -> jax.Array:
 
 
 __all__ = [
-    "rd_all_reduce", "rd_halving_all_reduce", "compressed_rd_all_reduce",
-    "quant_rd_all_reduce", "tp_all_reduce", "tp_reduce_scatter",
-    "tp_all_gather", "grad_cross_pod_reduce", "dp_psum_mean", "axes_size",
-    "QUANT_BITS",
+    "rd_all_reduce", "rd_halving_all_reduce", "quant_rd_all_reduce",
+    "tp_all_reduce", "tp_reduce_scatter", "tp_all_gather",
+    "grad_cross_pod_reduce", "dp_psum_mean", "axes_size", "QUANT_BITS",
 ]

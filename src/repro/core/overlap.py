@@ -24,13 +24,11 @@ Total wire bytes are unchanged.  With ``ar_strategy="auto"`` the dispatch is
 resolved ONCE from the unchunked projection output and shared by every
 chunk: a per-chunk lookup on the |M|/K message could select a different
 strategy (a different device-sum order) than the unfused path and void the
-bit-consistency guarantee above.  The legacy lossy knobs (``quant_ag``,
-``compress_slow``) still force the unchunked path: their per-message
-quantization groups shift with the chunk boundaries.
+bit-consistency guarantee above.
 
-The first-class quantized wire (``ar_quant``) DOES compose with chunking.
-Its quantization groups are cap-aligned windows along the trailing feature
-dim (``kernels.rd_allreduce.quant``), so when both the full output and every
+The quantized wire (``ar_quant``) composes with chunking.  Its quantization
+groups are cap-aligned windows along the trailing feature dim
+(``kernels.rd_allreduce.quant``), so when both the full output and every
 chunk's per-rank scattered shard are multiples of the group cap, the chunked
 path quantizes exactly the same absolute feature windows as the unchunked
 one — bit-identical output, overlap preserved (:func:`_quant_chunk_ok`).
@@ -131,15 +129,9 @@ def collective_matmul(x: jax.Array, w: jax.Array, ctx: ParallelCtx, *,
     fast_n = hier.axes_size(ctx.tp_fast)
     ctx = _resolve_auto_for_matmul(x, w, ctx)
     k = _resolve_chunks(d_out, fast_n, chunks)
-    if ctx.quant_ag or ctx.compress_slow:
-        # Legacy lossy knobs quantize per-message: chunking would change
-        # the quantization-group boundaries and make the output depend on
-        # the overlap knob.  Keep one message so the knob stays
-        # numerics-free.
-        k = 1
     bits = hier.QUANT_BITS.get(ctx.ar_quant)
     if bits is not None and k > 1:
-        # First-class quantized wire: chunking is allowed exactly when the
+        # Quantized wire: chunking is allowed exactly when the
         # chunk shards stay group-cap aligned (see _quant_chunk_ok); the
         # autotuner already scored this call site on the unchunked message,
         # so a misaligned fallback only loses overlap, never dispatch.
@@ -188,8 +180,6 @@ def collective_matmul_reduce_scatter(x: jax.Array, w: jax.Array,
     d_out = w.shape[-1]
     ctx = _resolve_auto_for_matmul(x, w, ctx)
     k = _resolve_chunks(d_out, 1, chunks)
-    if ctx.compress_slow:
-        k = 1  # same lossy-quantization-boundary rule as collective_matmul
     bits = hier.QUANT_BITS.get(ctx.ar_quant)
     if bits is not None and k > 1 and not _quant_chunk_ok(d_out, k, 1,
                                                           bits):

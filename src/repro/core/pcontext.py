@@ -56,17 +56,10 @@ class ParallelCtx:
     #                      dtype) via repro.core.autotune (resolved at trace
     #                      time; see DESIGN.md §Overlap-and-autotune)
     ar_strategy: str = "flat"
-    # Gradient cross-pod reduction strategy ("flat" | "rd" | "rd_int8").
+    # Gradient cross-pod reduction strategy ("flat" | "rd").
     grad_reduce_strategy: str = "rd"
     # Chunk count for pipelined slow-axis exchanges (paper Sec. 4.2.1 analogue).
     rd_chunks: int = 1
-    # int8-compress the slow-axis TP exchange (beyond-paper; eta-packing).
-    compress_slow: bool = False
-    # Quantized all-gather: TP AR runs as RS(bf16) + AG(int8 + scales) —
-    # cuts fast-axis AR wire bytes ~25-45% (beyond-paper optimization).
-    # Legacy force-knob; superseded by ``ar_quant`` which quantizes every
-    # phase and is autotuner-dispatchable.
-    quant_ag: bool = False
     # Quantized collective level for tp_all_reduce / tp_reduce_scatter /
     # tp_all_gather: "none" | "int8" | "int4" | "auto".  int8/int4 carry
     # nibble/byte-packed payloads + per-group bf16 scales on the wire

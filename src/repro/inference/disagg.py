@@ -25,8 +25,8 @@ ring) matters most.  A colocated deployment forces one mesh layout and one
   recompute, and tracks queue depths / transfer bytes / per-pool AR
   message-size buckets.
 
-Correctness bar (enforced by tests/test_disagg.py and
-benchmarks/bench_disagg.py): a disaggregated greedy trace is **bitwise
+Correctness bar (enforced by tests/test_disagg.py and, under a fault
+plan, tests/test_faults.py): a disaggregated greedy trace is **bitwise
 equal** to the colocated paged serve of the same trace, including with
 speculative decoding enabled on the decode pool — a slot's greedy tokens
 depend only on its own prompt and KV, and the handoff round-trips KV
@@ -86,7 +86,7 @@ def pool_tuner(ar_table) -> autotune.AutoTuner:
     if ar_table is not None:
         return autotune.tuner_for(ar_table)
     base = autotune.active()
-    return autotune.AutoTuner(base.net, allow_lossy=base.allow_lossy)
+    return autotune.AutoTuner(base.net)
 
 
 class PrefillPool:

@@ -24,8 +24,8 @@ Determinism contract: every decision is a pure hash of
 schedule replays bit-identically, and the event set at rate ``r1`` is a
 **subset** of the event set at ``r2 >= r1`` for the same seed/ids (the
 decision is ``hash_unit < rate``).  That superset property is what lets
-``benchmarks/bench_faults.py`` assert goodput degrades monotonically in
-the fault rate.
+``tests/test_faults.py::test_goodput_monotone_in_fault_rate`` assert
+goodput degrades monotonically in the fault rate.
 
 The recovery obligations on the consumer side (retry/backoff, re-prefill
 fallback, quarantine + recompute, deadline shedding) live with the

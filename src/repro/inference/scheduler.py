@@ -238,7 +238,7 @@ class ServeMetrics:
     # * ``wasted_tokens``     — tokens decoded then discarded by an
     #   eviction (preemption / OOM / quarantine) and re-decoded from
     #   scratch; ``total / (total + wasted)`` is the useful-work goodput
-    #   fraction ``benchmarks/bench_faults.py`` gates on.
+    #   fraction ``test_goodput_monotone_in_fault_rate`` holds monotone.
     quarantines: int = 0
     injected_oom: int = 0
     shed_requests: int = 0

@@ -15,9 +15,10 @@ findings:
 * **Pipeline bubbles**: HP latency uses the (m + p - 1)/m GPipe factor for
   prefill and per-token stage serialization for decode.
 
-Used by benchmarks/bench_scaling.py (Figs. 1-2), bench_breakdown.py
-(Figs. 3/8), bench_e2e.py (Fig. 7), bench_trace.py (Figs. 9/18) and
-bench_moe.py (Fig. 10).
+Used by the router's analytic prefill cost (``router.prefill_cost_model``),
+``examples/multinode_decode_sim.py`` and ``tests/test_simulator.py``.  Its
+times are models, not measurements: speed is measured on the chip by
+``bench/`` (``BENCHMARK.json``).
 """
 from __future__ import annotations
 

@@ -23,7 +23,7 @@ Layout contract (shared with the Pallas kernel in ``quant_kernel``):
 
 All reference functions are plain jnp (traceable inside shard_map); the
 collectives call these, while ``quant_kernel`` provides the fused Pallas
-variant benched in ``tests/test_kernels.py`` / ``bench_allreduce``.
+variant, checked bit-equal to them in ``tests/test_kernels.py``.
 """
 from __future__ import annotations
 

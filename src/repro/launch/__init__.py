@@ -1,2 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve drivers,
-roofline analysis."""
+"""Launchers: production mesh, multi-pod dry-run, train/serve drivers."""

@@ -47,22 +47,14 @@ def build_cell(arch: str, shape_name: str, mesh, *,
                ar_strategy: str = "flat", scan_layers: bool = True,
                cross_pod_tp: bool = False,
                cfg_override=None, extra_ctx: Optional[dict] = None,
-               probe: bool = False, shape_override=None,
+               shape_override=None,
                kv_quant: bool = False, window_kv: bool = False,
                weight_quant: bool = False,
                fsdp_serve_override=None, sp_prefill: bool = False) -> Cell:
-    """Construct the step + input specs for one dry-run cell.
-
-    ``probe=True`` builds the roofline costing variant: layers unrolled
-    (accurate cost_analysis), attention chunking disabled (chunk loops are
-    also counted once), one grad-accum microbatch.
-    """
+    """Construct the step + input specs for one dry-run cell."""
     cfg = cfg_override or get_config(arch)
     plan = cell_plan(arch, shape_name)
     shape = shape_override or plan.shape
-    attn_chunk = 0 if probe else None
-    if probe:
-        scan_layers = False
     ctx = make_ctx(mesh, ar_strategy=ar_strategy,
                    cross_pod_tp=cross_pod_tp,
                    batch_replicated=plan.batch_replicated,
@@ -76,7 +68,7 @@ def build_cell(arch: str, shape_name: str, mesh, *,
     if shape.kind == "train":
         built = st.build_train_step(
             ap, ctx, mesh,
-            microbatches=1 if probe else plan.microbatches,
+            microbatches=plan.microbatches,
             scan_layers=scan_layers,
             frame_embeds=cfg.family == "encdec",
             patch_embeds=cfg.family == "vlm")
@@ -104,7 +96,7 @@ def build_cell(arch: str, shape_name: str, mesh, *,
             scan_layers=scan_layers,
             fsdp_serve=plan.fsdp_serve if fsdp_serve_override is None
             else fsdp_serve_override,
-            attn_chunk=attn_chunk, sp=sp_prefill,
+            sp=sp_prefill,
             frame_embeds=cfg.family == "encdec",
             patch_embeds=cfg.family == "vlm")
         tok_t = jax.ShapeDtypeStruct((shape.global_batch, shape.seq_len),
@@ -127,7 +119,6 @@ def build_cell(arch: str, shape_name: str, mesh, *,
                                  fsdp_serve=plan.fsdp_serve
                                  if fsdp_serve_override is None
                                  else fsdp_serve_override,
-                                 attn_chunk=attn_chunk,
                                  kv_quant=kv_quant,
                                  weight_quant=weight_quant,
                                  window_cache=window_cache)

@@ -144,7 +144,7 @@ def main(argv=None):
     p.add_argument("--ckpt-every", type=int, default=25)
     p.add_argument("--lr", type=float, default=1e-2)
     p.add_argument("--grad-reduce", default="rd",
-                   choices=["flat", "rd", "rd_int8"])
+                   choices=["flat", "rd"])
     args = p.parse_args(argv)
     out = run_training(args.arch, steps=args.steps, smoke=args.smoke,
                        seq_len=args.seq_len, global_batch=args.global_batch,

@@ -6,7 +6,7 @@ the launcher, the dry-run, the inference engine and the tests alike.
 
 Training step = FSDP(all-gather weights per layer) x TP x SP x grad-accum
 microbatches x remat, with the cross-pod gradient reduction performed by the
-paper's recursive-doubling strategy (optionally int8-compressed).
+paper's recursive-doubling strategy (or XLA's psum).
 
 Decode step = Megatron-style TP with the per-layer all-reduce strategy under
 study (flat | hier_ring | hier_rd | hier_rd_halving).

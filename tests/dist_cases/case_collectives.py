@@ -1,12 +1,12 @@
 """8-device validation: every hierarchical all-reduce strategy is exact
-(or near-exact for int8) against flat psum."""
+against flat psum."""
 import numpy as np, jax, jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
 from repro.core.compat import make_mesh
-from repro.core import (rd_all_reduce, rd_halving_all_reduce,
-                        compressed_rd_all_reduce, tp_all_reduce, ParallelCtx)
+from repro.core import (rd_all_reduce, rd_halving_all_reduce, tp_all_reduce,
+                        ParallelCtx)
 
 mesh = make_mesh((2, 4), ("pod", "model"))
 rng = np.random.default_rng(0)
@@ -21,8 +21,6 @@ ref = run(lambda v: lax.psum(v, ("pod", "model")))
 assert np.allclose(run(lambda v: rd_all_reduce(lax.psum(v, "model"), "pod")), ref, rtol=1e-5)
 assert np.allclose(run(lambda v: rd_all_reduce(lax.psum(v, "model"), "pod", chunks=4)), ref, rtol=1e-5)
 assert np.allclose(run(lambda v: rd_halving_all_reduce(lax.psum(v, "model"), "pod")), ref, rtol=1e-5)
-c = run(lambda v: compressed_rd_all_reduce(lax.psum(v, "model"), "pod"))
-assert np.abs(c - ref).max() / np.abs(ref).max() < 0.05
 
 for strat in ("hier_rd", "hier_rd_halving", "hier_ring"):
     ctx = ParallelCtx(tp_fast=("model",), tp_slow=("pod",), ar_strategy=strat)

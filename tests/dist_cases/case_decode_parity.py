@@ -57,4 +57,25 @@ parity(tiny("moe", n_experts=8, top_k=2, d_ff_expert=32, capacity_factor=8.0), c
 parity(tiny("hybrid", d_inner=128, ssm_state=8, sliding_window=4), ctxA, 4, "hybrid tp4")
 parity(tiny("ssm", d_model=128, rwkv_head_dim=32, decay_lora=8), ctxA, 4, "rwkv tp4")
 parity(tiny("ssm", d_model=128, rwkv_head_dim=16, decay_lora=8), ctxB, 8, "rwkv tp8")
+
+# MoE with the TP group across pods: the hierarchical all-reduce gives the
+# flat one's greedy tokens (this MoE at tp8 is not bitwise the local model,
+# so the two strategies are compared with each other).
+def mesh_tokens(cfg, ctx, tp):
+    apN = make_plan(cfg, tp)
+    pN = init_params(key, apN)
+    tok = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
+    pre = build_prefill(apN, ctx, mesh, s_max=S + 4)
+    dec = build_decode_step(apN, ctx, mesh)
+    nxt, c = jax.jit(pre.fn)(pN, tok)
+    toks, pos = [np.asarray(nxt)], jnp.full((B,), S, jnp.int32)
+    for i in range(4):
+        nxt, c = dec.jit()(pN, c, nxt, pos + i)
+        toks.append(np.asarray(nxt))
+    return np.stack(toks)
+
+moe = tiny("moe", n_experts=8, top_k=2, d_ff_expert=32, capacity_factor=8.0)
+assert np.array_equal(mesh_tokens(moe, ctxB.replace(ar_strategy="flat"), 8),
+                      mesh_tokens(moe, ctxB, 8)), "moe tp8 flat vs hier_rd"
+print("moe tp8 flat == hier_rd OK")
 print("decode parity OK")

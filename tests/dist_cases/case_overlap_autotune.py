@@ -144,12 +144,10 @@ assert np.array_equal(toks["flat"], toks["auto+overlap"]), \
     "overlapped auto decode must reproduce flat greedy tokens"
 print("e2e overlapped auto decode parity OK")
 
-# --- int8 weight dequant under TP x the lossy-knob overlap rule ------------
-# quantized decode (dequant_layer inside the scan) with the lossy slow-axis
-# exchange enabled: overlap.collective_matmul must fall back to the
-# unchunked message (the quantization-group-boundary rule), so greedy
-# tokens cannot depend on the overlap knob even in the lossy + weight-quant
-# configuration.
+# --- int8 weight dequant under TP x overlap ---------------------------------
+# quantized decode (dequant_layer inside the scan) on the hierarchical
+# recursive-doubling all-reduce: greedy tokens must not depend on the
+# overlap knob in the weight-quant configuration either.
 from repro.models.transformer import init_cache
 from repro.parallel.quant import quantize_params
 
@@ -157,8 +155,8 @@ qparams = quantize_params(params)
 qtoks = {}
 for name, ov in (("plain", False), ("overlap", True)):
     ctx_q = ParallelCtx(tp_fast=("model",), tp_slow=("pod",), ep=("model",),
-                        ar_strategy="hier_rd", compress_slow=True,
-                        overlap_matmul=ov, overlap_chunks=4)
+                        ar_strategy="hier_rd", overlap_matmul=ov,
+                        overlap_chunks=4)
     dec_q = build_decode_step(ap, ctx_q, mesh, weight_quant=True)
     cache_q = shard_map(lambda: init_cache(ap, 4, 24, local=True),
                         mesh=mesh, in_specs=(),
@@ -171,8 +169,8 @@ for name, ov in (("plain", False), ("overlap", True)):
         seq.append(np.asarray(cur))
     qtoks[name] = np.stack(seq)
 assert np.array_equal(qtoks["plain"], qtoks["overlap"]), \
-    "lossy compress_slow + weight-quant decode must not depend on overlap"
-print("weight-quant dequant under TP x lossy overlap rule OK")
+    "weight-quant decode must not depend on overlap"
+print("weight-quant dequant under TP x overlap OK")
 
 # --- fused Pallas GEMM+RD kernel (TPU interpret mode) ----------------------
 from jax.experimental.pallas import tpu as pltpu

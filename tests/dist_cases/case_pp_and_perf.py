@@ -1,5 +1,6 @@
 """Hybrid parallelism (GPipe PP x TP) parity + perf-feature parity on 8
-simulated devices: quantized all-gather, SP prefill, cross-pod int8 RD."""
+simulated devices: SP prefill, int8 KV cache, ring-window cache, int8
+weights."""
 import numpy as np, jax, jax.numpy as jnp
 from repro.core.compat import make_mesh
 from repro.models import ModelConfig, make_plan, init_params, forward_lm
@@ -25,7 +26,7 @@ ref = np.asarray(forward_lm(p1, tok, ap1, LOCAL)[0], np.float32)
 assert np.abs(logits_pp - ref).max() / np.abs(ref).max() < 2e-3
 print("pp_parity OK")
 
-# --- SP prefill + quantized AG + int8 KV + ring, all at once ---------------
+# --- SP prefill vs the fused all-reduce ------------------------------------
 cfgs = ModelConfig(name="s", family="dense", n_layers=2, d_model=64,
                    n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
                    vocab_size=96, sliding_window=8, dtype=jnp.float32)
@@ -37,7 +38,6 @@ toks = {}
 for name, ctx2, kw in [
     ("plain", base_ctx, {}),
     ("sp", base_ctx, {"sp": True}),
-    ("q8ag", base_ctx.replace(quant_ag=True), {}),
 ]:
     pre = build_prefill(aps, ctx2, mesh, s_max=32, **kw)
     nxt, cache = jax.jit(pre.fn)(ps, tok)
@@ -49,11 +49,7 @@ for name, ctx2, kw in [
         seq.append(np.asarray(nxt))
     toks[name] = np.stack(seq)
 assert np.array_equal(toks["plain"], toks["sp"]), "sp prefill parity"
-# quant_ag is intentionally lossy (int8 + per-128 scales): require a high
-# greedy-token agreement rate rather than bit equality
-q8_match = np.mean(toks["plain"] == toks["q8ag"])
-assert q8_match >= 0.8, f"quant_ag match rate {q8_match}"
-print(f"sp parity exact; quant_ag match rate {q8_match:.2f} OK")
+print("sp parity exact OK")
 
 # --- int8 KV + ring-window decode vs bf16 full cache -----------------------
 ctx3 = base_ctx
@@ -71,9 +67,10 @@ for variant, kw in [("kv_int8", {"kv_quant": True}),
         "window_cache": kw.get("window_cache", False)})
     cur_r = cur_v = jnp.arange(8, dtype=jnp.int32)
     outs_r, outs_v = [], []
+    step_r, step_v = dec_ref.jit(), dec_var.jit()
     for i in range(10):
-        lr, cache_r = dec_ref.fn(ps, cache_r, cur_r, jnp.full((8,), i, jnp.int32))
-        lv, cache_v = dec_var.fn(ps, cache_v, cur_v, jnp.full((8,), i, jnp.int32))
+        lr, cache_r = step_r(ps, cache_r, cur_r, jnp.full((8,), i, jnp.int32))
+        lv, cache_v = step_v(ps, cache_v, cur_v, jnp.full((8,), i, jnp.int32))
         cur_r, cur_v = lr, lv
         outs_r.append(np.asarray(lr)); outs_v.append(np.asarray(lv))
     match = np.mean(np.stack(outs_r) == np.stack(outs_v))
@@ -90,10 +87,11 @@ c_r = _ic(aps, 8, 32, local=False)
 c_w = _ic(aps, 8, 32, local=False)
 dec_r2 = build_decode_step(aps, ctx3, mesh)
 cur_r = cur_w = jnp.arange(8, dtype=jnp.int32)
+step_r, step_w = dec_r2.jit(), dec_w.jit()
 m = t = 0
 for i in range(8):
-    cur_r, c_r = dec_r2.fn(ps, c_r, cur_r, jnp.full((8,), i, jnp.int32))
-    cur_w, c_w = dec_w.fn(qparams, c_w, cur_w, jnp.full((8,), i, jnp.int32))
+    cur_r, c_r = step_r(ps, c_r, cur_r, jnp.full((8,), i, jnp.int32))
+    cur_w, c_w = step_w(qparams, c_w, cur_w, jnp.full((8,), i, jnp.int32))
     m += int(np.sum(np.asarray(cur_r) == np.asarray(cur_w))); t += 8
 assert m / t >= 0.8, f"weight-quant match {m}/{t}"
 print(f"weight_quant decode match {m}/{t} OK")

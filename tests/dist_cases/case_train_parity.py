@@ -48,8 +48,6 @@ run(tiny("dense"), (2, 4), ("data", "model"), ctx1, 4, 2, "dense fsdp+sp+mb2")
 ctx2 = ParallelCtx(tp_fast=("model",), dp=("pod", "data"), fsdp=("data",),
                    ep=("model",), sp=("model",), grad_reduce_strategy="rd")
 run(tiny("dense"), (2, 2, 2), ("pod", "data", "model"), ctx2, 2, 1, "multipod rd")
-ctx3 = ctx2.replace(grad_reduce_strategy="rd_int8")
-run(tiny("dense"), (2, 2, 2), ("pod", "data", "model"), ctx3, 2, 1, "multipod rd_int8")
 run(tiny("moe", n_experts=8, top_k=2, d_ff_expert=32, capacity_factor=8.0),
     (2, 4), ("data", "model"), ctx1, 4, 2, "moe fsdp+sp")
 run(tiny("hybrid", d_inner=128, ssm_state=8), (2, 4), ("data", "model"), ctx1, 4, 1, "hybrid")

@@ -3,6 +3,7 @@
 refinement, and JSON persistence."""
 import json
 import os
+import warnings
 
 import pytest
 
@@ -101,32 +102,66 @@ def test_save_load_roundtrip(tmp_path):
 
 
 def test_save_load_roundtrip_lossy(tmp_path):
-    """An allow_lossy table must survive JSON persistence verbatim: the
-    knob itself, every compress_slow entry, and — after load — fresh
-    lookups must keep seeding with the lossy rule enabled."""
-    t = at.AutoTuner(cm.TPU_V5E, allow_lossy=True)
-    sizes = [64 * KB, 1 * MB, 16 * MB, 64 * MB, 256 * MB]
-    for s in sizes:
-        t.choose(s, 16, 4)
-    lossy_keys = {k for k, v in t.table.items() if v.compress_slow}
-    assert lossy_keys, "expected the lossy knob to fire at some size"
-    assert any(not v.compress_slow for v in t.table.values()), \
-        "latency-bound buckets must stay lossless"
-    p = os.path.join(tmp_path, "lossy_table.json")
-    t.save(p)
-    doc = json.load(open(p))
-    assert doc["allow_lossy"] is True
-    t2 = at.AutoTuner.load(p)
-    assert t2.allow_lossy is True
-    assert t2.table == t.table
-    assert {k for k, v in t2.table.items() if v.compress_slow} == lossy_keys
-    # a fresh bucket on the loaded tuner still honors allow_lossy
-    probe = 4 * max(sizes)
-    assert t2.choose(probe, 16, 4) == at.analytic_choice(
-        probe, 16, 4, cm.TPU_V5E, allow_lossy=True)
-    # and a lossless tuner never emits compress_slow at any probed size
-    t3 = at.AutoTuner(cm.TPU_V5E)
-    assert not any(t3.choose(s, 16, 4).compress_slow for s in sizes)
+    """A table saved before the int8 slow exchange was removed still
+    loads: each entry's ``"compress_slow": false`` and the top-level
+    ``"allow_lossy"`` flag are ignored, so every such entry is kept and
+    dispatches as before; an entry with ``"compress_slow": true`` names
+    the removed path and is dropped with a warning."""
+    old = {
+        "allow_lossy": False,
+        "net": "tpu_v5e",
+        "sp_table": {"b20/f4/s2/bfloat16": True},
+        "table": {
+            "b16/f4/s2/bfloat16": {"compress_slow": False, "quant": "none",
+                                   "rd_chunks": 1, "strategy": "hier_rd"},
+            "b20/f4/s2/bfloat16": {"compress_slow": False, "quant": "none",
+                                   "rd_chunks": 1, "strategy": "hier_rd"},
+            "b20/f4/s2/bfloat16:qauto": {"compress_slow": False,
+                                         "quant": "int4", "rd_chunks": 1,
+                                         "strategy": "hier_rd"},
+            "b24/f4/s2/bfloat16": {"compress_slow": False, "quant": "none",
+                                   "rd_chunks": 8, "strategy": "hier_rd"},
+        },
+        "version": 1,
+    }
+    p = os.path.join(tmp_path, "old_table.json")
+    with open(p, "w") as f:
+        json.dump(old, f)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")      # a clean old table: no warning
+        t = at.AutoTuner.load(p)
+    want = {k: at.ARChoice(strategy=v["strategy"], rd_chunks=v["rd_chunks"],
+                           quant=v["quant"])
+            for k, v in old["table"].items()}
+    assert t.table == want
+    assert t.sp_table == old["sp_table"]
+    # the loaded entries dispatch exactly as the stored ones
+    ctx = ParallelCtx(tp_fast=("model",), tp_slow=("pod",),
+                      ar_strategy="auto", ar_quant="auto")
+    prev = at.install(t)
+    try:
+        r = at.resolve(ctx, 1 * MB, 4, 2, "bfloat16")
+        assert (r.ar_strategy, r.rd_chunks, r.ar_quant) == \
+            ("hier_rd", 1, "int4")
+        r = at.resolve(ctx.replace(ar_quant="none"), 16 * MB, 4, 2,
+                       "bfloat16")
+        assert (r.ar_strategy, r.rd_chunks) == ("hier_rd", 8)
+    finally:
+        at.install(prev)
+    # what the loaded tuner saves again carries neither old field
+    doc = t.to_json()
+    assert set(doc) == {"version", "net", "table", "sp_table"}
+    assert all(set(v) == {"strategy", "rd_chunks", "quant"}
+               for v in doc["table"].values())
+    # an entry that selected the int8 slow exchange is dropped, the rest kept
+    old["table"]["b28/f16/s4/bfloat16"] = {
+        "compress_slow": True, "quant": "none", "rd_chunks": 1,
+        "strategy": "hier_rd"}
+    with open(p, "w") as f:
+        json.dump(old, f)
+    with pytest.warns(RuntimeWarning, match="dropped 1 "):
+        t2 = at.AutoTuner.load(p)
+    assert t2.table == want
 
 
 def test_install_and_resolve_roundtrip():
@@ -232,3 +267,33 @@ def test_seq_parallel_mode_validation():
         assert ParallelCtx(seq_parallel=mode).seq_parallel == mode
     with pytest.raises(ValueError):
         ParallelCtx(seq_parallel="maybe")
+
+
+def test_rd_chunk_count_is_interior():
+    """The pipelined slow exchange's chunk count (paper Table 5: the
+    optimum lies between one chunk and many) starts at 1 for decode-sized
+    messages, grows with the message, and stops at ``_MAX_RD_CHUNKS``
+    far below the count the threshold alone would give."""
+    sizes = [16 * KB * 4 ** i for i in range(10)]
+    chunks = [at._rd_chunks_for(s, 4) for s in sizes]
+    assert chunks[0] == 1
+    assert chunks == sorted(chunks)
+    assert 1 < max(chunks) == at._MAX_RD_CHUNKS
+    huge = 1 << 34
+    assert at._rd_chunks_for(huge, 1) == at._MAX_RD_CHUNKS \
+        < huge // at._CHUNK_THRESHOLD_BYTES
+
+
+def test_quant_auto_ladder_on_a_2x4_mesh():
+    """``ar_quant="auto"`` on the (2 pod x 4 model) topology: messages of
+    64 KB and less keep the full-precision wire on the NVRAR path, and at
+    least one bandwidth-bound size up to 16 MB quantizes."""
+    sizes = (16 * KB, 64 * KB, 256 * KB, 1 * MB, 4 * MB, 16 * MB)
+    picks = {s: at.analytic_quant_choice(s, 4, 2, cm.TPU_V5E, "auto")
+             for s in sizes}
+    for s in sizes:
+        if s <= 64 * KB:
+            assert picks[s].quant == "none", (s, picks[s])
+            assert picks[s] == at.analytic_choice(s, 4, 2, cm.TPU_V5E)
+    assert picks[16 * KB].strategy == "hier_rd"
+    assert any(p.quant != "none" for p in picks.values()), picks

@@ -85,3 +85,15 @@ def test_router_fleet(dist_runner):
 def test_replica_devices(dist_runner):
     out = dist_runner("case_replica_devices.py", 4)
     assert "replica devices OK" in out
+
+
+@pytest.mark.dist
+def test_wire_bytes(dist_runner):
+    out = dist_runner("case_wire_bytes.py")
+    assert "wire bytes OK" in out
+
+
+@pytest.mark.dist
+def test_pp_and_perf_parity(dist_runner):
+    out = dist_runner("case_pp_and_perf.py")
+    assert "pp+perf case OK" in out

@@ -51,23 +51,6 @@ def test_halving_schedule_slice_tracking(n):
         assert lo == r
 
 
-@given(st.integers(1, 4096), st.integers(0, 100))
-@settings(max_examples=100, deadline=None)
-def test_int8_group_quantization_error_bound(nelem, seed):
-    """The compressed-exchange quantizer: error <= group_absmax / 127 per
-    element (half a quantization step would be /254; rounding gives /127
-    worst case -> use that bound)."""
-    rng = np.random.default_rng(seed)
-    x = rng.standard_normal(nelem) * rng.uniform(0.1, 100)
-    group = 128
-    pad = (-nelem) % group
-    xp = np.pad(x, (0, pad)).reshape(-1, group)
-    scale = np.maximum(np.abs(xp).max(1, keepdims=True) / 127.0, 1e-30)
-    q = np.clip(np.round(xp / scale), -127, 127)
-    err = np.abs(q * scale - xp)
-    assert np.all(err <= scale * 0.5 + 1e-12)
-
-
 def test_tp_reduce_scatter_slow_phase_selection(monkeypatch):
     """PR 5 bugfix regression: tp_reduce_scatter's slow phase must route
     ``flat`` to lax.psum and EVERY hierarchical strategy through

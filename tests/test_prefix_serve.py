@@ -178,6 +178,36 @@ def test_trie_survives_runs_and_readmission_hits(tiny_lm):
     sched.alloc.check()
 
 
+@pytest.mark.parametrize("slots", [2, 4])
+def test_hits_monotone_in_sharing_fraction(tiny_lm, slots):
+    """More sharing can only add hits: ``make_prefix_trace`` draws each
+    request's share coin from the same per-request stream, so at fixed
+    slots the hit rate and the tokens saved never fall as the sharing
+    fraction rises; nothing is shared at 0 and something is spliced at 1,
+    where every prompt shares the prefix and tokens still match the
+    cache-off run."""
+    cfg, ap, params = tiny_lm
+    spec = RP.replace(slots=slots)
+    cells = []
+    for frac in (0.0, 0.5, 1.0):
+        reqs = _trace(cfg, n=12, seed=3, shared_frac=frac)
+        sched = build_replica(spec, ap=ap, params=params)
+        on, m = _outputs(sched, reqs)
+        sched.alloc.check()
+        cells.append(m)
+    for lo, hi in zip(cells, cells[1:]):
+        assert hi.prefix_hit_rate >= lo.prefix_hit_rate
+        assert hi.prefix_tokens_saved >= lo.prefix_tokens_saved
+    assert cells[0].prefix_tokens_saved == 0
+    assert cells[-1].prefix_tokens_saved > 0
+    # `on` holds the tokens of the sharing-1.0 run
+    off, _ = _outputs(build_replica(RS.replace(slots=slots), ap=ap,
+                                    params=params),
+                      _trace(cfg, n=12, seed=3, shared_frac=1.0))
+    for rid in off:
+        np.testing.assert_array_equal(off[rid], on[rid])
+
+
 def test_capacity_cap_still_exact(tiny_lm):
     """A one-block capacity forces constant LRU churn; hits may vanish
     but correctness may not."""

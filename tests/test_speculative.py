@@ -236,6 +236,28 @@ def test_spec_oracle_drafter_cuts_steps(tiny_lm):
     assert m1.drafter_hit_rate > 0.8
 
 
+def test_spec_oracle_acceptance_across_k(tiny_lm):
+    """The replay drafter at the shortest and longest k: acceptance stays
+    above 0.7 (not 1.0: drafts padded past a short request's stream end
+    are rejected, and that tail grows with k) and tokens stay bitwise
+    the plain stream.  k=4 is held by the test above."""
+    cfg, ap, params = tiny_lm
+    shape = dict(n=12, mean_out=14, rate=3.0, seed=3)
+    plain, _ = _trace_outputs(ap, params, cfg.vocab_size, slots=4,
+                              block_size=8, **shape)
+    reqs = make_trace(12, mean_in=10, mean_out=14, rate=3.0,
+                      vocab=cfg.vocab_size, seed=3)
+    streams = {tuple(int(t) for t in r.prompt): list(plain[r.rid])
+               for r in reqs}
+    for k in (2, 8):
+        got, m = _trace_outputs(ap, params, cfg.vocab_size, slots=4,
+                                block_size=8, spec_mode="replay", spec_k=k,
+                                drafter=ReplayDrafter(streams), **shape)
+        for rid in plain:
+            np.testing.assert_array_equal(plain[rid], got[rid])
+        assert m.acceptance_rate > 0.7, (k, m.acceptance_rate)
+
+
 def test_spec_preemption_rollback_correctness(tiny_lm):
     """Tight paged pool + speculative growth: preemption and rejected-draft
     truncation must still emit exactly the undisturbed streams, and drain
